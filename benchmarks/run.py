@@ -10,6 +10,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.sim.execache import enable_persistent_cache
+    enable_persistent_cache()
     from benchmarks import (bench_analysis, bench_belief, bench_dq_tradeoff,
                             bench_geo_calibration, bench_kernels, bench_obs,
                             bench_optimizers, bench_paper_example,

@@ -11,8 +11,9 @@ that price EXACTLY what the kernels run (both sides share
 
   * :func:`vmem_bytes` — the per-grid-step VMEM footprint: every streamed
     input tile double-buffered, plus the scratch accumulator and output;
-  * :func:`predict_seconds` — a roofline estimate (``repro.perf.roofline``
-    peaks): max(compute term, HBM-traffic term) + per-grid-step overhead.
+  * :func:`predict_seconds` — a roofline estimate (the device kind's
+    entry in ``repro.perf.roofline.CHIP_PEAKS``): max(compute term,
+    HBM-traffic term) + per-grid-step overhead.
     HBM traffic counts tile *revisits* (the dense kernel re-reads com once
     per edge block and x_j once per u block), which is what makes the
     ranking non-trivial.
@@ -40,9 +41,11 @@ import dataclasses
 import json
 import threading
 
+import jax
+
 from repro import obs
 from repro.kernels.edge_latency import block_geometry
-from repro.perf.roofline import HBM_BW, PEAK_FLOPS
+from repro.perf.roofline import peaks_for
 
 __all__ = ["KernelConfig", "ShapeKey", "DEFAULT_CONFIG", "VMEM_BUDGET_BYTES",
            "candidate_configs", "vmem_bytes", "predict_seconds", "rank",
@@ -120,10 +123,13 @@ def vmem_bytes(kind: str, E: int, V: int, R: int | None,
 
 def predict_seconds(kind: str, B: int, E: int, V: int, R: int | None,
                     config: KernelConfig, com_batch: int = 1,
-                    backend: str = "tpu") -> float:
+                    backend: str = "tpu",
+                    device_kind: str | None = None) -> float:
     """Analytic time estimate for one kernel launch: roofline terms over
     the PADDED shape (so over-padding from a too-coarse block is priced),
-    with HBM traffic counting every tile revisit the index maps imply."""
+    with HBM traffic counting every tile revisit the index maps imply.
+    Off the CPU the peaks are ``device_kind``'s; an unknown kind raises."""
+    peaks = peaks_for(backend, device_kind)
     g = block_geometry(kind, E, V, R, config.block_edges, config.block_v)
     if kind == "dense":
         steps = B * g.n_e * g.n_u * g.n_v
@@ -143,7 +149,7 @@ def predict_seconds(kind: str, B: int, E: int, V: int, R: int | None,
                    + com_batch * g.v_pad * g.n_e    # corr: per e block
                    + B * g.e_pad)
     overhead = STEP_OVERHEAD_S.get(backend, STEP_OVERHEAD_DEFAULT_S)
-    return max(flops / PEAK_FLOPS, BYTES_F32 * traffic / HBM_BW) \
+    return max(flops / peaks.flops, BYTES_F32 * traffic / peaks.hbm_bw) \
         + steps * overhead
 
 
@@ -151,8 +157,8 @@ def candidate_configs(kind: str, E: int, V: int,
                       R: int | None) -> list[KernelConfig]:
     """VMEM-feasible (block_edges, block_v) pairs, deduplicated by the
     geometry they actually clamp to (a 512-wide block over V = 300 is the
-    same kernel as a 384-wide one).  Never empty: the smallest candidate
-    tile fits the budget at any R ≤ a few thousand."""
+    same kernel as a 384-wide one).  Raises ValueError when not even the
+    smallest tile fits the budget (a huge R): no kernel lowers there."""
     out, seen = [], set()
     for be in BLOCK_EDGES_CANDIDATES:
         for bv in BLOCK_V_CANDIDATES:
@@ -164,34 +170,38 @@ def candidate_configs(kind: str, E: int, V: int,
                 continue
             seen.add((g.be, g.bv))
             out.append(cfg)
-    if not out:  # huge R can exhaust the budget; fall back to minimum tiles
-        out.append(KernelConfig(block_edges=BLOCK_EDGES_CANDIDATES[0],
-                                block_v=BLOCK_V_CANDIDATES[0]))
+    if not out:
+        raise ValueError(
+            f"no {kind} block shape fits the {VMEM_BUDGET_BYTES}-byte VMEM "
+            f"budget at E={E}, V={V}, R={R}")
     return out
 
 
 def rank(kind: str, B: int, E: int, V: int, R: int | None = None,
-         com_batch: int = 1, backend: str = "tpu") -> list[KernelConfig]:
+         com_batch: int = 1, backend: str = "tpu",
+         device_kind: str | None = None) -> list[KernelConfig]:
     """Feasible candidates, best predicted first (deterministic: ties break
     toward the larger blocks, which also minimize grid-sequencing steps)."""
     cands = candidate_configs(kind, E, V, R)
     return sorted(
         cands,
         key=lambda c: (predict_seconds(kind, B, E, V, R, c,
-                                       com_batch=com_batch, backend=backend),
+                                       com_batch=com_batch, backend=backend,
+                                       device_kind=device_kind),
                        -c.block_v, -c.block_edges))
 
 
 def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
                com_batch: int = 1, backend: str | None = None,
-               timer=None) -> KernelConfig:
+               device_kind: str | None = None, timer=None) -> KernelConfig:
     """The block config for one shape: decision-table hit, else analytic
     ranking (plus an empirical race over the top candidates when ``timer``
     — a ``callable(KernelConfig) -> seconds`` — is supplied), stored.
+    ``backend`` defaults to JAX's, ``device_kind`` (which picks the peaks
+    off the CPU) to that of the backend's first device.
 
     Safe to call at trace time: pure host work, deterministic per key."""
     if backend is None:
-        import jax
         backend = jax.default_backend()
     key = ShapeKey.of(backend, kind, B, E, V, R)
     with _lock:
@@ -202,8 +212,10 @@ def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
             reg.counter("kernels.autotune.decisions", kind=kind,
                         source="table", backend=backend).add(1)
         return hit[0]
+    if backend != "cpu" and device_kind is None:
+        device_kind = jax.devices(backend)[0].device_kind
     ranked = rank(kind, key.b_bucket, E, V, R, com_batch=com_batch,
-                  backend=backend)
+                  backend=backend, device_kind=device_kind)
     best, source = ranked[0], "analytic"
     if timer is not None:
         timed = [(timer(c), c) for c in ranked[:EMPIRICAL_TOP_K]]

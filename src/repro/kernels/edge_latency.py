@@ -25,7 +25,11 @@ Compiled-ready blocking scheme (see kernels/README.md for the full story):
     VMEM in (be, bv) / (bv, bv) tiles instead of requiring residency;
   * the STRUCTURED kernel (RegionFleetFamily: ``t = mass @ A + corr·x_j``
     with R ≪ V) runs a (B, E/be, V/bv) grid, V-blocking its (be, R)@(R, bv)
-    product and diagonal correction with the same running max over u-tiles.
+    product and diagonal correction with the same running max over u-tiles;
+  * both kernels write a lane-dense (B, e_pad, LANE) output in (1, be, LANE)
+    blocks, every lane holding the edge's running max: Mosaic requires an
+    output block's last two dims to be (8, 128)-aligned or whole, which a
+    (1, be) block over a (B, e_pad) array is not.  The wrapper keeps lane 0.
 
 Block shapes come from :mod:`repro.kernels.autotune` via the dispatch layer
 (:mod:`repro.kernels.dispatch`); the single-tile kernels the blocked ones
@@ -43,8 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 __all__ = ["LANE", "SUBLANE", "BlockGeometry", "block_geometry",
            "edge_latency_pallas", "edge_latency_structured_pallas",
            "edge_latency_pallas_single_tile",
@@ -52,6 +54,9 @@ __all__ = ["LANE", "SUBLANE", "BlockGeometry", "block_geometry",
 
 LANE = 128     # f32 minor-dim tile width on TPU
 SUBLANE = 8    # f32 second-minor tile width
+# the f32 contraction at f32 precision on the MXU (Mosaic's contract
+# precision fp32), not one bf16 pass: scores answer to a float64 oracle
+F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _round_up(n: int, m: int) -> int:
@@ -75,6 +80,7 @@ class BlockGeometry:
     n_e: int          # edge-block grid steps
     n_u: int          # u-axis (row-max) grid steps
     n_v: int          # v-axis (contraction) grid steps; 1 for structured
+    out_lanes: int    # minor dim of the (B, e_pad, out_lanes) kernel output
 
 
 def block_geometry(kind: str, E: int, V: int, R: int | None,
@@ -101,7 +107,22 @@ def block_geometry(kind: str, E: int, V: int, R: int | None,
         r_pad = _round_up(R, LANE)
     return BlockGeometry(be=be, bv=bv, e_pad=e_pad, v_pad=v_pad,
                          r_pad=r_pad, n_e=e_pad // be, n_u=v_pad // bv,
-                         n_v=n_v)
+                         n_v=n_v, out_lanes=LANE)
+
+
+def _fold_row_max(u, o_ref, vals):
+    """Fold the (be, bv) tile's row max into the lane-dense (be, LANE)
+    output block: initialise on the first u-tile, running max after."""
+    part = jnp.broadcast_to(jnp.max(vals, axis=1, keepdims=True),
+                            o_ref.shape[1:])
+
+    @pl.when(u == 0)
+    def _init():
+        o_ref[0] = part
+
+    @pl.when(u > 0)
+    def _running():
+        o_ref[0] = jnp.maximum(o_ref[0], part)
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, target: int) -> jnp.ndarray:
@@ -136,6 +157,7 @@ def _edge_latency_blocked_kernel(n_v: int, v_real: int, xi_ref, xj_ref,
     com = com_ref[0].astype(jnp.float32)  # (bu=bv, bv) — (u, v) com tile
     # t_acc[e, u'] += Σ_{v'} com[u', v'] · xj[e, v']
     t_acc[...] += jax.lax.dot_general(xj, com, (((1,), (1,)), ((), ())),
+                                      precision=F32_DOT,
                                       preferred_element_type=jnp.float32)
 
     @pl.when(v == n_v - 1)
@@ -143,16 +165,8 @@ def _edge_latency_blocked_kernel(n_v: int, v_real: int, xi_ref, xj_ref,
         xi = xi_ref[0].astype(jnp.float32)  # (be, bu) — pre-scaled by s_i
         u_ix = u * xi.shape[1] + jax.lax.broadcasted_iota(
             jnp.int32, xi.shape, 1)
-        part = jnp.max(jnp.where(u_ix < v_real, xi * t_acc[...], -jnp.inf),
-                       axis=1)
-
-        @pl.when(u == 0)
-        def _init():
-            o_ref[0] = part
-
-        @pl.when(u > 0)
-        def _running():
-            o_ref[0] = jnp.maximum(o_ref[0], part)
+        _fold_row_max(u, o_ref,
+                      jnp.where(u_ix < v_real, xi * t_acc[...], -jnp.inf))
 
 
 @functools.partial(jax.jit,
@@ -187,15 +201,17 @@ def edge_latency_pallas(x_i, x_j, com, block_edges: int = 128,
             pl.BlockSpec((1, g.be, g.bv), lambda b, e, u, v: (b, e, v)),
             pl.BlockSpec((1, g.bv, g.bv), com_ix),
         ],
-        out_specs=pl.BlockSpec((1, g.be), lambda b, e, u, v: (b, e)),
-        out_shape=jax.ShapeDtypeStruct((B, g.e_pad), jnp.float32),
+        out_specs=pl.BlockSpec((1, g.be, g.out_lanes),
+                               lambda b, e, u, v: (b, e, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, g.e_pad, g.out_lanes),
+                                       jnp.float32),
         scratch_shapes=[pltpu.VMEM((g.be, g.bv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
     )(x_i, x_j, com)
-    return out[:, :E]
+    return out[:, :E, 0]
 
 
 # -- structured (RegionFleet) V-blocked kernel --------------------------------
@@ -227,18 +243,11 @@ def _edge_latency_structured_blocked_kernel(v_real: int, xi_ref, xj_ref,
     a = a_ref[0].astype(jnp.float32)        # (Rp, bv)
     corr = corr_ref[0].astype(jnp.float32)  # (1, bv)
     t = jax.lax.dot_general(mass, a, (((1,), (0,)), ((), ())),
+                            precision=F32_DOT,
                             preferred_element_type=jnp.float32)
     u_ix = u * xi.shape[1] + jax.lax.broadcasted_iota(jnp.int32, xi.shape, 1)
-    part = jnp.max(jnp.where(u_ix < v_real, xi * (t + corr * xj), -jnp.inf),
-                   axis=1)
-
-    @pl.when(u == 0)
-    def _init():
-        o_ref[0] = part
-
-    @pl.when(u > 0)
-    def _running():
-        o_ref[0] = jnp.maximum(o_ref[0], part)
+    _fold_row_max(u, o_ref,
+                  jnp.where(u_ix < v_real, xi * (t + corr * xj), -jnp.inf))
 
 
 @functools.partial(jax.jit,
@@ -281,13 +290,15 @@ def edge_latency_structured_pallas(x_i, x_j, mass, a, corr,
             pl.BlockSpec((1, g.r_pad, g.bv), scen_ix),
             pl.BlockSpec((1, 1, g.bv), scen_ix),
         ],
-        out_specs=pl.BlockSpec((1, g.be), lambda b, e, u: (b, e)),
-        out_shape=jax.ShapeDtypeStruct((B, g.e_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((1, g.be, g.out_lanes),
+                               lambda b, e, u: (b, e, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, g.e_pad, g.out_lanes),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x_i, x_j, mass, a, corr)
-    return out[:, :E]
+    return out[:, :E, 0]
 
 
 # -- single-tile parity references --------------------------------------------
@@ -334,7 +345,7 @@ def edge_latency_pallas_single_tile(x_i, x_j, com, block_edges: int = 128,
         ],
         out_specs=pl.BlockSpec((1, be), lambda b, e: (b, e)),
         out_shape=jax.ShapeDtypeStruct((B, x_i.shape[1]), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x_i, x_j, com)
@@ -388,7 +399,7 @@ def edge_latency_structured_pallas_single_tile(x_i, x_j, mass, a, corr,
         ],
         out_specs=pl.BlockSpec((1, be), lambda b, e: (b, e)),
         out_shape=jax.ShapeDtypeStruct((B, x_i.shape[1]), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x_i, x_j, mass, a, corr)

@@ -19,9 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["flash_attention_pallas"]
 
@@ -105,7 +104,7 @@ def flash_attention_pallas(q, k, v, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq,), jnp.float32),       # l (running denom)
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

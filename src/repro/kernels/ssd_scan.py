@@ -20,9 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["ssd_scan_pallas"]
 
@@ -106,7 +105,7 @@ def ssd_scan_pallas(x, B, C, dt, A, D, chunk: int = 128,
                                lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, chunk, H, Pd), x.dtype),
         scratch_shapes=[pltpu.VMEM((head_block, N, Pd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, Bc, Cc, dtc, Ab, Db)

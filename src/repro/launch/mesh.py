@@ -20,36 +20,19 @@ MODEL_AXIS = "model"
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (``axis_types`` and ``AxisType`` only exist ≥ 0.5)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def use_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Tried in the order the API evolved so the installed mesh is always the
-    one ``repro.models.sharding._active_mesh`` reads back: ``jax.set_mesh``
-    (≥ 0.6), ``jax.sharding.use_mesh`` (0.5.x, feeds get_abstract_mesh),
-    else the Mesh object itself (≤ 0.4, thread-resources env).
-    """
-    setter = getattr(jax, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh that
+    ``repro.models.sharding._active_mesh`` reads back."""
+    return jax.set_mesh(mesh)
 
 
 def named_shardings(mesh: jax.sharding.Mesh, spec_tree):
-    """PartitionSpec tree → NamedSharding tree.
-
-    jax < 0.5 rejects bare PartitionSpecs in jit in/out_shardings; wrapping
-    in NamedSharding works on every version.
-    """
+    """PartitionSpec tree → NamedSharding tree."""
     P = jax.sharding.PartitionSpec
     return jax.tree.map(lambda s: jax.sharding.NamedSharding(mesh, s),
                         spec_tree, is_leaf=lambda x: isinstance(x, P))

@@ -77,17 +77,9 @@ def axis_rules() -> AxisRules:
     return getattr(_local, "rules", DEFAULT_RULES)
 
 
-def _active_mesh() -> jax.sharding.Mesh | None:
-    # jax ≥ 0.5 exposes the context mesh as jax.sharding.get_abstract_mesh;
-    # on older releases fall back to the thread-resources physical mesh that
-    # `with mesh:` installs.
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        m = getter()
-    else:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
+def _active_mesh() -> jax.sharding.AbstractMesh | None:
+    """The context mesh ``use_mesh`` installed, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return None
     return m

@@ -1,6 +1,6 @@
 """Recompile and compile-time accounting via ``jax.monitoring``.
 
-jax 0.4.x fires a ``/jax/core/compile/backend_compile_duration`` event for
+JAX fires a ``/jax/core/compile/backend_compile_duration`` event for
 every XLA backend compilation — including the silent retraces a
 shape-bucket miss triggers in chunked ``score_batch`` — and nothing at all
 for compilation-cache hits.  One module-level listener (installed lazily,

@@ -18,11 +18,53 @@ from __future__ import annotations
 
 import dataclasses
 
-PEAK_FLOPS = 197e12  # bf16, per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+__all__ = ["ChipPeaks", "CHIP_PEAKS", "peaks_for",
+           "local_peaks", "RooflineTerms", "compute_terms", "PEAK_FLOPS",
+           "HBM_BW", "ICI_BW"]
 
-__all__ = ["RooflineTerms", "compute_terms", "PEAK_FLOPS", "HBM_BW", "ICI_BW"]
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops: float   # bf16 matmul FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    ici_bw: float  # chip-to-chip bytes/s per link
+
+
+# THE peak table, keyed by ``jax.Device.device_kind`` as the runtime and the
+# TPU compiler report it.  TPU v5e (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s interconnect over 4 links.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# the dry-run's target chip, and the ranking weights on the CPU backend
+_V5E = CHIP_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E.flops
+HBM_BW = _V5E.hbm_bw
+ICI_BW = _V5E.ici_bw
+
+
+def peaks_for(backend: str, device_kind: str | None) -> ChipPeaks:
+    """The table entry for ``device_kind``; a kind not in it is an error,
+    never a default.  The CPU backend keeps the v5e constants: there they
+    only weigh compute against traffic when ranking shapes, and the
+    seconds they give are not CPU speeds."""
+    if backend == "cpu":
+        return _V5E
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak entry for device kind {device_kind!r}; "
+                         f"known: {sorted(CHIP_PEAKS)}") from None
+
+
+def local_peaks() -> ChipPeaks:
+    """Peaks of this process's first JAX device."""
+    import jax
+    dev = jax.devices()[0]
+    return peaks_for(dev.platform, dev.device_kind)
 
 
 @dataclasses.dataclass

@@ -7,13 +7,13 @@ is self-referential and cheap: every query is priced BEFORE dispatch from
 
   * an **analytic FLOPs/roofline prior** — the same dominant-term counts
     ``tests/test_perf_hlo.py`` pins against compiled HLO (dense edge
-    kernel ``2·B·E·V² + B·E·V``, structured ``2·B·E·R·V + B·E·V``), run
-    through :func:`repro.perf.roofline.compute_terms` (the machinery
-    behind ``repro.obs.perfbridge``) — available for shape buckets the
-    service has never executed, WITHOUT compiling anything;
+    kernel ``2·B·E·V² + B·E·V``, structured ``2·B·E·R·V + B·E·V``) over
+    the device's peaks (:func:`repro.perf.roofline.local_peaks`, looked up
+    by ``device_kind``; an unknown TPU kind raises) — available for shape
+    buckets the service has never executed, WITHOUT compiling anything;
   * a **calibration factor** — observed/prior ratio (running median of the
-    last observations), because the prior is a hardware bound and the host
-    is not a TPU-v5e;
+    last observations), because the prior is a hardware bound (on the CPU
+    backend a v5e bound, which is no CPU speed at all);
   * **observed per-bucket p99** — once a bucket has real dispatch history
     (:class:`repro.serve.cache.BucketStats` histograms), its p99 overrides
     the prior: measured tails beat models.
@@ -32,7 +32,7 @@ import statistics
 
 import numpy as np
 
-from repro.perf.roofline import compute_terms
+from repro.perf.roofline import local_peaks
 
 __all__ = ["AdmissionConfig", "Admitted", "Degraded", "Rejected",
            "DispatchPricer", "decide"]
@@ -103,13 +103,14 @@ class DispatchPricer:
         self.V = int(n_devices)
         self.R = None if n_regions is None else int(n_regions)
         self.cfg = cfg
+        self.peaks = local_peaks()
         self._ratios: list[float] = []
 
     # -- the FLOPs/roofline prior --------------------------------------------
     def roofline_bound_s(self, n_scenarios: int, rows: int) -> float:
         """Roofline lower bound for one raw score_grid dispatch of
         ``rows`` placements × ``n_scenarios`` scenarios (perfect overlap,
-        TPU-v5e terms — a *bound*, scaled to this host by calibration)."""
+        ``self.peaks`` — a *bound*, scaled to this host by calibration)."""
         B = n_scenarios * rows
         if self.R is None:
             flops = 2.0 * B * self.E * self.V * self.V + B * self.E * self.V
@@ -121,9 +122,7 @@ class DispatchPricer:
                 + B * self.E * self.V
             bytes_ = 4.0 * (2.0 * B * self.E * self.V
                             + n_scenarios * self.E * self.R * self.V)
-        terms = compute_terms(hlo_flops=flops, hlo_bytes=bytes_,
-                              wire_bytes=0.0, chips=1, model_flops=flops)
-        return terms.step_time_s
+        return max(flops / self.peaks.flops, bytes_ / self.peaks.hbm_bw)
 
     # -- calibration from observed dispatches --------------------------------
     def observe(self, n_scenarios: int, rows: int, seconds: float) -> None:

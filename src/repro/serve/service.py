@@ -23,8 +23,10 @@ dispatch through :func:`repro.obs.jaxhooks.snapshot`.
 Every dispatch is RAW (dq = 0, β = 0): the dq-dependent part of the
 objective is closed-form (:func:`repro.search.decision.split_dq_term`), so
 per-query dq/β — scalars, per-scenario columns, whole dq grids — are
-finished on the host afterwards, float32, bitwise equal to a direct
-``score_grid`` call for the single-objective path.  Results stream back
+finished afterwards.  Single-objective score and rank queries finish on the
+device through :meth:`BatchedEvaluator.finish_grid`, bitwise equal to a
+direct ``score_grid`` call on every backend; joint dq grids and
+multi-objective scalarizations finish on the host in float32.  Results stream back
 per tenant (:meth:`~WhatIfService.poll`) as chunks complete: long queries
 yield :class:`ResultChunk` partials before the final :class:`QueryResult`.
 """
@@ -187,6 +189,7 @@ class _Pending:
     done_rows: int = 0
     lat_cols: list = dataclasses.field(default_factory=list)
     rest_cols: list = dataclasses.field(default_factory=list)
+    fin_cols: list = dataclasses.field(default_factory=list)  # device-finished
     w_lat: float = 1.0
     raw_cols: dict = dataclasses.field(default_factory=dict)
 
@@ -360,25 +363,25 @@ class WhatIfService:
         for start in range(0, batch.shape[0], self.max_chunk_rows):
             chunk = batch[start:start + self.max_chunk_rows]
             bucket = next_pow2(chunk.shape[0])
-            lat, rest, w_lat, raw = self._dispatch(
-                fleet, pad_rows(chunk, bucket), bucket,
-                n_rows=chunk.shape[0],
-                n_queries=sum(1 for _, a, b in spans
-                              if a < start + chunk.shape[0] and b > start))
             end = start + chunk.shape[0]
-            for p, a, b in spans:
+            live = [(p, a, b) for p, a, b in spans if a < end and b > start]
+            lat, rest, w_lat, raw, fins = self._dispatch(
+                fleet, pad_rows(chunk, bucket), bucket,
+                n_rows=chunk.shape[0], queries=[p for p, _, _ in live])
+            for p, a, b in live:
                 lo, hi = max(a, start), min(b, end)
-                if lo >= hi:
-                    continue
                 sl = slice(lo - start, hi - start)
                 p.lat_cols.append(lat[:, sl])
                 p.rest_cols.append(rest[:, sl])
                 p.w_lat = w_lat
                 for name, g in raw.items():
                     p.raw_cols.setdefault(name, []).append(g[:, sl])
+                if p.query_id in fins:
+                    p.fin_cols.append(fins[p.query_id][:, sl])
                 if p.query.kind != "joint":
-                    fin = finish_scores(p.lat_cols[-1], p.rest_cols[-1],
-                                        w_lat, p.query.dq, p.query.beta)
+                    fin = p.fin_cols[-1] if p.fin_cols else finish_scores(
+                        p.lat_cols[-1], p.rest_cols[-1], w_lat, p.query.dq,
+                        p.query.beta)
                     self._mail.setdefault(p.tenant, []).append(ResultChunk(
                         query_id=p.query_id, tenant=p.tenant,
                         offset=p.done_rows, scores=fin))
@@ -405,10 +408,11 @@ class WhatIfService:
 
     # -- dispatch + accounting ----------------------------------------------
     def _dispatch(self, fleet: _Fleet, padded: np.ndarray, bucket: int,
-                  n_rows: int, n_queries: int):
+                  n_rows: int, queries: list[_Pending]):
         """ONE raw score_grid call (dq = 0, β = 0) over the padded chunk;
         returns host-side float32 (lat, rest, w_lat, raw per-objective
-        grids) with padding rows already sliced off."""
+        grids, device-finished scores by query id) with padding rows
+        already sliced off."""
         snap = jaxhooks.snapshot()
         t0 = time.perf_counter()
         out = self._ev.score_grid(padded, fleet.pack, dq=0.0, beta=0.0,
@@ -422,8 +426,17 @@ class WhatIfService:
                                  weights=out.weights)
             raw = {n: np.asarray(g, dtype=np.float32)[:, :n_rows]
                    for n, g in out.grids.items()}
+            fins = {}
         else:
-            out = jax.device_get(out)
+            # each score/rank query's dq/β finish runs on the device over
+            # the whole padded chunk (elementwise, so any column slice is
+            # the query's own), all fetched in one transfer with the grid
+            fins = {p.query_id: self._ev.finish_grid(out, p.query.dq,
+                                                     p.query.beta)
+                    for p in queries if p.query.kind != "joint"}
+            out, fins = jax.device_get((out, fins))
+            fins = {k: np.asarray(v, dtype=np.float32)[:, :n_rows]
+                    for k, v in fins.items()}
             raw = {}
         seconds = time.perf_counter() - t0
         recompiles, compile_s = snap.delta()
@@ -431,14 +444,14 @@ class WhatIfService:
         lat = np.asarray(lat, dtype=np.float32)[:, :n_rows]
         rest = np.asarray(rest, dtype=np.float32)[:, :n_rows]
         self.stats.bucket(bucket).observe(
-            seconds, n_rows=n_rows, n_padded=bucket, n_queries=n_queries,
+            seconds, n_rows=n_rows, n_padded=bucket, n_queries=len(queries),
             n_recompiles=recompiles, compile_s=compile_s)
         # calibrate the pricer on warm execution time only — compile cost
         # is a one-off the executable cache amortizes away, not a per-
         # dispatch price
         fleet.pricer.observe(fleet.n_scenarios, bucket,
                              max(seconds - compile_s, 0.0))
-        return lat, rest, w_lat, raw
+        return lat, rest, w_lat, raw, fins
 
     # -- per-kind finalization ----------------------------------------------
     def _finalize(self, fleet: _Fleet, p: _Pending) -> QueryResult:
@@ -458,7 +471,8 @@ class WhatIfService:
                 scores=scores, grids=raw or None, degraded=p.degraded,
                 best=best, dq_idx=dq_idx, worst=worst,
                 infeasible=bool(np.isinf(worst[best])))
-        scores = finish_scores(lat, rest, p.w_lat, q.dq, q.beta)
+        scores = (np.concatenate(p.fin_cols, axis=1) if p.fin_cols
+                  else finish_scores(lat, rest, p.w_lat, q.dq, q.beta))
         # per-objective finished grids: only latency_f carries the dq term
         grids = None
         if raw:

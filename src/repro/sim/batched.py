@@ -260,6 +260,16 @@ class BatchedEvaluator:
         dq = jnp.broadcast_to(jnp.asarray(dq, lat.dtype), (S,))
         return lat / (1.0 + beta * dq[:, None])
 
+    def finish_grid(self, lat, dq=0.0, beta: float = 0.0) -> jnp.ndarray:
+        """Finish a raw (dq = 0, β = 0) single-objective (S, P) grid on the
+        device with the ops ``score_grid`` runs in its own dispatch, so the
+        result is bitwise ``score_grid(..., dq=dq, beta=beta)`` on every
+        backend.  The host cannot stand in: a TPU's f32 division is not
+        IEEE-rounded and its ``1 + β·dq`` is not fused."""
+        lat = jnp.asarray(lat)
+        return _jit_finish_grid(lat, self._validate_dq(dq, lat.shape[0]),
+                                float(beta))
+
     # -- structured batched math (RegionFleetFamily scenarios) ---------------
     @staticmethod
     def _layout_key(fam: RegionFleetFamily) -> tuple:
@@ -550,3 +560,8 @@ class BatchedEvaluator:
         return ObjectiveGrids(names=obj_set.names,
                               grids={n: grids[n] for n in obj_set.names},
                               scalarized=scal, weights=obj_set.weights)
+
+
+_jit_finish_grid = jax.jit(
+    lambda lat, dq, beta: BatchedEvaluator._finish_grid(lat, lat.shape[0],
+                                                        dq, beta))

@@ -32,18 +32,48 @@ stats` reports them unconditionally for the serving layer's per-bucket
 accounting.  :func:`fresh_cache` scopes an isolated cache — tests and the
 ``bench_serve`` dedicated-evaluator baseline use it to measure exactly the
 per-consumer recompilation this module deletes.
+
+Across processes, :func:`enable_persistent_cache` turns on JAX's on-disk
+compilation cache at one fixed directory, so a second run of the same
+checkout loads its executables instead of compiling them.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import os
 import threading
+from pathlib import Path
+
+import jax
 
 from repro import obs
 
 __all__ = ["ExecutableCache", "executable_cache", "set_executable_cache",
-           "fresh_cache", "graph_key"]
+           "fresh_cache", "graph_key", "enable_persistent_cache",
+           "CHECKOUT_CACHE_DIR"]
+
+#: the persistent cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: fixed inside the checkout (and git-ignored), because the path is part of
+#: what a later run must find again
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory: JAX reads it
+    itself and no other is set here.  Otherwise the cache goes to
+    :data:`CHECKOUT_CACHE_DIR`.  Every compile is cached, however quick.
+    Call it from an entry point, never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def graph_key(graph) -> tuple:

@@ -1,7 +1,11 @@
 """Process-wide executable cache (repro.sim.execache): LRU semantics, the
 cross-instance recompile regression the cache exists to kill, and
-fresh_cache isolation."""
+fresh_cache isolation, and the fixed home of the persistent compile
+cache."""
 
+from pathlib import Path
+
+import jax
 import numpy as np
 import pytest
 
@@ -93,3 +97,38 @@ def test_distinct_configs_do_not_collide():
         alpha = np.asarray(
             BatchedEvaluator(g, CostConfig(alpha=1.0)).score_grid(xs, coms))
     assert not np.array_equal(plain, alpha)
+
+
+@pytest.fixture
+def cache_config():
+    """Restore JAX's persistent-cache settings after the test."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def test_persistent_cache_honors_env_dir(monkeypatch, tmp_path,
+                                         cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set the helper returns it and sets no
+    directory of its own."""
+    from repro.sim.execache import enable_persistent_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_persistent_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_persistent_cache_defaults_to_fixed_checkout_dir(monkeypatch,
+                                                         cache_config):
+    """Without the variable every call lands on the same directory inside
+    the checkout (never a temp, pid- or time-based path)."""
+    from repro.sim.execache import CHECKOUT_CACHE_DIR, enable_persistent_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = enable_persistent_cache()
+    assert first == enable_persistent_cache() == str(CHECKOUT_CACHE_DIR)
+    assert jax.config.jax_compilation_cache_dir == first
+    assert CHECKOUT_CACHE_DIR.parent == Path(__file__).resolve().parents[1]

@@ -16,6 +16,7 @@ from repro.core.graph import linear_graph
 from repro.kernels import autotune, dispatch
 from repro.kernels.autotune import KernelConfig, ShapeKey
 from repro.obs.registry import MetricsRegistry, set_registry
+from repro.perf import roofline
 from repro.sim.batched import BatchedEvaluator, pack_fleets, pack_placements
 from repro.serve.service import WhatIfService
 
@@ -35,6 +36,9 @@ def metrics():
     set_registry(reg)
     yield reg
     set_registry(old)
+
+
+V5E = "TPU v5 lite"
 
 
 def _counter_total(reg, name):
@@ -74,7 +78,8 @@ def test_plan_auto_on_cpu_is_xla():
     assert plan.impl == "xla" and plan.interpret and plan.config is None
 
 
-def test_plan_pallas_config_fits_vmem_budget():
+def test_plan_pallas_config_fits_vmem_budget(monkeypatch):
+    monkeypatch.setattr(dispatch, "local_device_kind", lambda: V5E)
     plan = dispatch.plan_edge_kernel("dense", 4, 24, 8192, use_pallas=True,
                                      backend="tpu")
     assert plan.impl == "pallas" and not plan.interpret
@@ -116,6 +121,25 @@ def test_candidates_all_fit_budget_and_dedupe():
         geoms.add((g.be, g.bv))
 
 
+def test_candidates_raise_when_nothing_fits_vmem():
+    """No silent fallback to tiles that cannot lower: at an R whose (R, bv)
+    tile alone overflows VMEM, not even the smallest block shape fits."""
+    with pytest.raises(ValueError, match="VMEM"):
+        autotune.candidate_configs("structured", 24, 4096, 10 ** 6)
+
+
+def test_unknown_tpu_kind_has_no_peaks():
+    """A TPU missing from the peak table is an error, for the table itself
+    and for the tuner that ranks by it; the CPU branch needs no entry."""
+    with pytest.raises(ValueError, match="no peak entry"):
+        roofline.peaks_for("tpu", "TPU v0 imaginary")
+    with pytest.raises(ValueError, match="no peak entry"):
+        autotune.get_config("dense", 4, 24, 1024, backend="tpu",
+                            device_kind="TPU v0 imaginary")
+    assert roofline.peaks_for("tpu", V5E) == roofline.CHIP_PEAKS[V5E]
+    assert roofline.peaks_for("cpu", None).flops == roofline.PEAK_FLOPS
+
+
 def test_cpu_model_prefers_fewer_grid_steps():
     """On CPU (interpret mode) per-step overhead dominates, so the model
     must rank a one-tile config above many small tiles."""
@@ -151,7 +175,8 @@ def test_empirical_timer_overrides_analytic_ranking():
 
 def test_table_round_trips_through_json(tmp_path):
     autotune.get_config("dense", 4, 24, 1024, backend="cpu")
-    autotune.get_config("structured", 2, 12, 131072, 8, backend="tpu")
+    autotune.get_config("structured", 2, 12, 131072, 8, backend="tpu",
+                        device_kind=V5E)
     path = tmp_path / "table.json"
     autotune.save_table(path)
     rows_before = autotune.table_rows()

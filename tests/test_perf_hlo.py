@@ -92,7 +92,8 @@ def test_roofline_terms_and_dominance():
 # around that dot — exact equality would re-pin XLA's deterministic but
 # version-dependent accounting of the elementwise mask/mul/max tail, which
 # is O(1/v_pad) of the dot.  HBM bytes only as >= the PADDED I/O lower
-# bound, since interpret-mode Pallas lowering adds interpreter traffic.
+# bound, since interpret-mode Pallas lowering adds interpreter traffic;
+# the output term is the kernels' lane-dense (B, e_pad, out_lanes) block.
 
 _B, _E, _V, _R = 2, 6, 8, 4
 
@@ -122,7 +123,7 @@ def test_dense_edge_latency_kernel_flops_pinned():
     assert lo <= s.flops <= hi
     # I/O floor: padded x_i + x_j + com + out, f32
     io_floor = 4 * (2 * _B * g.e_pad * g.v_pad + g.v_pad * g.v_pad
-                    + _B * g.e_pad)
+                    + _B * g.e_pad * g.out_lanes)
     assert s.hbm_bytes >= io_floor
 
 
@@ -140,7 +141,8 @@ def test_structured_edge_latency_kernel_flops_pinned():
                          _B * g.e_pad * g.v_pad)
     assert lo <= s.flops <= hi
     io_floor = 4 * (2 * _B * g.e_pad * g.v_pad + _B * g.e_pad * g.r_pad
-                    + g.r_pad * g.v_pad + g.v_pad + _B * g.e_pad)
+                    + g.r_pad * g.v_pad + g.v_pad
+                    + _B * g.e_pad * g.out_lanes)
     assert s.hbm_bytes >= io_floor
 
 
