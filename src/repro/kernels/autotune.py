@@ -49,7 +49,7 @@ from repro.perf.roofline import peaks_for
 
 __all__ = ["KernelConfig", "ShapeKey", "DEFAULT_CONFIG", "VMEM_BUDGET_BYTES",
            "candidate_configs", "vmem_bytes", "predict_seconds", "rank",
-           "get_config", "table_rows", "save_table", "load_table",
+           "local_device_kind", "get_config", "table_rows", "save_table", "load_table",
            "clear_table"]
 
 BYTES_F32 = 4
@@ -191,6 +191,12 @@ def rank(kind: str, B: int, E: int, V: int, R: int | None = None,
                        -c.block_v, -c.block_edges))
 
 
+def local_device_kind(backend: str) -> str:
+    """``device_kind`` of ``backend``'s first device (e.g. "TPU v5 lite"):
+    the key the tuner looks its peaks up by."""
+    return jax.devices(backend)[0].device_kind
+
+
 def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
                com_batch: int = 1, backend: str | None = None,
                device_kind: str | None = None, timer=None) -> KernelConfig:
@@ -213,7 +219,7 @@ def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
                         source="table", backend=backend).add(1)
         return hit[0]
     if backend != "cpu" and device_kind is None:
-        device_kind = jax.devices(backend)[0].device_kind
+        device_kind = local_device_kind(backend)
     ranked = rank(kind, key.b_bucket, E, V, R, com_batch=com_batch,
                   backend=backend, device_kind=device_kind)
     best, source = ranked[0], "analytic"

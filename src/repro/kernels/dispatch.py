@@ -37,21 +37,14 @@ from repro.kernels import autotune
 from repro.kernels.edge_latency import (edge_latency_pallas,
                                         edge_latency_structured_pallas)
 
-__all__ = ["backend_name", "local_device_kind", "resolve_flags",
-           "KernelPlan", "plan_edge_kernel", "edge_latency",
-           "edge_latency_structured"]
+__all__ = ["backend_name", "resolve_flags", "KernelPlan", "plan_edge_kernel",
+           "edge_latency", "edge_latency_structured"]
 
 
 def backend_name() -> str:
     """The active JAX backend ("cpu", "tpu", "gpu"); the dispatch policy
     keys off this, never off caller-supplied booleans alone."""
     return jax.default_backend()
-
-
-def local_device_kind() -> str:
-    """``device_kind`` of the first JAX device (e.g. "TPU v5 lite"): the
-    key the tuner looks its peaks up by."""
-    return jax.devices()[0].device_kind
 
 
 def resolve_flags(use_pallas: bool | None = None,
@@ -115,9 +108,8 @@ def plan_edge_kernel(kind: str, B: int, E: int, V: int, R: int | None = None,
             block_v=block_v if block_v is not None else dflt.block_v)
         plan = KernelPlan(impl="pallas", interpret=interpret_r, config=cfg)
     else:
-        cfg = autotune.get_config(
-            kind, B, E, V, R, com_batch=com_batch, backend=backend,
-            device_kind=None if backend == "cpu" else local_device_kind())
+        cfg = autotune.get_config(kind, B, E, V, R, com_batch=com_batch,
+                                  backend=backend)
         plan = KernelPlan(impl="pallas", interpret=interpret_r, config=cfg)
     reg = obs.registry()
     if reg.enabled:

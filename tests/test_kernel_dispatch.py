@@ -79,7 +79,7 @@ def test_plan_auto_on_cpu_is_xla():
 
 
 def test_plan_pallas_config_fits_vmem_budget(monkeypatch):
-    monkeypatch.setattr(dispatch, "local_device_kind", lambda: V5E)
+    monkeypatch.setattr(autotune, "local_device_kind", lambda backend: V5E)
     plan = dispatch.plan_edge_kernel("dense", 4, 24, 8192, use_pallas=True,
                                      backend="tpu")
     assert plan.impl == "pallas" and not plan.interpret

@@ -117,7 +117,8 @@ def tpu_dispatch(chip, monkeypatch):
     """Steer the dispatch policy onto the compiled-Pallas route that a TPU
     process takes, inside an isolated executable cache."""
     monkeypatch.setattr(dispatch, "backend_name", lambda: "tpu")
-    monkeypatch.setattr(dispatch, "local_device_kind", lambda: chip[1])
+    monkeypatch.setattr(autotune, "local_device_kind",
+                        lambda backend: chip[1])
     with fresh_cache():
         yield chip[0]
 
