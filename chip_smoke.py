@@ -13,7 +13,9 @@ both scored for one 16-operator DAG.  It registers both with one
 and then serves the same queries again warm.  It checks that
 
   * every served answer is bitwise what direct ``score_grid`` calls on the
-    same evaluator give (and the warm pass repeats the cold one bitwise);
+    same evaluator give, at each query's own dq and β — scores, the pareto
+    queries' per-objective grids and scalarization, and the joint dq grid
+    (and the warm pass repeats the cold one bitwise);
   * ``ORACLE_PAIRS`` (scenario, placement) pairs per deployment agree with
     the float64 oracle in ``repro.core.costmodel`` within ``ORACLE_RTOL``;
   * the edge kernels ran as compiled Pallas: ``kernels.dispatch.plans``
@@ -169,8 +171,6 @@ def make_queries(rng: np.random.Generator, graph, dep: Deployment,
         if kind == "joint":
             q = WhatIfQuery(kind="joint", placements=x, beta=JOINT_BETA,
                             dq_values=JOINT_DQ)
-        elif kind == "pareto":
-            q = WhatIfQuery(kind="pareto", placements=x)
         else:
             # scalar and per-scenario dq, β on and off
             dq = (np.linspace(0.1, 0.8, n_scenarios).astype(np.float32)
@@ -217,7 +217,8 @@ def check_parity(graph, dep: Deployment, served: list[Served]) -> int:
     for s in served:
         q = s.query
         if q.kind == "pareto":
-            og = ev.score_grid(q.placements, dep.pack, objectives=OBJECTIVES)
+            og = ev.score_grid(q.placements, dep.pack, dq=q.dq, beta=q.beta,
+                               objectives=OBJECTIVES)
             calls.append((og.grids, og.scalarized))
         elif q.kind == "joint":     # raw grid, finished over JOINT_DQ below
             calls.append(ev.score_grid(q.placements, dep.pack))
@@ -228,9 +229,15 @@ def check_parity(graph, dep: Deployment, served: list[Served]) -> int:
         q, res = s.query, s.result
         where = f"{dep.name} {q.kind} query {s.query_id}"
         if q.kind == "pareto":
+            grids, scal = direct
+            for name in OBJECTIVES.names:
+                np.testing.assert_array_equal(res.grids[name], grids[name],
+                                              err_msg=f"{where}: {name}")
+            np.testing.assert_array_equal(res.scores, scal,
+                                          err_msg=f"{where}: scalarized")
             want = pareto_front(ObjectiveGrids(
-                names=OBJECTIVES.names, grids=direct[0],
-                scalarized=direct[1], weights=OBJECTIVES.weights))
+                names=OBJECTIVES.names, grids=grids, scalarized=scal,
+                weights=OBJECTIVES.weights))
             np.testing.assert_array_equal(res.front.indices, want.indices,
                                           err_msg=f"{where}: front")
         elif q.kind == "joint":
@@ -312,6 +319,7 @@ def run_phase(svc, graph, dep: Deployment, seed: int, shape: Shape,
     cold = serve_round(svc, dep, fids, queries)
     cold_s = time.perf_counter() - t0
     n_compiles, compile_s = snap.delta()
+    n_loads = snap.cache_loads()
     t0 = time.perf_counter()
     warm = serve_round(svc, dep, fids, queries)
     warm_s = time.perf_counter() - t0
@@ -322,7 +330,8 @@ def run_phase(svc, graph, dep: Deployment, seed: int, shape: Shape,
              "scenarios": S, "queries": len(cold),
              "rows": int(sum(s.query.placements.shape[0] for s in cold)),
              "cold_s": cold_s, "compile_s": compile_s,
-             "compiles": n_compiles, "warm_s": warm_s,
+             "compiles": n_compiles, "cache_loads": n_loads,
+             "warm_s": warm_s,
              "bitwise_parity_queries": n_parity,
              "oracle_pairs": ORACLE_PAIRS, "oracle_max_rel": oracle_rel,
              "oracle_rtol": ORACLE_RTOL}

@@ -3,12 +3,12 @@
 Heterogeneous tenant queries coalesce only when they can share a dispatch.
 Two facts make that sharing wide instead of narrow:
 
-  * **dq/β are analytic, not traced.**  Every query is dispatched RAW
-    (dq = 0, β = 0, exactly like ``repro.search.engine``): only latency-F
-    depends on dq, through the closed-form ``/(1 + β·dq)`` factor, so
-    queries with *different* dq values, dq grids, and β coexist in one
-    super-batch and get their own finish on the host afterwards
-    (:func:`finish_scores`).
+  * **dq/β are operands, not shapes.**  Only latency-F depends on dq,
+    through the closed-form ``/(1 + β·dq)`` factor, and ``score_grid``
+    takes dq per cell and β per row, so queries with *different* dq
+    values and β coexist in one super-batch and each row is finished with
+    its own.  Joint queries ride along raw (dq = 0, β = 0, exactly like
+    ``repro.search.engine``) and expand their dq grid on the host.
   * **rows are independent.**  ``score_grid`` vmaps over the placement
     axis, so concatenating tenants' candidate rows — and padding with
     repeated rows up to a power-of-two bucket — changes nothing about any
@@ -35,8 +35,7 @@ from repro.core.devices import RegionFleetFamily
 from repro.core.objectives import ObjectiveSet
 from repro.sim.execache import graph_key
 
-__all__ = ["CoalesceKey", "dq_denominator", "fleet_digest", "next_pow2",
-           "pad_rows", "finish_scores"]
+__all__ = ["CoalesceKey", "fleet_digest", "next_pow2", "pad_rows"]
 
 
 def next_pow2(n: int) -> int:
@@ -77,7 +76,7 @@ class CoalesceKey:
     ``fleet`` (the registration-time content digest) pins the scenario
     operands, ``objectives`` pins the multi-objective executable (None =
     the single-objective latency grid).  dq/β are deliberately ABSENT —
-    they are applied analytically per query after the dispatch."""
+    they are per-row operands of the shared dispatch."""
 
     graph: tuple
     cfg: CostConfig
@@ -107,35 +106,3 @@ def pad_rows(xs: np.ndarray, bucket: int) -> np.ndarray:
         return xs
     return np.concatenate([xs, np.repeat(xs[-1:], pad, axis=0)])
 
-
-def dq_denominator(dq, beta: float, n_scenarios: int) -> np.ndarray:
-    """The (S, 1) float32 column ``1 + β·dq``, computed EXACTLY as the
-    compiled dispatch computes it: XLA fuses the multiply-add into an FMA
-    (one rounding of the exact β·dq + 1), which numpy's two-rounding
-    ``f32(f32(β·dq) + 1)`` misses by 1 ulp on ~⅓ of operands.  Emulated
-    here via float64 — the f32×f32 product is exact in double, the +1 sum
-    rounds once to f32 — so the host finish divides by the bitwise-same
-    denominator the device would."""
-    dq_col = np.broadcast_to(
-        np.asarray(dq, dtype=np.float32), (n_scenarios,))[:, None]
-    return (np.float64(np.float32(beta)) * dq_col.astype(np.float64)
-            + 1.0).astype(np.float32)
-
-
-def finish_scores(lat: np.ndarray, rest: np.ndarray, w_lat: float,
-                  dq, beta: float) -> np.ndarray:
-    """Apply one query's dq/β finish to its slice of the raw grids:
-    ``rest + w_lat · lat / (1 + β·dq)`` with dq a scalar or per-scenario
-    (S,) column.
-
-    Arithmetic is float32 in the dispatch's own op order (FMA included,
-    see :func:`dq_denominator`) — so a served single-objective score is
-    BITWISE what a direct ``score_grid(..., dq=dq, beta=beta)`` computes
-    on device (IEEE-754 divide is exactly rounded on both sides; gated in
-    ``tests/test_serve.py`` and ``bench_serve``)."""
-    lat32 = np.asarray(lat, dtype=np.float32)
-    denom = dq_denominator(dq, beta, lat32.shape[0])
-    # w_lat = 1 / rest = 0 (the single-objective path) are bitwise no-ops:
-    # ×1.0f and +0.0f are exact, so this one expression serves both cases
-    return np.asarray(rest, dtype=np.float32) \
-        + np.float32(w_lat) * lat32 / denom

@@ -4,9 +4,8 @@ Query lifecycle (see serve/README.md for the diagram)::
 
     submit ── normalize ── bucket ── admit ─┬─ Rejected (typed, priced)
                                             └─ queue[CoalesceKey]
-    step ──── coalesce queues ── pad to 2^k ── ONE raw dispatch per chunk
-                 └── per-query analytic dq/β finish ── stream ResultChunks
-                                                        └── final QueryResult
+    step ──── coalesce queues ── pad to 2^k ── ONE dispatch per chunk,
+                 per-column dq/β ── stream ResultChunks ── final QueryResult
 
 Tenants :meth:`~WhatIfService.register_fleet` scenario packs once (content
 digest → equal fleets coalesce across tenants), then
@@ -20,15 +19,15 @@ runs through a handful of compiled executables — resolved via the
 process-wide :mod:`repro.sim.execache`, with recompiles attributed per
 dispatch through :func:`repro.obs.jaxhooks.snapshot`.
 
-Every dispatch is RAW (dq = 0, β = 0): the dq-dependent part of the
-objective is closed-form (:func:`repro.search.decision.split_dq_term`), so
-per-query dq/β — scalars, per-scenario columns, whole dq grids — are
-finished afterwards.  Single-objective score and rank queries finish on the
-device through :meth:`BatchedEvaluator.finish_grid`, bitwise equal to a
-direct ``score_grid`` call on every backend; joint dq grids and
-multi-objective scalarizations finish on the host in float32.  Results stream back
-per tenant (:meth:`~WhatIfService.poll`) as chunks complete: long queries
-yield :class:`ResultChunk` partials before the final :class:`QueryResult`.
+Each chunk is ONE ``score_grid`` dispatch whose dq is per cell (S, rows)
+and β per row, so every query's own dq/β — scalar or per-scenario —
+finishes on the device with the ops a direct ``score_grid`` call runs:
+served scores and per-objective grids are bitwise that call's on every
+backend.  Joint rows ride the same dispatch raw (dq = 0, β = 0); their dq
+grid is closed-form (:func:`repro.search.decision.split_dq_term`) and
+expands on the host.  Results stream back per tenant
+(:meth:`~WhatIfService.poll`) as chunks complete: long queries yield
+:class:`ResultChunk` partials before the final :class:`QueryResult`.
 """
 
 from __future__ import annotations
@@ -51,8 +50,7 @@ from repro.search.decision import (dq_caps_mask, epsilon_constraint,
                                    robust_select, split_dq_term)
 from repro.serve.admission import (AdmissionConfig, Admitted, Degraded,
                                    DispatchPricer, Rejected, decide)
-from repro.serve.bucketing import (CoalesceKey, dq_denominator,
-                                   finish_scores, fleet_digest, next_pow2,
+from repro.serve.bucketing import (CoalesceKey, fleet_digest, next_pow2,
                                    pad_rows)
 from repro.serve.cache import ServeStats
 from repro.sim.batched import BatchedEvaluator
@@ -177,7 +175,8 @@ class _Fleet:
 @dataclasses.dataclass
 class _Pending:
     """An admitted query waiting in (or mid-flight through) its key's
-    queue, accumulating raw host-side grid columns chunk by chunk."""
+    queue, accumulating its host-side grid columns chunk by chunk (finished,
+    or raw for joint queries)."""
 
     query_id: int
     tenant: str
@@ -187,11 +186,8 @@ class _Pending:
     predicted_s: float
     degraded: Degraded | None
     done_rows: int = 0
-    lat_cols: list = dataclasses.field(default_factory=list)
-    rest_cols: list = dataclasses.field(default_factory=list)
-    fin_cols: list = dataclasses.field(default_factory=list)  # device-finished
-    w_lat: float = 1.0
-    raw_cols: dict = dataclasses.field(default_factory=dict)
+    score_cols: list = dataclasses.field(default_factory=list)
+    grid_cols: dict = dataclasses.field(default_factory=dict)
 
     @property
     def rows(self) -> int:
@@ -344,7 +340,7 @@ class WhatIfService:
     # -- the serving loop (coalesce → pad → dispatch → stream) ---------------
     def step(self) -> int:
         """Serve the oldest non-empty coalesce queue: merge its pending
-        queries into one super-batch, dispatch it RAW in ≤max_chunk_rows
+        queries into one super-batch, dispatch it in ≤max_chunk_rows
         power-of-two chunks, stream each chunk's finished scores to tenant
         mailboxes, finalize completed queries.  Returns the number of
         queries completed (0 = nothing pending)."""
@@ -364,28 +360,20 @@ class WhatIfService:
             chunk = batch[start:start + self.max_chunk_rows]
             bucket = next_pow2(chunk.shape[0])
             end = start + chunk.shape[0]
-            live = [(p, a, b) for p, a, b in spans if a < end and b > start]
-            lat, rest, w_lat, raw, fins = self._dispatch(
-                fleet, pad_rows(chunk, bucket), bucket,
-                n_rows=chunk.shape[0], queries=[p for p, _, _ in live])
-            for p, a, b in live:
-                lo, hi = max(a, start), min(b, end)
-                sl = slice(lo - start, hi - start)
-                p.lat_cols.append(lat[:, sl])
-                p.rest_cols.append(rest[:, sl])
-                p.w_lat = w_lat
-                for name, g in raw.items():
-                    p.raw_cols.setdefault(name, []).append(g[:, sl])
-                if p.query_id in fins:
-                    p.fin_cols.append(fins[p.query_id][:, sl])
+            # each live query's columns within the chunk
+            live = [(p, slice(max(a, start) - start, min(b, end) - start))
+                    for p, a, b in spans if a < end and b > start]
+            scores, grids = self._dispatch(
+                fleet, pad_rows(chunk, bucket), bucket, live)
+            for p, sl in live:
+                p.score_cols.append(scores[:, sl])
+                for name, g in grids.items():
+                    p.grid_cols.setdefault(name, []).append(g[:, sl])
                 if p.query.kind != "joint":
-                    fin = p.fin_cols[-1] if p.fin_cols else finish_scores(
-                        p.lat_cols[-1], p.rest_cols[-1], w_lat, p.query.dq,
-                        p.query.beta)
                     self._mail.setdefault(p.tenant, []).append(ResultChunk(
                         query_id=p.query_id, tenant=p.tenant,
-                        offset=p.done_rows, scores=fin))
-                p.done_rows += hi - lo
+                        offset=p.done_rows, scores=scores[:, sl]))
+                p.done_rows += sl.stop - sl.start
                 if p.done_rows == p.rows:
                     self._mail.setdefault(p.tenant, []).append(
                         self._finalize(fleet, p))
@@ -408,77 +396,63 @@ class WhatIfService:
 
     # -- dispatch + accounting ----------------------------------------------
     def _dispatch(self, fleet: _Fleet, padded: np.ndarray, bucket: int,
-                  n_rows: int, queries: list[_Pending]):
-        """ONE raw score_grid call (dq = 0, β = 0) over the padded chunk;
-        returns host-side float32 (lat, rest, w_lat, raw per-objective
-        grids, device-finished scores by query id) with padding rows
-        already sliced off."""
+                  live: list[tuple[_Pending, slice]]):
+        """ONE score_grid call over the padded chunk, each query's columns
+        finished with its own dq/β (joint and padding columns raw: dq = 0,
+        β = 0); returns host-side float32 (scalar scores, per-objective
+        grids), both (S, bucket)."""
+        dq = np.zeros((fleet.n_scenarios, bucket), np.float32)
+        beta = np.zeros(bucket, np.float32)
+        for p, sl in live:
+            if p.query.kind != "joint":
+                dq[:, sl] = np.broadcast_to(np.asarray(
+                    p.query.dq, np.float32), (fleet.n_scenarios,))[:, None]
+                beta[sl] = p.query.beta
+        n_rows = sum(sl.stop - sl.start for _, sl in live)
         snap = jaxhooks.snapshot()
         t0 = time.perf_counter()
-        out = self._ev.score_grid(padded, fleet.pack, dq=0.0, beta=0.0,
+        out = self._ev.score_grid(padded, fleet.pack, dq=dq, beta=beta,
                                   objectives=fleet.objectives)
+        # one host transfer for the whole chunk
         if isinstance(out, ObjectiveGrids):
-            # one host transfer for the whole chunk (grids + scalarized)
-            host = jax.device_get({"grids": dict(out.grids),
-                                   "scal": out.scalarized})
-            out = ObjectiveGrids(names=out.names, grids=host["grids"],
-                                 scalarized=host["scal"],
-                                 weights=out.weights)
-            raw = {n: np.asarray(g, dtype=np.float32)[:, :n_rows]
-                   for n, g in out.grids.items()}
-            fins = {}
+            scores, grids = jax.device_get((out.scalarized, dict(out.grids)))
         else:
-            # each score/rank query's dq/β finish runs on the device over
-            # the whole padded chunk (elementwise, so any column slice is
-            # the query's own), all fetched in one transfer with the grid
-            fins = {p.query_id: self._ev.finish_grid(out, p.query.dq,
-                                                     p.query.beta)
-                    for p in queries if p.query.kind != "joint"}
-            out, fins = jax.device_get((out, fins))
-            fins = {k: np.asarray(v, dtype=np.float32)[:, :n_rows]
-                    for k, v in fins.items()}
-            raw = {}
+            scores, grids = jax.device_get(out), {}
         seconds = time.perf_counter() - t0
         recompiles, compile_s = snap.delta()
-        lat, rest, w_lat = split_dq_term(out)
-        lat = np.asarray(lat, dtype=np.float32)[:, :n_rows]
-        rest = np.asarray(rest, dtype=np.float32)[:, :n_rows]
         self.stats.bucket(bucket).observe(
-            seconds, n_rows=n_rows, n_padded=bucket, n_queries=len(queries),
+            seconds, n_rows=n_rows, n_padded=bucket, n_queries=len(live),
             n_recompiles=recompiles, compile_s=compile_s)
         # calibrate the pricer on warm execution time only — compile cost
         # is a one-off the executable cache amortizes away, not a per-
         # dispatch price
         fleet.pricer.observe(fleet.n_scenarios, bucket,
                              max(seconds - compile_s, 0.0))
-        return lat, rest, w_lat, raw, fins
+        return np.asarray(scores, np.float32), {
+            n: np.asarray(g, np.float32) for n, g in grids.items()}
 
     # -- per-kind finalization ----------------------------------------------
     def _finalize(self, fleet: _Fleet, p: _Pending) -> QueryResult:
         q = p.query
-        lat = np.concatenate(p.lat_cols, axis=1)     # (S, P) float32
-        rest = np.concatenate(p.rest_cols, axis=1)
-        raw = {n: np.concatenate(cols, axis=1)
-               for n, cols in p.raw_cols.items()}
+        scores = np.concatenate(p.score_cols, axis=1)     # (S, P) float32
+        grids = {n: np.concatenate(cols, axis=1)
+                 for n, cols in p.grid_cols.items()} or None
         if q.kind == "joint":
+            raw = scores if grids is None else ObjectiveGrids(
+                names=fleet.objectives.names, grids=grids,
+                scalarized=scores, weights=fleet.objectives.weights)
+            lat, rest, w_lat = split_dq_term(raw)
             feas = dq_caps_mask(p.placements, p.dq_values, q.coupling)
             scores, dq_idx = joint_dq_scores(
-                lat, np.atleast_1d(p.dq_values), q.beta, rest=rest,
-                w_lat=p.w_lat, feasible=feas)
+                lat.astype(np.float32), np.atleast_1d(p.dq_values), q.beta,
+                rest=rest.astype(np.float32),
+                w_lat=w_lat, feasible=feas)
             best, worst = robust_select(scores)
             return QueryResult(
                 query_id=p.query_id, tenant=p.tenant, kind=q.kind,
-                scores=scores, grids=raw or None, degraded=p.degraded,
+                scores=scores, grids=grids, degraded=p.degraded,
                 best=best, dq_idx=dq_idx, worst=worst,
                 infeasible=bool(np.isinf(worst[best])))
-        scores = (np.concatenate(p.fin_cols, axis=1) if p.fin_cols
-                  else finish_scores(lat, rest, p.w_lat, q.dq, q.beta))
-        # per-objective finished grids: only latency_f carries the dq term
-        grids = None
-        if raw:
-            denom = dq_denominator(q.dq, q.beta, lat.shape[0])
-            grids = {n: (g / denom if n == "latency_f" else g)
-                     for n, g in raw.items()}
         if q.kind == "score":
             return QueryResult(query_id=p.query_id, tenant=p.tenant,
                                kind=q.kind, scores=scores, grids=grids,

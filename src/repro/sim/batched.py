@@ -255,20 +255,18 @@ class BatchedEvaluator:
         return self._finish_grid(lat, coms.shape[0], dq, beta)
 
     @staticmethod
-    def _finish_grid(lat: jnp.ndarray, S: int, dq, beta) -> jnp.ndarray:
-        """(S, P) latencies → objectives; dq scalar or per-scenario (S,)."""
-        dq = jnp.broadcast_to(jnp.asarray(dq, lat.dtype), (S,))
-        return lat / (1.0 + beta * dq[:, None])
+    def _dq_cells(dq, S: int, dtype=jnp.float32) -> jnp.ndarray:
+        """dq against an (S, P) grid: a scalar or per-scenario (S,) dq
+        becomes an (S, 1) column, a per-cell (S, P) dq is used as is."""
+        dq = jnp.asarray(dq, dtype)
+        return dq if dq.ndim == 2 else jnp.broadcast_to(dq, (S,))[:, None]
 
-    def finish_grid(self, lat, dq=0.0, beta: float = 0.0) -> jnp.ndarray:
-        """Finish a raw (dq = 0, β = 0) single-objective (S, P) grid on the
-        device with the ops ``score_grid`` runs in its own dispatch, so the
-        result is bitwise ``score_grid(..., dq=dq, beta=beta)`` on every
-        backend.  The host cannot stand in: a TPU's f32 division is not
-        IEEE-rounded and its ``1 + β·dq`` is not fused."""
-        lat = jnp.asarray(lat)
-        return _jit_finish_grid(lat, self._validate_dq(dq, lat.shape[0]),
-                                float(beta))
+    @staticmethod
+    def _finish_grid(lat: jnp.ndarray, S: int, dq, beta) -> jnp.ndarray:
+        """(S, P) latencies → objectives; dq scalar, (S,) or (S, P), β
+        scalar or per-placement (P,)."""
+        return lat / (1.0 + beta
+                      * BatchedEvaluator._dq_cells(dq, S, lat.dtype))
 
     # -- structured batched math (RegionFleetFamily scenarios) ---------------
     @staticmethod
@@ -359,7 +357,7 @@ class BatchedEvaluator:
 
     def _finish_multi(self, obj_set: ObjectiveSet, raw: dict, S: int,
                       dq, beta, weights):
-        dq_col = jnp.broadcast_to(jnp.asarray(dq, jnp.float32), (S,))[:, None]
+        dq_col = self._dq_cells(dq, S)
         grids = {s.name: s.finish(raw[s.name], dq_col, beta)
                  for s in obj_set.specs}
         stacked = jnp.stack([grids[n] for n in obj_set.names])  # (K, S, P)
@@ -427,15 +425,29 @@ class BatchedEvaluator:
         return executable_cache().get_or_build(key, build)
 
     @staticmethod
-    def _validate_dq(dq, S: int) -> jnp.ndarray:
-        """dq must be a scalar or EXACTLY (S,) — a wrong-length vector that
-        happens to broadcast (e.g. (1,) against S scenarios, or a (P,)
-        slipped in as dq) would silently mis-scale the grid."""
+    def _validate_dq(dq, S: int, P: int | None = None) -> jnp.ndarray:
+        """dq must be a scalar, EXACTLY (S,), or — with ``P`` given — one
+        entry per cell (S, P).  A wrong-length vector that happens to
+        broadcast (e.g. (1,) against S scenarios, or a (P,) slipped in as
+        dq) would silently mis-scale the grid."""
         arr = np.asarray(dq, dtype=np.float64)
-        if arr.ndim != 0 and arr.shape != (S,):
+        if arr.ndim != 0 and arr.shape != (S,) and (
+                P is None or arr.shape != (S, P)):
+            cells = "" if P is None else f" or ({S}, {P}) per cell"
             raise ValueError(
                 f"dq must be a scalar or shape ({S},) — one entry per "
-                f"scenario; got shape {arr.shape} for S={S}")
+                f"scenario{cells}; got shape {arr.shape} for S={S}")
+        return jnp.asarray(arr, jnp.float32)
+
+    @staticmethod
+    def _validate_beta(beta, P: int):
+        """β is a scalar or one entry per placement (P,)."""
+        if np.ndim(beta) == 0:
+            return float(beta)
+        arr = np.asarray(beta, dtype=np.float64)
+        if arr.shape != (P,):
+            raise ValueError(f"beta must be a scalar or shape ({P},) — one "
+                             f"entry per placement; got shape {arr.shape}")
         return jnp.asarray(arr, jnp.float32)
 
     def _dense_speeds(self, coms: jnp.ndarray, speed) -> jnp.ndarray:
@@ -483,7 +495,9 @@ class BatchedEvaluator:
         """Score every (scenario, placement) pair in one jitted dispatch.
 
         ``coms`` is a dense (S, V, V) stack or a RegionFleetFamily; ``dq``
-        must be a scalar or exactly per-scenario (S,).
+        must be a scalar, exactly per-scenario (S,), or per-cell (S, P), and
+        ``beta`` a scalar or per-placement (P,) — the per-cell forms let
+        rows with different dq/β share one dispatch (:mod:`repro.serve`).
 
         ``objectives=None`` (default) returns the (S, P) latency-F grid —
         the single-objective fast path.  With an :class:`ObjectiveSet` (or
@@ -500,7 +514,9 @@ class BatchedEvaluator:
         if not structured:
             coms = jnp.asarray(coms)
         S = coms.n_scenarios if structured else coms.shape[0]
-        dq_arr = self._validate_dq(dq, S)
+        P = int(placements.shape[0])
+        dq_arr = self._validate_dq(dq, S, P)
+        beta = self._validate_beta(beta, P)
         san = sanitize.state()
         if san.enabled and san.domain_check:
             sanitize.check_dq(dq)  # host-side operand: no device round-trip
@@ -537,8 +553,8 @@ class BatchedEvaluator:
             if structured:
                 return self._structured(coms).grid(
                     placements, *self._family_args(coms), dq_arr,
-                    float(beta))
-            return self._jit_grid(placements, coms, dq_arr, float(beta))
+                    beta)
+            return self._jit_grid(placements, coms, dq_arr, beta)
         obj_set = as_objective_set(objectives)
         weights = jnp.asarray(obj_set.weights, jnp.float32)
         if structured:
@@ -550,18 +566,13 @@ class BatchedEvaluator:
             speeds = jnp.asarray(coms.speed_or_ones(), jnp.float32)
             grids, scal = self._multi_structured(coms, obj_set)(
                 placements, *self._family_args(coms), speeds, dq_arr,
-                float(beta), weights)
+                beta, weights)
         else:
             grids, scal = self._multi_dense(obj_set)(
                 placements, coms, self._dense_speeds(coms, speed), dq_arr,
-                float(beta), weights)
+                beta, weights)
         # jit returns dict pytrees in sorted-key order; present the grids
         # in the set's declared objective order
         return ObjectiveGrids(names=obj_set.names,
                               grids={n: grids[n] for n in obj_set.names},
                               scalarized=scal, weights=obj_set.weights)
-
-
-_jit_finish_grid = jax.jit(
-    lambda lat, dq, beta: BatchedEvaluator._finish_grid(lat, lat.shape[0],
-                                                        dq, beta))

@@ -35,7 +35,8 @@ per-consumer recompilation this module deletes.
 
 Across processes, :func:`enable_persistent_cache` turns on JAX's on-disk
 compilation cache at one fixed directory, so a second run of the same
-checkout loads its executables instead of compiling them.
+commit — from this checkout or another — loads its executables instead of
+compiling them.
 """
 
 from __future__ import annotations
@@ -43,6 +44,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import os
+import re
 import threading
 from pathlib import Path
 
@@ -52,12 +54,14 @@ from repro import obs
 
 __all__ = ["ExecutableCache", "executable_cache", "set_executable_cache",
            "fresh_cache", "graph_key", "enable_persistent_cache",
-           "CHECKOUT_CACHE_DIR"]
+           "CHECKOUT_ROOT", "CHECKOUT_CACHE_DIR"]
 
+#: the checkout this module runs from
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
 #: the persistent cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset:
 #: fixed inside the checkout (and git-ignored), because the path is part of
 #: what a later run must find again
-CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+CHECKOUT_CACHE_DIR = CHECKOUT_ROOT / ".jax_cache"
 
 
 def enable_persistent_cache() -> str:
@@ -66,13 +70,20 @@ def enable_persistent_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory: JAX reads it
     itself and no other is set here.  Otherwise the cache goes to
     :data:`CHECKOUT_CACHE_DIR`.  Every compile is cached, however quick.
-    Call it from an entry point, never at import."""
+
+    A Pallas TPU kernel carries the source paths of its operations inside
+    the program the cache key hashes, so the checkout's own root is
+    stripped from every source path: two checkouts of one commit then key
+    their programs alike.  Call it from an entry point before its first
+    compile, never at import."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(CHECKOUT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(f"{CHECKOUT_ROOT}{os.sep}"))
     return path
 
 

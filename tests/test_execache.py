@@ -6,6 +6,7 @@ cache."""
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -101,14 +102,18 @@ def test_distinct_configs_do_not_collide():
 
 @pytest.fixture
 def cache_config():
-    """Restore JAX's persistent-cache settings after the test."""
+    """Restore JAX's persistent-cache settings after the test, and let the
+    cache pick its directory afresh on the next compile."""
+    from jax.experimental.compilation_cache import compilation_cache
     names = ("jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes")
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_hlo_source_file_canonicalization_regex")
     saved = {n: getattr(jax.config, n) for n in names}
     yield
     for n, v in saved.items():
         jax.config.update(n, v)
+    compilation_cache.reset_cache()
 
 
 def test_persistent_cache_honors_env_dir(monkeypatch, tmp_path,
@@ -132,3 +137,49 @@ def test_persistent_cache_defaults_to_fixed_checkout_dir(monkeypatch,
     assert first == enable_persistent_cache() == str(CHECKOUT_CACHE_DIR)
     assert jax.config.jax_compilation_cache_dir == first
     assert CHECKOUT_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+
+
+def test_persistent_cache_writes_entries_and_reads_them_back(
+        monkeypatch, tmp_path, cache_config):
+    """A compile after the helper writes an entry into the cache directory;
+    the same program compiled again, with the in-memory caches cleared,
+    is read back from it instead of compiled."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.obs import jaxhooks
+    from repro.sim import execache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(execache, "CHECKOUT_CACHE_DIR", tmp_path / "cache")
+    assert execache.enable_persistent_cache() == str(tmp_path / "cache")
+    compilation_cache.reset_cache()   # earlier compiles fixed it as unused
+
+    def program(x):
+        return jnp.tanh(x @ x.T) * 3.0 + 1.0
+
+    x = jnp.ones((24, 8), jnp.float32)
+    snap = jaxhooks.snapshot()
+    first = jax.jit(program)(x)
+    assert snap.delta()[0] == 1 and snap.cache_loads() == 0
+    entries = sorted(p.name for p in (tmp_path / "cache").iterdir())
+    assert entries
+    jax.clear_caches()
+    snap = jaxhooks.snapshot()
+    again = jax.jit(program)(x)
+    assert snap.delta()[0] == snap.cache_loads() == 1   # loaded
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(first))
+    assert sorted(p.name for p in (tmp_path / "cache").iterdir()) == entries
+
+
+def test_persistent_cache_keys_ignore_the_checkout_path(cache_config):
+    """Source paths under the checkout lose its root (so two checkouts of
+    one commit share entries); paths outside it are left alone."""
+    import re
+
+    from repro.sim.execache import CHECKOUT_ROOT, enable_persistent_cache
+    enable_persistent_cache()
+    pattern = jax.config.jax_hlo_source_file_canonicalization_regex
+    kernel = CHECKOUT_ROOT / "src" / "repro" / "kernels" / "edge_latency.py"
+    assert re.sub(pattern, "", str(kernel)) == str(
+        Path("src", "repro", "kernels", "edge_latency.py"))
+    outside = "/elsewhere" + str(kernel)
+    assert re.sub(pattern, "", outside) == outside

@@ -133,35 +133,29 @@ def test_equal_fleets_coalesce_across_tenants():
 
 
 def test_multi_objective_grids_parity():
-    """Multi-objective serving: raw per-objective grids are bitwise equal
-    to a direct dq=0 dispatch; the dq-finished scalarization matches the
-    device's own finish to float32 resolution (the recombination crosses
-    float64 host math, so bitwise is only guaranteed for the raw grids and
-    the single-objective path)."""
+    """Multi-objective serving finishes dq/β in the shared dispatch: every
+    per-objective grid and the scalarization are bitwise a direct
+    dispatch at the query's own dq/β, scalar and per-scenario alike."""
     g, coms, placements = _setup()
     svc = WhatIfService(g, admission=RELAXED)
     fid = svc.register_fleet("t", coms, objectives=OBJ2)
-    x = placements(6)
-    dq, beta = 0.35, 0.8
+    x, y = placements(6), placements(5)
+    dq_y = np.linspace(0.1, 0.7, coms.shape[0]).astype(np.float32)
     tk = svc.submit("t", fid, WhatIfQuery(kind="score", placements=x,
-                                          dq=dq, beta=beta))
+                                          dq=0.35, beta=0.8))
+    tk_y = svc.submit("t", fid, WhatIfQuery(kind="score", placements=y,
+                                            dq=dq_y, beta=1.3))
     svc.drain()
-    res = _result(svc.poll("t"), tk.query_id)
+    msgs = svc.poll("t")
     ev = BatchedEvaluator.shared(g)
-    raw = ev.score_grid(x, coms, objectives=OBJ2)      # dq=0 raw dispatch
-    for name in OBJ2.names:
-        want = np.asarray(raw.grids[name], dtype=np.float32)
-        if name == "latency_f":
-            continue                # dq-finished below; raw parity via rest
-        np.testing.assert_array_equal(res.grids[name], want)
-    direct = ev.score_grid(x, coms, dq=dq, beta=beta, objectives=OBJ2)
-    np.testing.assert_allclose(
-        res.scores, np.asarray(direct.scalarized, dtype=np.float32),
-        rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(
-        res.grids["latency_f"],
-        np.asarray(direct.grids["latency_f"], dtype=np.float32),
-        rtol=1e-6, atol=0)
+    for ticket, xs, dq, beta in ((tk, x, 0.35, 0.8), (tk_y, y, dq_y, 1.3)):
+        res = _result(msgs, ticket.query_id)
+        direct = ev.score_grid(xs, coms, dq=dq, beta=beta, objectives=OBJ2)
+        for name in OBJ2.names:
+            np.testing.assert_array_equal(
+                res.grids[name], np.asarray(direct.grids[name], np.float32))
+        np.testing.assert_array_equal(
+            res.scores, np.asarray(direct.scalarized, np.float32))
 
 
 def test_rank_pareto_joint_match_decision_layer():

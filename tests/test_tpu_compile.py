@@ -124,17 +124,17 @@ def tpu_dispatch(chip, monkeypatch):
 
 
 def test_dense_score_grid_compiles(tpu_dispatch, smoke):
-    """The whole dense score_grid of one smoke chunk: P = 64 placements of
-    16 operators over S = 8 scenarios of V = 4096 devices."""
+    """The whole dense score_grid of one smoke chunk as the service
+    dispatches it: P = 64 placements of 16 operators over S = 8 scenarios
+    of V = 4096 devices, with dq per cell and β per placement."""
     cs, graph = smoke
     full = cs.FULL
     ev = BatchedEvaluator(graph)
     assert (ev.use_pallas, ev.interpret) == (True, False)
-    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=tpu_dispatch)
-    args = _shapes(tpu_dispatch,
-                   (full.chunk_rows, graph.n_ops, full.dense_devices),
-                   (full.scenarios, full.dense_devices, full.dense_devices))
-    _compiled_text(ev._jit_grid, args + [scalar, scalar])
+    P, S = full.chunk_rows, full.scenarios
+    args = _shapes(tpu_dispatch, (P, graph.n_ops, full.dense_devices),
+                   (S, full.dense_devices, full.dense_devices), (S, P), (P,))
+    _compiled_text(ev._jit_grid, args)
 
 
 def test_structured_score_grid_compiles(tpu_dispatch, smoke):
