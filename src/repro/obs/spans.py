@@ -15,6 +15,13 @@ timed region into the process-local trace buffer.  Each span carries:
     *inside* the wall measurement — otherwise a dispatch-and-return would
     read as ~0 execute.
 
+While telemetry is enabled each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and arguments, so under a
+running profiler trace the program's spans land on its host plane, on the
+same clock as the device's operations, nested as they ran.  The profiler
+keeps a string argument only up to its first comma: give scalars, or
+strings with no comma (query ids joined by spaces).
+
 Spans nest (``parent`` links reconstruct the tree) and export as
 Chrome-trace events — one JSON object per line (JSONL), each a complete
 ``"ph": "X"`` duration event, plus ``"ph": "C"`` counter samples for the
@@ -65,7 +72,7 @@ class Span:
     """One live timed region; becomes a ``"ph": "X"`` trace event on exit."""
 
     __slots__ = ("name", "args", "t0_us", "wall_s", "compile_s",
-                 "n_compiles", "_synced")
+                 "n_compiles", "_synced", "_annotation")
 
     def __init__(self, name: str, args: dict):
         self.name = name
@@ -75,6 +82,7 @@ class Span:
         self.compile_s = 0.0
         self.n_compiles = 0
         self._synced = False
+        self._annotation = None
 
     @property
     def execute_s(self) -> float:
@@ -89,7 +97,19 @@ class Span:
         self._synced = True
         return value
 
+    def set(self, **args) -> None:
+        """Add arguments known only inside the span (a byte count after
+        the copy it counts), to this span's event and to its profiler
+        annotation."""
+        self.args.update(args)
+        self._annotation.set_metadata(**args)
+
     def __enter__(self):
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self.args)
+        self._annotation.__enter__()
         self.t0_us = _now_us()
         _stack().append(self)
         return self
@@ -97,6 +117,7 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
         self.wall_s = (t1 - self.t0_us) / 1e6
+        self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -125,6 +146,9 @@ class _NullSpan:
     def sync(self, value):
         return value
 
+    def set(self, **args) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -137,7 +161,8 @@ _NULL = _NullSpan()
 
 def span(name: str, **args):
     """Open a span when telemetry is enabled; a shared no-op otherwise.
-    ``args`` must be JSON-able (they land in the trace event's ``args``)."""
+    ``args`` must be scalars or comma-free strings: they land in the trace
+    event's ``args`` and in the profiler annotation's stats."""
     if not registry().enabled:
         return _NULL
     return Span(name, args)

@@ -7,9 +7,8 @@ each (CoalesceKey, padded-row bucket) pair tracks
 
   * dispatch count, rows scored, queries served, padding waste;
   * a latency :class:`repro.obs.Histogram` (exponential buckets) whose
-    p50/p95/p99 feed admission pricing — kept as a LOCAL instance so
-    admission control works with the obs registry disabled, and mirrored
-    into the registry when it is enabled;
+    p50/p95/p99 feed admission pricing — a LOCAL instance, so admission
+    control works with the obs registry disabled;
   * recompiles attributed via :class:`repro.obs.jaxhooks.CompileSnapshot`
     deltas around each dispatch — a warm bucket must show zero.
 
@@ -66,14 +65,6 @@ class BucketStats:
             self.warm += 1
             self.warm_latency.observe(seconds)
         self.latency.observe(seconds)
-        reg = obs.registry()
-        if reg.enabled:
-            b = str(self.bucket)
-            reg.counter("serve.dispatches", bucket=b).add(1)
-            reg.counter("serve.rows", bucket=b).add(n_rows)
-            reg.counter("serve.recompiles", bucket=b).add(n_recompiles)
-            reg.histogram("serve.dispatch_s", lo=_HIST_LO,
-                          bucket=b).observe(seconds)
 
     def p99(self) -> float:
         return self.latency.quantile(0.99)

@@ -61,6 +61,12 @@ __all__ = ["WhatIfQuery", "QueryTicket", "ResultChunk", "QueryResult",
 _KINDS = ("score", "rank", "pareto", "joint")
 
 
+def _query_ids(live) -> str:
+    """The chunk's query ids joined by spaces: the profiler keeps a string
+    argument only up to its first comma."""
+    return " ".join(str(p.query_id) for p, _ in live)
+
+
 @dataclasses.dataclass(frozen=True)
 class WhatIfQuery:
     """One tenant question over a batch of candidate placements.
@@ -292,42 +298,36 @@ class WhatIfService:
         dq_steps = None if q.dq_values is None else len(
             np.atleast_1d(q.dq_values))
         rows = q.placements.shape[0]
-        verdict = decide(
-            fleet.pricer, fleet.n_scenarios, next_pow2(rows),
-            backlog_s=self._backlog_s(), cfg=self.admission,
-            dq_steps=dq_steps,
-            bucket_stats=self.stats.peek_bucket(next_pow2(rows)))
-        if isinstance(verdict, Rejected):
-            self.stats.rejected += 1
-            reg = obs.registry()
-            if reg.enabled:
-                reg.counter("serve.admission", verdict="rejected").add(1)
-            return verdict
-        placements, dq_vals, degraded = q.placements, q.dq_values, None
-        if isinstance(verdict, Degraded):
-            degraded = verdict
-            self.stats.degraded += 1
-            placements = placements[:verdict.keep_rows]
-            if verdict.dq_steps is not None and dq_steps is not None \
-                    and verdict.dq_steps < dq_steps:
-                grid = np.atleast_1d(
-                    np.asarray(q.dq_values, dtype=np.float64))
-                pick = np.linspace(0, len(grid) - 1,
-                                   verdict.dq_steps).round().astype(int)
-                dq_vals = grid[np.unique(pick)]
-        else:
-            self.stats.admitted += 1
-        reg = obs.registry()
-        if reg.enabled:
-            reg.counter("serve.admission",
-                        verdict=("degraded" if degraded else
-                                 "admitted")).add(1)
+        with obs.span("serve.admit", rows=rows):
+            verdict = decide(
+                fleet.pricer, fleet.n_scenarios, next_pow2(rows),
+                backlog_s=self._backlog_s(), cfg=self.admission,
+                dq_steps=dq_steps,
+                bucket_stats=self.stats.peek_bucket(next_pow2(rows)))
+            if isinstance(verdict, Rejected):
+                self.stats.rejected += 1
+                return verdict
+            placements, dq_vals, degraded = q.placements, q.dq_values, None
+            if isinstance(verdict, Degraded):
+                degraded = verdict
+                self.stats.degraded += 1
+                placements = placements[:verdict.keep_rows]
+                if verdict.dq_steps is not None and dq_steps is not None \
+                        and verdict.dq_steps < dq_steps:
+                    grid = np.atleast_1d(
+                        np.asarray(q.dq_values, dtype=np.float64))
+                    pick = np.linspace(0, len(grid) - 1,
+                                       verdict.dq_steps).round().astype(int)
+                    dq_vals = grid[np.unique(pick)]
+            else:
+                self.stats.admitted += 1
         qid = self._next_id
         self._next_id += 1
-        self._queues.setdefault(fleet.key, []).append(_Pending(
-            query_id=qid, tenant=tenant, query=q, placements=placements,
-            dq_values=dq_vals, predicted_s=verdict.predicted_s,
-            degraded=degraded))
+        with obs.span("serve.enqueue", query_id=qid):
+            self._queues.setdefault(fleet.key, []).append(_Pending(
+                query_id=qid, tenant=tenant, query=q, placements=placements,
+                dq_values=dq_vals, predicted_s=verdict.predicted_s,
+                degraded=degraded))
         return QueryTicket(query_id=qid, tenant=tenant, admission=verdict,
                            rows=placements.shape[0],
                            dq_steps=None if dq_vals is None
@@ -349,35 +349,47 @@ class WhatIfService:
             return 0
         queue = self._queues.pop(key)
         fleet = next(f for f in self._fleets.values() if f.key == key)
-        batch = np.concatenate([p.placements for p in queue])
-        # (query, slice) spans inside the super-batch, in queue order
+        # (query, rows) spans inside the super-batch, in queue order
         spans, off = [], 0
         for p in queue:
             spans.append((p, off, off + p.rows))
             off += p.rows
         done = 0
-        for start in range(0, batch.shape[0], self.max_chunk_rows):
-            chunk = batch[start:start + self.max_chunk_rows]
-            bucket = next_pow2(chunk.shape[0])
-            end = start + chunk.shape[0]
-            # each live query's columns within the chunk
-            live = [(p, slice(max(a, start) - start, min(b, end) - start))
-                    for p, a, b in spans if a < end and b > start]
-            scores, grids = self._dispatch(
-                fleet, pad_rows(chunk, bucket), bucket, live)
-            for p, sl in live:
-                p.score_cols.append(scores[:, sl])
-                for name, g in grids.items():
-                    p.grid_cols.setdefault(name, []).append(g[:, sl])
-                if p.query.kind != "joint":
-                    self._mail.setdefault(p.tenant, []).append(ResultChunk(
-                        query_id=p.query_id, tenant=p.tenant,
-                        offset=p.done_rows, scores=scores[:, sl]))
-                p.done_rows += sl.stop - sl.start
-                if p.done_rows == p.rows:
-                    self._mail.setdefault(p.tenant, []).append(
-                        self._finalize(fleet, p))
-                    done += 1
+        with obs.span("serve.step", queries=len(queue), rows=off):
+            for start in range(0, off, self.max_chunk_rows):
+                end = min(start + self.max_chunk_rows, off)
+                bucket = next_pow2(end - start)
+                # each live query's columns within the chunk
+                live = [(p, slice(max(a, start) - start,
+                                  min(b, end) - start))
+                        for p, a, b in spans if a < end and b > start]
+                with obs.span("serve.chunk", bucket=bucket, rows=end - start,
+                              query_ids=(_query_ids(live) if obs.enabled()
+                                         else "")):
+                    scores, grids = self._dispatch(fleet, bucket, live)
+                    with obs.span("serve.finalize", queries=len(live)):
+                        done += self._deliver(fleet, scores, grids, live)
+        return done
+
+    def _deliver(self, fleet: _Fleet, scores: np.ndarray, grids: dict,
+                 live: list[tuple[_Pending, slice]]) -> int:
+        """Hand each live query its chunk columns: a ResultChunk to its
+        tenant's mailbox, then the final QueryResult once its last rows
+        are in.  Returns the number of queries completed."""
+        done = 0
+        for p, sl in live:
+            p.score_cols.append(scores[:, sl])
+            for name, g in grids.items():
+                p.grid_cols.setdefault(name, []).append(g[:, sl])
+            if p.query.kind != "joint":
+                self._mail.setdefault(p.tenant, []).append(ResultChunk(
+                    query_id=p.query_id, tenant=p.tenant,
+                    offset=p.done_rows, scores=scores[:, sl]))
+            p.done_rows += sl.stop - sl.start
+            if p.done_rows == p.rows:
+                self._mail.setdefault(p.tenant, []).append(
+                    self._finalize(fleet, p))
+                done += 1
         return done
 
     def drain(self) -> int:
@@ -395,29 +407,45 @@ class WhatIfService:
         return self._mail.pop(tenant, [])
 
     # -- dispatch + accounting ----------------------------------------------
-    def _dispatch(self, fleet: _Fleet, padded: np.ndarray, bucket: int,
+    def _assemble(self, fleet: _Fleet, bucket: int,
                   live: list[tuple[_Pending, slice]]):
-        """ONE score_grid call over the padded chunk, each query's columns
-        finished with its own dq/β (joint and padding columns raw: dq = 0,
-        β = 0); returns host-side float32 (scalar scores, per-objective
-        grids), both (S, bucket)."""
-        dq = np.zeros((fleet.n_scenarios, bucket), np.float32)
-        beta = np.zeros(bucket, np.float32)
-        for p, sl in live:
-            if p.query.kind != "joint":
-                dq[:, sl] = np.broadcast_to(np.asarray(
-                    p.query.dq, np.float32), (fleet.n_scenarios,))[:, None]
-                beta[sl] = p.query.beta
+        """The chunk's rows padded to ``bucket``, each query's columns
+        carrying its own dq/β (joint and padding columns 0)."""
+        with obs.span("serve.assemble", bucket=bucket):
+            padded = pad_rows(np.concatenate(
+                [p.placements[p.done_rows:p.done_rows + sl.stop - sl.start]
+                 for p, sl in live]), bucket)
+            dq = np.zeros((fleet.n_scenarios, bucket), np.float32)
+            beta = np.zeros(bucket, np.float32)
+            for p, sl in live:
+                if p.query.kind != "joint":
+                    dq[:, sl] = np.broadcast_to(np.asarray(
+                        p.query.dq, np.float32),
+                        (fleet.n_scenarios,))[:, None]
+                    beta[sl] = p.query.beta
+        return padded, dq, beta
+
+    def _dispatch(self, fleet: _Fleet, bucket: int,
+                  live: list[tuple[_Pending, slice]]):
+        """ONE score_grid call over the chunk's padded rows, each query's
+        columns finished with its own dq/β; returns host-side float32
+        (scalar scores, per-objective grids), both (S, bucket)."""
+        padded, dq, beta = self._assemble(fleet, bucket, live)
         n_rows = sum(sl.stop - sl.start for _, sl in live)
         snap = jaxhooks.snapshot()
         t0 = time.perf_counter()
         out = self._ev.score_grid(padded, fleet.pack, dq=dq, beta=beta,
                                   objectives=fleet.objectives)
         # one host transfer for the whole chunk
-        if isinstance(out, ObjectiveGrids):
-            scores, grids = jax.device_get((out.scalarized, dict(out.grids)))
-        else:
-            scores, grids = jax.device_get(out), {}
+        with obs.span("serve.fetch") as sp:
+            if isinstance(out, ObjectiveGrids):
+                scores, grids = jax.device_get((out.scalarized,
+                                                dict(out.grids)))
+            else:
+                scores, grids = jax.device_get(out), {}
+            if obs.enabled():
+                sp.set(d2h_bytes=sum(a.nbytes for a in
+                                     jax.tree.leaves((scores, grids))))
         seconds = time.perf_counter() - t0
         recompiles, compile_s = snap.delta()
         self.stats.bucket(bucket).observe(

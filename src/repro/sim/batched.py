@@ -117,6 +117,14 @@ def pack_speeds(fleets: list[Fleet], dtype=jnp.float32) -> jnp.ndarray:
     return jnp.asarray(np.stack(sp), dtype=dtype)
 
 
+def _host_bytes(pairs) -> int:
+    """Device bytes of the uploaded operands whose source was not already
+    on the device: ``pairs`` holds (source, device array); a Python scalar
+    left as it was (β) counts nothing."""
+    return sum(int(d.nbytes) for s, d in pairs
+               if isinstance(d, jax.Array) and not isinstance(s, jax.Array))
+
+
 @dataclasses.dataclass
 class _StructuredFns:
     """Jitted structured-path entry points for one family layout (lat_raw
@@ -509,27 +517,23 @@ class BatchedEvaluator:
         :func:`pack_speeds`; default unit speeds); structured families
         carry their own speeds, so ``speed`` must stay None there.
         """
-        placements = jnp.asarray(placements)
         structured = isinstance(coms, RegionFleetFamily)
-        if not structured:
-            coms = jnp.asarray(coms)
-        S = coms.n_scenarios if structured else coms.shape[0]
-        P = int(placements.shape[0])
-        dq_arr = self._validate_dq(dq, S, P)
-        beta = self._validate_beta(beta, P)
-        san = sanitize.state()
-        if san.enabled and san.domain_check:
-            sanitize.check_dq(dq)  # host-side operand: no device round-trip
+        S = coms.n_scenarios if structured else int(np.shape(coms)[0])
+        P = int(np.shape(placements)[0])
         path = "structured" if structured else "dense"
         multi = objectives is not None
         reg = obs.registry()
         if reg.enabled:
             reg.counter("eval.score_grid.dispatches", path=path).add(1)
-            reg.histogram("eval.score_grid.cells", lo=1.0).observe(
-                S * int(placements.shape[0]))
-        with obs.span("score_grid", S=S, P=int(placements.shape[0]),
-                      path=path, multi=multi) as sp:
-            out = self._dispatch_grid(placements, coms, dq_arr, beta,
+            reg.histogram("eval.score_grid.cells", lo=1.0).observe(S * P)
+        with obs.span("score_grid", S=S, P=P, path=path,
+                      multi=multi) as sp:
+            placements, pack, dq_arr, beta = self._upload(
+                placements, coms, dq, beta, S, P)
+            san = sanitize.state()
+            if san.enabled and san.domain_check:
+                sanitize.check_dq(dq)  # host-side operand: no round-trip
+            out = self._dispatch_grid(placements, coms, pack, dq_arr, beta,
                                       objectives, speed, structured)
             sp.sync(out.scalarized if isinstance(out, ObjectiveGrids)
                     else out)
@@ -544,17 +548,41 @@ class BatchedEvaluator:
                 out.scalarized if isinstance(out, ObjectiveGrids) else out)
         return out
 
-    def _dispatch_grid(self, placements, coms, dq_arr, beta, objectives,
-                       speed, structured: bool):
+    def _upload(self, placements, coms, dq, beta, S: int, P: int):
+        """The grid's operands as device arrays: placements, the pack (a
+        dense stack, or a family's ``(inter, degrade)``), dq and β.  In a
+        ``grid.upload`` span that, with telemetry on, waits for the copies
+        and counts ``h2d_bytes``: the device bytes of every operand that
+        was not already a ``jax.Array``."""
+        with obs.span("grid.upload") as up:
+            structured = isinstance(coms, RegionFleetFamily)
+            pairs = [(placements, jnp.asarray(placements))]
+            if structured:
+                pairs += zip((coms.inter, coms.degrade),
+                             self._family_args(coms))
+            else:
+                pairs.append((coms, jnp.asarray(coms)))
+            pairs += [(dq, self._validate_dq(dq, S, P)),
+                      (beta, self._validate_beta(beta, P))]
+            out = [d for _, d in pairs]
+            if obs.enabled():
+                up.sync(out)
+                up.set(h2d_bytes=_host_bytes(pairs))
+        pack = tuple(out[1:3]) if structured else out[1]
+        return out[0], pack, out[-2], out[-1]
+
+    def _dispatch_grid(self, placements, coms, pack, dq_arr, beta,
+                       objectives, speed, structured: bool):
+        """``pack`` is ``coms`` on the device: the dense stack, or the
+        family's ``(inter, degrade)``."""
         if objectives is None:
             if speed is not None:
                 raise ValueError("speed only feeds the occupancy objectives "
                                  "— pass objectives= to use it")
             if structured:
-                return self._structured(coms).grid(
-                    placements, *self._family_args(coms), dq_arr,
-                    beta)
-            return self._jit_grid(placements, coms, dq_arr, beta)
+                return self._structured(coms).grid(placements, *pack,
+                                                   dq_arr, beta)
+            return self._jit_grid(placements, pack, dq_arr, beta)
         obj_set = as_objective_set(objectives)
         weights = jnp.asarray(obj_set.weights, jnp.float32)
         if structured:
@@ -565,11 +593,10 @@ class BatchedEvaluator:
             # traced degrade itself (effective = speed / degrade)
             speeds = jnp.asarray(coms.speed_or_ones(), jnp.float32)
             grids, scal = self._multi_structured(coms, obj_set)(
-                placements, *self._family_args(coms), speeds, dq_arr,
-                beta, weights)
+                placements, *pack, speeds, dq_arr, beta, weights)
         else:
             grids, scal = self._multi_dense(obj_set)(
-                placements, coms, self._dense_speeds(coms, speed), dq_arr,
+                placements, pack, self._dense_speeds(pack, speed), dq_arr,
                 beta, weights)
         # jit returns dict pytrees in sorted-key order; present the grids
         # in the set's declared objective order

@@ -12,23 +12,7 @@ import pytest
 from repro import obs
 from repro.obs import bench as obench
 from repro.obs import jaxhooks, perfbridge
-from repro.obs.spans import _fresh_trace
-
-
-@pytest.fixture
-def telemetry():
-    """Enable telemetry against a fresh registry + trace buffer, restore
-    the disabled default afterwards."""
-    saved = obs.registry()
-    reg = obs.MetricsRegistry(enabled=False)
-    obs.set_registry(reg)
-    with _fresh_trace():
-        obs.enable()
-        try:
-            yield reg
-        finally:
-            obs.disable()
-            obs.set_registry(saved)
+from repro.obs.spans import _NULL, _fresh_trace
 
 
 # -- registry -----------------------------------------------------------------
@@ -83,6 +67,33 @@ def test_span_records_compile_and_execute_split(telemetry):
     evs = obs.trace_events()
     assert [e["name"] for e in evs if e["ph"] == "X"] == ["cold", "warm"]
     assert evs[0]["args"]["synced"] is True
+
+
+def test_spans_land_in_the_profiler_trace(telemetry, host_trace):
+    """Enabled spans are profiler annotations too: on the host plane, with
+    their names, nesting and arguments (``set`` ones included)."""
+    with host_trace() as events:
+        with obs.span("outer", n=3, ids="3 4 7"):
+            with obs.span("inner", k=1.5) as sp:
+                sp.set(h2d_bytes=64)
+    got = {e[0]: e for e in events if e[0] in ("outer", "inner")}
+    outer, inner = got["outer"], got["inner"]
+    assert outer[1] <= inner[1] < inner[2] <= outer[2]
+    assert outer[3] == {"n": 3, "ids": "3 4 7"}
+    assert inner[3] == {"k": 1.5, "h2d_bytes": 64}
+    (ev,) = [e for e in obs.trace_events() if e["name"] == "inner"]
+    assert ev["args"]["h2d_bytes"] == 64
+
+
+def test_disabled_spans_make_no_annotation(monkeypatch):
+    def refuse(*args, **kwargs):
+        raise AssertionError("annotation made with telemetry off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert not obs.enabled()
+    with obs.span("x", a=1) as sp:
+        sp.set(b=2)
+    assert sp is _NULL
 
 
 def test_span_nesting_attributes_innermost(telemetry):

@@ -6,6 +6,7 @@ layer."""
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import (CostConfig, DQCoupling, ExplicitFleet, ObjectiveSet,
                         random_dag, random_placement)
 from repro.search import (epsilon_constraint, joint_dq_scores, pareto_front,
@@ -313,3 +314,101 @@ def test_submit_validation():
     with pytest.raises(ValueError, match="devices"):
         svc.submit("t", fid, WhatIfQuery(
             kind="score", placements=np.ones((2, 5, 9), np.float32)))
+
+
+# -- the served path's spans --------------------------------------------------
+
+SPAN_PARENT = {"serve.assemble": "serve.chunk", "score_grid": "serve.chunk",
+               "grid.upload": "score_grid", "serve.fetch": "serve.chunk",
+               "serve.finalize": "serve.chunk", "serve.chunk": "serve.step"}
+
+
+def _serve_once(g, coms, xs):
+    """Two tenants' score queries through one fresh service and one
+    step(): (query ids, each query's final scores)."""
+    svc = WhatIfService(g, admission=RELAXED, max_chunk_rows=4)
+    fid = svc.register_fleet("a", coms)
+    ids = [svc.submit(t, fid, WhatIfQuery(kind="score", placements=x,
+                                          dq=0.3, beta=0.5)).query_id
+           for t, x in zip(("a", "b"), xs)]
+    assert svc.step() == len(xs)
+    mail = svc.poll("a") + svc.poll("b")
+    return ids, [_result(mail, q).scores for q in ids]
+
+
+def test_served_step_spans_under_the_profiler(telemetry, host_trace):
+    """One step of 3 + 2 rows in chunks of 4: the span tree of the served
+    path on the profiler's host plane, with the stats the benchmark reads;
+    the answers are bitwise those served with telemetry off."""
+    g, coms, placements = _setup(n_fleets=2)
+    xs = [placements(3), placements(2)]
+    with host_trace() as events:
+        ids, on = _serve_once(g, coms, xs)
+    spans = [e for e in events if e[0].startswith(("serve.", "grid.",
+                                                   "score_grid"))]
+    names = [e[0] for e in spans]
+    assert {n: names.count(n) for n in set(names)} == {
+        "serve.admit": 2, "serve.enqueue": 2, "serve.step": 1,
+        "serve.chunk": 2, "serve.assemble": 2, "score_grid": 2,
+        "grid.upload": 2, "serve.fetch": 2, "serve.finalize": 2}
+    for name, a, b, _ in spans:
+        if name in SPAN_PARENT:
+            assert any(p == SPAN_PARENT[name] and pa <= a and b <= pb
+                       for p, pa, pb, _ in spans), name
+    chunks = sorted((e for e in spans if e[0] == "serve.chunk"),
+                    key=lambda e: e[1])
+    assert [(c[3]["bucket"], c[3]["rows"]) for c in chunks] == [(4, 4),
+                                                                 (1, 1)]
+    # the first chunk carries both queries, the second the rest of one
+    carried = [[int(q) for q in str(c[3]["query_ids"]).split()]
+               for c in chunks]
+    assert carried == [ids, ids[1:]]
+    assert [e[3]["query_id"] for e in spans
+            if e[0] == "serve.enqueue"] == ids
+    S, V = coms.shape[0], coms.shape[1]
+    n_ops = xs[0].shape[1]
+    # padded placements, the host pack, dq (S, bucket), beta (bucket,)
+    want = sorted(4 * (b * n_ops * V + S * V * V + S * b + b)
+                  for b in (4, 1))
+    assert sorted(e[3]["h2d_bytes"] for e in spans
+                  if e[0] == "grid.upload") == want
+    assert sorted(e[3]["d2h_bytes"] for e in spans
+                  if e[0] == "serve.fetch") == [4 * S * 1, 4 * S * 4]
+    obs.disable()
+    _, off = _serve_once(g, coms, xs)
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_upload_counts_only_host_operands(telemetry):
+    """A pack already on the device crosses nothing: h2d_bytes is then
+    the placements, dq and β alone."""
+    g, coms, placements = _setup(n_fleets=2)
+    x = placements(4)
+    dq = np.full((coms.shape[0], 4), 0.2, np.float32)
+    beta = np.full(4, 0.5, np.float32)
+    ev = BatchedEvaluator.shared(g)
+    ev.score_grid(x, coms, dq=dq, beta=beta)
+    on_device = pack_fleets([ExplicitFleet(com_cost=c) for c in coms])
+    ev.score_grid(x, on_device, dq=dq, beta=beta)
+    got = [e["args"]["h2d_bytes"] for e in obs.trace_events()
+           if e["name"] == "grid.upload"]
+    assert got == [x.nbytes + coms.nbytes + dq.nbytes + beta.nbytes,
+                   x.nbytes + dq.nbytes + beta.nbytes]
+
+
+def test_served_path_builds_nothing_with_telemetry_off(monkeypatch):
+    """Telemetry off: no profiler annotation, no query-id string and no
+    byte count anywhere on the served path."""
+    from repro.serve import service as service_mod
+    from repro.sim import batched as batched_mod
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("telemetry work with telemetry off")
+
+    monkeypatch.setattr("jax.profiler.TraceAnnotation", refuse)
+    monkeypatch.setattr(service_mod, "_query_ids", refuse)
+    monkeypatch.setattr(batched_mod, "_host_bytes", refuse)
+    assert not obs.enabled()
+    g, coms, placements = _setup(n_fleets=2)
+    _serve_once(g, coms, [placements(3), placements(2)])
