@@ -36,6 +36,7 @@ import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
@@ -237,6 +238,7 @@ class WhatIfService:
                                            use_pallas=use_pallas,
                                            interpret=interpret)
         self._fleets: dict[str, _Fleet] = {}
+        self._packs: dict[str, object] = {}    # dense, by content digest
         self._queues: dict[CoalesceKey, list[_Pending]] = {}
         self._mail: dict[str, list] = {}
         self._next_id = 0
@@ -250,29 +252,60 @@ class WhatIfService:
         so two tenants registering equal fleets receive the SAME id and
         their queries coalesce into one dispatch stream.  ``objectives``
         fixes the multi-objective set for queries against this fleet
-        (None = single-objective latency-F)."""
+        (None = single-objective latency-F).
+
+        A dense pack is held on the device as float32, one copy per
+        content digest however many objective sets register it, so no
+        dispatch copies it again; the copy lands here, not in the first
+        dispatch.  A ``jax.Array`` pack stays where it is.  Where the
+        device has no room left, the float32 host pack is held instead
+        and every dispatch copies it."""
         obj_set = as_objective_set(objectives) if objectives is not None \
             else None
-        fid = fleet_digest(pack)
-        if obj_set is not None:
-            fid = f"{fid}:{abs(hash(obj_set)):x}"
-        if fid in self._fleets:
-            return fid
-        if isinstance(pack, RegionFleetFamily):
-            S, V = pack.n_scenarios, int(pack.degrade.shape[1])
-            R = pack.n_regions
-        else:
-            pack = np.asarray(pack, dtype=np.float32)
-            S, V = int(pack.shape[0]), int(pack.shape[1])
-            R = None
-        key = CoalesceKey.of(self.graph, self.cfg, self.use_pallas,
-                             self.interpret, fid, obj_set)
-        self._fleets[fid] = _Fleet(
-            pack=pack, key=key, n_scenarios=S, n_devices=V,
-            objectives=obj_set,
-            pricer=DispatchPricer(len(self.graph.edges), V, R,
-                                  cfg=self.admission))
+        with obs.span("serve.register") as sp:
+            structured = isinstance(pack, RegionFleetFamily)
+            if not structured and not isinstance(pack, jax.Array):
+                pack = np.asarray(pack, dtype=np.float32)
+            digest = fleet_digest(pack)
+            fid = digest if obj_set is None \
+                else f"{digest}:{abs(hash(obj_set)):x}"
+            moved = 0
+            if fid not in self._fleets:
+                if structured:
+                    S, V = pack.n_scenarios, int(pack.degrade.shape[1])
+                    R = pack.n_regions
+                else:
+                    pack, moved = self._resident(digest, pack)
+                    S, V = int(pack.shape[0]), int(pack.shape[1])
+                    R = None
+                key = CoalesceKey.of(self.graph, self.cfg, self.use_pallas,
+                                     self.interpret, fid, obj_set)
+                self._fleets[fid] = _Fleet(
+                    pack=pack, key=key, n_scenarios=S, n_devices=V,
+                    objectives=obj_set,
+                    pricer=DispatchPricer(len(self.graph.edges), V, R,
+                                          cfg=self.admission))
+            if obs.enabled():
+                sp.set(device_bytes=moved)
         return fid
+
+    def _resident(self, digest: str, pack) -> tuple[object, int]:
+        """The pack held for this content digest, made on first sight, and
+        the bytes this call put on the device: a float32 device copy,
+        waited for, or the float32 host pack where the device is out of
+        memory."""
+        held = self._packs.get(digest)
+        if held is not None:
+            return held, 0
+        try:
+            held = jnp.asarray(pack, jnp.float32).block_until_ready()
+        except jax.errors.JaxRuntimeError as err:
+            if "RESOURCE_EXHAUSTED" not in str(err):
+                raise
+            held = np.asarray(pack, np.float32)
+        self._packs[digest] = held
+        on_device = isinstance(held, jax.Array) and held is not pack
+        return held, held.nbytes if on_device else 0
 
     # -- submission (normalize → bucket → admit → queue) ---------------------
     def submit(self, tenant: str, fleet_id: str,

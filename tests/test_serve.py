@@ -3,6 +3,9 @@ score_grid, padding non-leak, cross-tenant merging), streaming, typed
 admission verdicts, and per-kind post-processing parity with the decision
 layer."""
 
+import gc
+
+import jax
 import numpy as np
 import pytest
 
@@ -348,9 +351,10 @@ def test_served_step_spans_under_the_profiler(telemetry, host_trace):
                                                    "score_grid"))]
     names = [e[0] for e in spans]
     assert {n: names.count(n) for n in set(names)} == {
-        "serve.admit": 2, "serve.enqueue": 2, "serve.step": 1,
-        "serve.chunk": 2, "serve.assemble": 2, "score_grid": 2,
-        "grid.upload": 2, "serve.fetch": 2, "serve.finalize": 2}
+        "serve.register": 1, "serve.admit": 2, "serve.enqueue": 2,
+        "serve.step": 1, "serve.chunk": 2, "serve.assemble": 2,
+        "score_grid": 2, "grid.upload": 2, "serve.fetch": 2,
+        "serve.finalize": 2}
     for name, a, b, _ in spans:
         if name in SPAN_PARENT:
             assert any(p == SPAN_PARENT[name] and pa <= a and b <= pb
@@ -367,9 +371,9 @@ def test_served_step_spans_under_the_profiler(telemetry, host_trace):
             if e[0] == "serve.enqueue"] == ids
     S, V = coms.shape[0], coms.shape[1]
     n_ops = xs[0].shape[1]
-    # padded placements, the host pack, dq (S, bucket), beta (bucket,)
-    want = sorted(4 * (b * n_ops * V + S * V * V + S * b + b)
-                  for b in (4, 1))
+    # padded placements, dq (S, bucket), beta (bucket,): the pack crossed
+    # once, at registration
+    want = sorted(4 * (b * n_ops * V + S * b + b) for b in (4, 1))
     assert sorted(e[3]["h2d_bytes"] for e in spans
                   if e[0] == "grid.upload") == want
     assert sorted(e[3]["d2h_bytes"] for e in spans
@@ -395,6 +399,125 @@ def test_upload_counts_only_host_operands(telemetry):
            if e["name"] == "grid.upload"]
     assert got == [x.nbytes + coms.nbytes + dq.nbytes + beta.nbytes,
                    x.nbytes + dq.nbytes + beta.nbytes]
+
+
+def test_dense_pack_crosses_once_at_registration(telemetry):
+    """A dense pack crosses to the device when it is registered, once per
+    content: a second objective set over the same pack moves nothing, and
+    no served upload carries it.  The answers are bitwise a direct
+    score_grid on the NumPy pack."""
+    g, coms, placements = _setup(n_fleets=2)
+    S, V = coms.shape[0], coms.shape[1]
+    svc = WhatIfService(g, admission=RELAXED, max_chunk_rows=4)
+    fid = svc.register_fleet("a", coms)
+    fid_m = svc.register_fleet("a", coms, objectives=OBJ2)
+    reg = [e["args"] for e in obs.trace_events()
+           if e["name"] == "serve.register"]
+    assert [a["device_bytes"] for a in reg] == [S * V * V * 4, 0]
+    x = placements(3)
+    tk = svc.submit("a", fid, WhatIfQuery(kind="score", placements=x,
+                                          dq=0.3, beta=0.5))
+    svc.submit("b", fid_m, WhatIfQuery(kind="pareto",
+                                       placements=placements(2)))
+    svc.drain()
+    n_ops = x.shape[1]
+    got = sorted(e["args"]["h2d_bytes"] for e in obs.trace_events()
+                 if e["name"] == "grid.upload")
+    # placements padded to the bucket, dq (S, bucket), beta (bucket,)
+    assert got == sorted(4 * (b * n_ops * V + S * b + b) for b in (4, 2))
+    res = _result(svc.poll("a"), tk.query_id)
+    direct = BatchedEvaluator.shared(g).score_grid(x, coms, dq=0.3,
+                                                   beta=0.5)
+    np.testing.assert_array_equal(res.scores,
+                                  np.asarray(direct, np.float32))
+
+
+def _live_packs(shape) -> int:
+    gc.collect()
+    return sum(a.shape == shape for a in jax.live_arrays())
+
+
+def test_dense_pack_is_held_once_per_content_on_the_device():
+    """One device array per content digest and service: the plain and the
+    multi-objective fleet of one pack share it, a float64 pack is held as
+    float32, and the copy goes with the service."""
+    g, coms, placements = _setup(n_dev=5, n_fleets=7)
+    before = _live_packs(coms.shape)
+    svc = WhatIfService(g, admission=RELAXED)
+    fid = svc.register_fleet("a", coms.astype(np.float64))
+    fid_m = svc.register_fleet("b", coms, objectives=OBJ2)
+    assert fid_m.startswith(fid)
+    assert _live_packs(coms.shape) == before + 1
+    assert any(a.dtype == np.float32 and a.shape == coms.shape
+               and np.array_equal(np.asarray(a), coms)
+               for a in jax.live_arrays())
+    x = placements(3)
+    tk = svc.submit("a", fid, WhatIfQuery(kind="score", placements=x))
+    svc.drain()
+    np.testing.assert_array_equal(
+        _result(svc.poll("a"), tk.query_id).scores,
+        np.asarray(BatchedEvaluator.shared(g).score_grid(x, coms)))
+    del svc
+    assert _live_packs(coms.shape) == before
+
+
+def test_device_pack_is_registered_in_place(telemetry):
+    """A pack already on the device is neither copied nor moved: the
+    registration puts no bytes on the device and holds no second array."""
+    g, coms, placements = _setup(n_dev=3, n_fleets=6)
+    on_device = jax.device_put(coms)
+    before = _live_packs(coms.shape)
+    svc = WhatIfService(g, admission=RELAXED)
+    fid = svc.register_fleet("a", on_device)
+    assert fid == fleet_digest(coms)
+    assert _live_packs(coms.shape) == before
+    (reg,) = [e["args"] for e in obs.trace_events()
+              if e["name"] == "serve.register"]
+    assert reg["device_bytes"] == 0
+    x = placements(2)
+    tk = svc.submit("a", fid, WhatIfQuery(kind="score", placements=x))
+    svc.drain()
+    np.testing.assert_array_equal(
+        _result(svc.poll("a"), tk.query_id).scores,
+        np.asarray(BatchedEvaluator.shared(g).score_grid(x, coms)))
+
+
+@pytest.mark.parametrize("status", ["RESOURCE_EXHAUSTED", "INTERNAL"])
+def test_full_device_keeps_the_dense_pack_on_the_host(telemetry, monkeypatch,
+                                                      status):
+    """Where the device has no room for a dense pack, registration still
+    succeeds: the float32 host pack is held and every dispatch copies it,
+    with answers bitwise a direct score_grid.  Any other device error at
+    registration is raised."""
+    g, coms, placements = _setup(n_dev=6, n_fleets=5)
+    S, V = coms.shape[0], coms.shape[1]
+    svc = WhatIfService(g, admission=RELAXED, max_chunk_rows=4)
+    real = jax.numpy.asarray
+
+    def full(a, *args, **kw):
+        if getattr(a, "shape", None) == coms.shape:
+            raise jax.errors.JaxRuntimeError(f"{status}: no room for the pack")
+        return real(a, *args, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.numpy, "asarray", full)
+        if status != "RESOURCE_EXHAUSTED":
+            with pytest.raises(jax.errors.JaxRuntimeError, match=status):
+                svc.register_fleet("a", coms)
+            return
+        fid = svc.register_fleet("a", coms)
+        assert svc.register_fleet("b", coms, objectives=OBJ2) != fid
+    assert [e["args"]["device_bytes"] for e in obs.trace_events()
+            if e["name"] == "serve.register"] == [0, 0]
+    x = placements(3)
+    tk = svc.submit("a", fid, WhatIfQuery(kind="score", placements=x))
+    svc.drain()
+    (up,) = [e["args"]["h2d_bytes"] for e in obs.trace_events()
+             if e["name"] == "grid.upload"]
+    assert up == 4 * (4 * x.shape[1] * V + S * V * V + S * 4 + 4)
+    np.testing.assert_array_equal(
+        _result(svc.poll("a"), tk.query_id).scores,
+        np.asarray(BatchedEvaluator.shared(g).score_grid(x, coms)))
 
 
 def test_served_path_builds_nothing_with_telemetry_off(monkeypatch):
