@@ -122,25 +122,24 @@ def _dense_races(rng, sweep, interpret: bool, backend: str):
 
 def _structured_races(rng, sweep, interpret: bool, backend: str):
     races, rows = [], []
-    xla = jax.jit(lambda xi, xj, mass, a, corr: jnp.max(
-        xi * (jnp.einsum("ber,bru->beu", mass, a) + corr * xj), axis=-1))
+    xla = jax.jit(lambda xi, mass, a, w: jnp.max(
+        xi * (jnp.einsum("ber,bru->beu", mass, a) + w), axis=-1))
     for V in sweep:
         xi = jnp.asarray(rng.standard_normal((STRUCT_B, STRUCT_E, V)),
-                         jnp.float32)
-        xj = jnp.asarray(rng.standard_normal((STRUCT_B, STRUCT_E, V)),
                          jnp.float32)
         mass = jnp.asarray(rng.standard_normal((STRUCT_B, STRUCT_E,
                                                 STRUCT_R)), jnp.float32)
         a = jnp.asarray(rng.standard_normal((1, STRUCT_R, V)), jnp.float32)
-        corr = jnp.asarray(rng.standard_normal((1, 1, V)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((STRUCT_B, STRUCT_E, V)),
+                        jnp.float32)
         tuned = autotune.get_config("structured", STRUCT_B, STRUCT_E, V,
                                     STRUCT_R, com_batch=1, backend=backend)
-        xla_t = _time(lambda: xla(xi, xj, mass, a, corr))
+        xla_t = _time(lambda: xla(xi, mass, a, w))
         fixed_t = _time(lambda: edge_latency_structured_pallas(
-            xi, xj, mass, a, corr, block_edges=FIXED.block_edges,
+            xi, mass, a, w, block_edges=FIXED.block_edges,
             block_v=FIXED.block_v, interpret=interpret))
         tuned_t = _time(lambda: edge_latency_structured_pallas(
-            xi, xj, mass, a, corr, block_edges=tuned.block_edges,
+            xi, mass, a, w, block_edges=tuned.block_edges,
             block_v=tuned.block_v, interpret=interpret))
         races.append(_race_entry(
             "structured", V, STRUCT_E, STRUCT_B, STRUCT_R, xla_t, fixed_t,

@@ -30,6 +30,7 @@ from repro.core.graph import OpGraph
 __all__ = ["SmoothConfig", "make_latency_fn", "make_objective_fn",
            "make_edge_latencies_com_fn", "make_latency_com_fn",
            "make_edge_latencies_region_fn", "make_latency_region_fn",
+           "region_a_off", "region_own", "region_terms", "region_times",
            "critical_path_dp"]
 
 
@@ -77,20 +78,18 @@ def make_latency_fn(graph: OpGraph, fleet: ExplicitFleet | RegionFleet,
     sel = [op.selectivity for op in graph.operators]
 
     if isinstance(fleet, RegionFleet):
-        region = jnp.asarray(fleet.region)
-        d = fleet.degrade_or_ones()
-        # index in numpy BEFORE tracing: a traced inter[region] gather gets
-        # constant-folded per edge — minutes of XLA time at 10⁵ devices
-        inter_dev = jnp.asarray(fleet.inter[fleet.region] * d[:, None])  # (V, R)
-        # u==v is priced at d²·inter[r,r] by the matvec; correct to self_cost
-        corr = jnp.asarray(
-            fleet.self_cost - d * d * np.diag(fleet.inter)[fleet.region])
-        d_j = jnp.asarray(d)
+        region = np.asarray(fleet.region, dtype=np.int64)
+        inter = jnp.asarray(fleet.inter)
+        d = jnp.asarray(fleet.degrade_or_ones())
+        # factor the scenario BEFORE tracing: a gather of the fleet's
+        # constants by region gets constant-folded per edge — minutes of
+        # XLA time at 10⁵ devices
+        own = region_own(inter, d, region)
 
         def com_times(x_j):
-            mass = jax.ops.segment_sum(d_j * x_j, region,
-                                       num_segments=fleet.n_regions)
-            return inter_dev @ mass + corr * x_j
+            mass, w = region_terms(x_j, d, own, region, fleet.n_regions,
+                                   fleet.self_cost)
+            return region_times(mass, w, inter, d, region)
     else:
         com = jnp.asarray(fleet.com_cost)
 
@@ -207,31 +206,88 @@ def make_latency_com_fn(graph: OpGraph, cfg: SmoothConfig = SmoothConfig(),
 #
 # The dense com-traced twins above need the (V, V) matrix as an operand —
 # fine for scenario batches of modest V, hopeless at the 10⁵-device fleets
-# the paper targets.  These twins generalize the segment-sum ``com_times``
-# closure of make_latency_fn into argument-taking functions: the *region
-# assignment* is static (a what-if family shares the fleet layout) while the
-# (R, R) inter matrix and (V,) per-device degrade multipliers are traced —
-# so vmapping over (inter, degrade) pairs scores a whole RegionFleetFamily
-# without ever materializing an (S, V, V) tensor.  Per edge the math is
+# the paper targets.  These twins price transfers through region space: the
+# *region assignment* is static (a what-if family shares the fleet layout)
+# while the (R, R) inter matrix and (V,) per-device degrade multipliers are
+# traced — so vmapping over (inter, degrade) pairs scores a whole
+# RegionFleetFamily without ever materializing an (S, V, V) tensor.  Per
+# destination row x_j, with dj = d·x_j and mass_r = Σ_{v ∈ region r} dj_v,
 #
-#   t_u = d_u · Σ_r inter[r_u, r] · mass_r  +  (self_cost − d_u²·inter[r_u,r_u])·x_{j,u}
-#   mass_r = Σ_{v ∈ region r} d_v · x_{j,v}
+#   t_u = d_u · Σ_{r ≠ r_u} inter[r_u, r] · mass_r          (mass @ a_off)
+#       + d_u · inter[r_u, r_u] · loo_u  +  self_cost · x_{j,u}      (w)
+#   loo_u = Σ_{v ∈ r_u, v ≠ u} dj_v
 #
-# i.e. O(E·(V·R + R²)) work and O(E·V) memory — linear in V.
+# i.e. O(V·R + R²) work and O(V) memory per row — linear in V.  A device's
+# transfer to itself never enters a float32 sum: ``loo`` is the region's
+# mass less u's own term only where that term is at most about half of it
+# (the difference then keeps at least half the mass, so its relative error
+# stays a few ulps), and otherwise the region's sum over the devices that
+# do not dominate it.  A region has at most one dominant device, so that
+# second sum leaves out exactly u's term.  Pricing u's self-pair into
+# ``mass @ A`` and subtracting it afterwards instead cancels catastrophically
+# where one heavily degraded device holds both ends of an edge.
 
-def _region_factors(inter: jnp.ndarray, degrade: jnp.ndarray,
-                    region_ix: jnp.ndarray, self_cost: float):
-    """The structured pricing rule, factored once for every consumer
-    (this module's region twin, the batched evaluator's Pallas precompute):
+#: a device whose term exceeds this share of its region's mass is priced
+#: from the rest of the region; just above ½, so that rounding in a float32
+#: region sum can never flag two devices of one region
+DOMINANT_SHARE = 0.5 + 2.0 ** -9
 
-        a[r, u]  = degrade_u · inter[region_u, r]                  (R, V)
-        corr[u]  = self_cost − degrade_u² · inter[r_u, r_u]        (V,)
 
-    so ``t = mass @ a + corr·x_j`` prices one scenario's per-device transfer
-    times.  vmap over (inter, degrade) pairs for a whole family."""
-    a = degrade[None, :] * inter.T[:, region_ix]             # (R, V)
-    corr = self_cost - degrade * degrade * jnp.diag(inter)[region_ix]
-    return a, corr
+def region_own(inter: jnp.ndarray, degrade: jnp.ndarray, region_ix):
+    """(V,) ``degrade_u · inter[r_u, r_u]``: what one unit of ``d·x_j`` on
+    another device of u's region costs u.  vmap over (inter, degrade)
+    pairs for a whole family."""
+    return degrade * jnp.diagonal(inter)[region_ix]
+
+
+def region_a_off(inter: jnp.ndarray, degrade: jnp.ndarray, region_ix):
+    """(R, V) ``a_off[r, u] = degrade_u · inter[r_u, r]`` for r ≠ r_u, 0 in
+    u's own region: the factor of ``t = mass @ a_off + w`` for a kernel
+    that contracts the masses itself."""
+    n_regions = inter.shape[0]
+    own_col = jnp.arange(n_regions)[:, None] == jnp.asarray(region_ix)[None, :]
+    return jnp.where(own_col, 0.0, degrade[None, :] * inter.T[:, region_ix])
+
+
+def region_terms(x_j: jnp.ndarray, degrade: jnp.ndarray, own: jnp.ndarray,
+                 region_ix, n_regions: int, self_cost: float,
+                 segment_sum=None, per_device=None):
+    """The placement-dependent terms of ``t = mass @ a_off + w`` for rows
+    ``x_j`` (..., V) ≥ 0 against one scenario (``own`` from
+    :func:`region_own`): the region masses (..., R) and ``w`` (..., V),
+    each device's own-region transfer over the OTHER devices of its region
+    plus its transfer to itself (module comment above).  The caller may
+    give its route's ``segment_sum`` (..., V) → (..., R), the sum over
+    each region's devices (default a scatter-add), and ``per_device``
+    (..., R) → (..., V), each device's entry of its region (default a
+    gather); each must treat every row on its own, whatever rows ride with
+    it, and ``per_device`` must be exact."""
+    if segment_sum is None:
+        def segment_sum(v):
+            out = jnp.zeros(v.shape[:-1] + (n_regions,), v.dtype)
+            return out.at[..., region_ix].add(v)
+    if per_device is None:
+        def per_device(m):
+            return m[..., region_ix]
+    dj = degrade * x_j
+    mass = segment_sum(dj)
+    mass_u = per_device(mass)
+    dom = dj > DOMINANT_SHARE * mass_u
+    rest = segment_sum(jnp.where(dom, 0.0, dj))
+    loo = jnp.where(dom, per_device(rest), mass_u - dj)
+    return mass, own * loo + self_cost * x_j
+
+
+def region_times(mass: jnp.ndarray, w: jnp.ndarray, inter: jnp.ndarray,
+                 degrade: jnp.ndarray, region_ix) -> jnp.ndarray:
+    """``t = mass @ a_off + w`` (..., V) in XLA, each row on its own: the
+    other regions' share as an (R, R) sum per row, gathered per device.  A
+    row's times are then the same bits whatever batch it rides in, which a
+    (rows, R) @ (R, V) matmul, blocked by its row count, does not give."""
+    n_regions = inter.shape[0]
+    inter_off = inter * (1.0 - jnp.eye(n_regions, dtype=inter.dtype))
+    y = (mass[..., None, :] * inter_off).sum(-1)   # y_q = Σ_{r≠q} inter·mass
+    return degrade * y[..., region_ix] + w
 
 
 def make_edge_latencies_region_fn(graph: OpGraph, region: np.ndarray,
@@ -251,18 +307,19 @@ def make_edge_latencies_region_fn(graph: OpGraph, region: np.ndarray,
     sel_j = jnp.asarray(sel)
     region_ix = jnp.asarray(np.asarray(region, dtype=np.int64))
     alpha = cfg.alpha
-    n_edges = graph.n_edges
 
     def elat(x: jnp.ndarray, inter: jnp.ndarray,
              degrade: jnp.ndarray) -> jnp.ndarray:
-        x_i = x[src_j] * sel_j[:, None]                  # (E, V)
-        x_j = x[dst_j]                                   # (E, V)
-        dj = degrade[None, :] * x_j                      # (E, V)
-        mass = jnp.zeros((n_edges, n_regions), x.dtype)  # (E, R)
-        mass = mass.at[:, region_ix].add(dj)             # segment sum over V
-        a, corr = _region_factors(inter, degrade, region_ix, self_cost)
-        t = mass @ a.astype(x.dtype) + corr.astype(x.dtype)[None, :] * x_j
-        out = jnp.max(x_i * t, axis=1)                   # (E,)
+        degrade = degrade.astype(x.dtype)
+        # every edge into operator j shares j's terms: price per operator
+        # (n_ops, V), then gather per edge
+        with jax.named_scope("region.terms"):
+            own = region_own(inter.astype(x.dtype), degrade, region_ix)
+            mass, w = region_terms(x, degrade, own, region_ix, n_regions,
+                                   self_cost)
+            t = region_times(mass, w, inter.astype(x.dtype), degrade,
+                             region_ix)[dst_j]           # (E, V)
+        out = jnp.max(x[src_j] * sel_j[:, None] * t, axis=1)   # (E,)
         if alpha:
             nz = (x > nz_eps).astype(x.dtype)
             counts = nz.sum(axis=1)

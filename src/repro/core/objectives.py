@@ -40,8 +40,10 @@ per-objective grids already hold):
   ``occupancy_imbalance``   max_u − mean_u occupancy (load skew, 0 ⇒ even)
 
 Structured network movement collapses to a degrade-weighted region-mass
-quadratic form — ``mᵀ·inter·m`` with ``m_r = Σ_{v∈r} degrade_v·x_v`` minus
-the u == v diagonal — O(R² + V) per edge, mirroring ``_com_times_x``.
+quadratic form over pairs of distinct regions — ``mᵀ·inter_off·m`` with
+``m_r = Σ_{v∈r} degrade_v·x_v`` — plus the same-region pairs from
+``jaxmodel.region_terms``, which never adds the u == v pair: O(R² + V) per
+edge.
 """
 
 from __future__ import annotations
@@ -58,7 +60,8 @@ from repro.core.costmodel import (CostConfig, device_occupancy, latency,
 from repro.core.devices import ExplicitFleet, RegionFleet
 from repro.core.graph import OpGraph
 from repro.core.jaxmodel import (SmoothConfig, _edge_arrays,
-                                 make_latency_com_fn, make_latency_region_fn)
+                                 make_latency_com_fn, make_latency_region_fn,
+                                 region_own, region_terms)
 
 __all__ = [
     "ObjectiveSpec",
@@ -166,7 +169,6 @@ def _make_structured_movement(weighted: bool):
         src_j, dst_j = jnp.asarray(src), jnp.asarray(dst)
         w = jnp.asarray(_edge_movement_weights(graph))
         region_ix = jnp.asarray(np.asarray(region, dtype=np.int64))
-        n_ops = graph.n_ops
 
         def f(x, inter, degrade, speed):
             if not weighted:
@@ -174,17 +176,21 @@ def _make_structured_movement(weighted: bool):
                 pair = tot[src_j] * tot[dst_j] \
                     - (x[src_j] * x[dst_j]).sum(1)
                 return w.astype(x.dtype) @ pair
-            # Σ_{u≠v} d_u·d_v·inter[r_u,r_v]·x_iu·x_jv as a degrade-weighted
-            # region-mass quadratic form minus the u == v diagonal — the
-            # bilinear twin of _com_times_x's matvec, O(R² + V) per edge
-            # with the (n_ops, R) masses segment-summed ONCE per placement
+            # Σ_{u≠v} d_u·d_v·inter[r_u,r_v]·x_iu·x_jv: the cross-region
+            # pairs as a region-mass quadratic form with inter's diagonal
+            # zeroed, the same-region pairs as x_i against region_terms' w
+            # (at self_cost 0: each device's own-region transfer over the
+            # OTHER devices of its region), so no u == v pair is added and
+            # subtracted again; O(R² + V) per edge with the (n_ops, ·)
+            # terms computed ONCE per placement
             d = degrade.astype(x.dtype)
-            mass = jnp.zeros((n_ops, n_regions), x.dtype)
-            mass = mass.at[:, region_ix].add(d[None, :] * x)   # (n_ops, R)
-            quad = jnp.einsum("er,rq,eq->e", mass[src_j],
-                              inter.astype(x.dtype), mass[dst_j])
-            diag = (d * d * jnp.diagonal(inter).astype(x.dtype)[region_ix])
-            pair = quad - (x[src_j] * diag[None, :] * x[dst_j]).sum(1)
+            inter = inter.astype(x.dtype)
+            mass, w_in = region_terms(x, d, region_own(inter, d, region_ix),
+                                      region_ix, n_regions, 0.0)
+            inter_off = inter * (1.0 - jnp.eye(n_regions, dtype=x.dtype))
+            quad = jnp.einsum("er,rq,eq->e", mass[src_j], inter_off,
+                              mass[dst_j])
+            pair = quad + (x[src_j] * w_in[dst_j]).sum(1)
             return w.astype(x.dtype) @ pair
 
         return f

@@ -115,8 +115,8 @@ def vmem_bytes(kind: str, E: int, V: int, R: int | None,
         inputs = g.be * g.bv + g.be * g.bv + g.bv * g.bv  # xi, xj, com
         scratch = g.be * g.bv                             # t accumulator
     else:
-        # xi, xj, mass, a, corr
-        inputs = 2 * g.be * g.bv + g.be * g.r_pad + g.r_pad * g.bv + g.bv
+        # xi, mass, a, w
+        inputs = 2 * g.be * g.bv + g.be * g.r_pad + g.r_pad * g.bv
         scratch = 0
     return BYTES_F32 * (2 * inputs + scratch + 2 * g.be)
 
@@ -142,11 +142,10 @@ def predict_seconds(kind: str, B: int, E: int, V: int, R: int | None,
     else:
         steps = B * g.n_e * g.n_u
         flops = 2.0 * B * g.e_pad * g.r_pad * g.v_pad \
-            + 4.0 * B * g.e_pad * g.v_pad
-        traffic = (2 * B * g.e_pad * g.v_pad        # xi, xj: once per (e, u)
+            + 3.0 * B * g.e_pad * g.v_pad
+        traffic = (2 * B * g.e_pad * g.v_pad        # xi, w: once per (e, u)
                    + B * g.e_pad * g.r_pad * g.n_u  # mass: re-read per u blk
                    + com_batch * g.r_pad * g.v_pad * g.n_e  # a: per e block
-                   + com_batch * g.v_pad * g.n_e    # corr: per e block
                    + B * g.e_pad)
     overhead = STEP_OVERHEAD_S.get(backend, STEP_OVERHEAD_DEFAULT_S)
     return max(flops / peaks.flops, BYTES_F32 * traffic / peaks.hbm_bw) \

@@ -126,11 +126,11 @@ def _edge_latency_xla(x_i, x_j, com):
     return jnp.max(x_i.astype(jnp.float32) * t, axis=-1)
 
 
-def _edge_latency_structured_xla(x_i, x_j, mass, a, corr):
+def _edge_latency_structured_xla(x_i, mass, a, w):
     t = jnp.einsum("ber,bru->beu", mass.astype(jnp.float32),
                    a.astype(jnp.float32))
-    t = t + corr.astype(jnp.float32) * x_j.astype(jnp.float32)
-    return jnp.max(x_i.astype(jnp.float32) * t, axis=-1)
+    return jnp.max(x_i.astype(jnp.float32) * (t + w.astype(jnp.float32)),
+                   axis=-1)
 
 
 def edge_latency(x_i, x_j, com, *, use_pallas: bool | None = None,
@@ -154,14 +154,14 @@ def edge_latency(x_i, x_j, com, *, use_pallas: bool | None = None,
                                interpret=plan.interpret)
 
 
-def edge_latency_structured(x_i, x_j, mass, a, corr, *,
+def edge_latency_structured(x_i, mass, a, w, *,
                             use_pallas: bool | None = None,
                             interpret: bool | None = None,
                             backend: str | None = None,
                             block_edges: int | None = None,
                             block_v: int | None = None):
     """Structured (RegionFleet) edge-latency max through the dispatch
-    policy: t = mass @ a + corr·x_j with R ≪ V (see kernels/edge_latency)."""
+    policy: t = mass @ a + w with R ≪ V (see kernels/edge_latency)."""
     B, E, V = x_i.shape
     if E == 0:
         return jnp.zeros((B, 0), jnp.float32)
@@ -170,7 +170,7 @@ def edge_latency_structured(x_i, x_j, mass, a, corr, *,
                             backend=backend, com_batch=a.shape[0],
                             block_edges=block_edges, block_v=block_v)
     if plan.impl == "xla":
-        return _edge_latency_structured_xla(x_i, x_j, mass, a, corr)
+        return _edge_latency_structured_xla(x_i, mass, a, w)
     return edge_latency_structured_pallas(
-        x_i, x_j, mass, a, corr, block_edges=plan.config.block_edges,
+        x_i, mass, a, w, block_edges=plan.config.block_edges,
         block_v=plan.config.block_v, interpret=plan.interpret)

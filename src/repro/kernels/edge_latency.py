@@ -23,9 +23,10 @@ Compiled-ready blocking scheme (see kernels/README.md for the full story):
     u axis folds per-block row maxima into the output with a running max —
     so the (E, V) endpoint rows and the (V, V) com matrix stream through
     VMEM in (be, bv) / (bv, bv) tiles instead of requiring residency;
-  * the STRUCTURED kernel (RegionFleetFamily: ``t = mass @ A + corr·x_j``
-    with R ≪ V) runs a (B, E/be, V/bv) grid, V-blocking its (be, R)@(R, bv)
-    product and diagonal correction with the same running max over u-tiles;
+  * the STRUCTURED kernel (RegionFleetFamily: ``t = mass @ A + w`` with
+    R ≪ V) runs a (B, E/be, V/bv) grid, V-blocking its (be, R)@(R, bv)
+    product and the own-region term ``w`` with the same running max over
+    u-tiles;
   * both kernels write a lane-dense (B, e_pad, LANE) output in (1, be, LANE)
     blocks, every lane holding the edge's running max: Mosaic requires an
     output block's last two dims to be (8, 128)-aligned or whole, which a
@@ -217,47 +218,56 @@ def edge_latency_pallas(x_i, x_j, com, block_edges: int = 128,
 # -- structured (RegionFleet) V-blocked kernel --------------------------------
 #
 # At 10⁵ devices the (V, V) com matrix no longer exists; the structured path
-# factors the per-edge matvec through region space:
+# factors the per-edge matvec through region space
+# (``repro.core.jaxmodel.region_a_off`` / ``region_terms``):
 #
-#   t[e, u] = Σ_r A[r, u] · mass[e, r]  +  corr[u] · x_j[e, u]
-#   A[r, u] = degrade_u · inter[region_u, r]          (R, V), per scenario
-#   mass[e, r] = Σ_{v ∈ region r} degrade_v · x_j[e, v]   (E, R), XLA scatter
+#   t[e, u] = Σ_r A[r, u] · mass[e, r]  +  w[e, u]
+#   A[r, u] = degrade_u · inter[region_u, r], 0 in u's own region (R, V)
+#   mass[e, r] = Σ_{v ∈ region r} degrade_v · x_j[e, v]          (E, R)
+#   w[e, u] = u's own-region transfer over the region's other devices,
+#             plus its transfer to itself                          (E, V)
 #
 # so the kernel's inner product is (be, R) @ (R, bv) — R ≪ V — and the only
-# V-sized operands are the same (E, V) endpoint rows the dense kernel already
-# streams.  The caller precomputes ``mass``/``A``/``corr`` (cheap XLA
-# gathers/scatters, no V² anywhere); here the u axis is V-blocked with the
-# same running max as the dense kernel, so A/corr/x tiles stream through
-# VMEM in (R, bv)/(1, bv)/(be, bv) slices and V = 131 072 fleets never need
-# a V-resident row.  R pads to the lane width (zero rows of mass/A add
+# V-sized operands are two (E, V) rows, as many as the dense kernel
+# streams.  The caller precomputes ``mass``/``A``/``w`` (XLA gathers and
+# segment sums, no V² anywhere); here the u axis is V-blocked with the
+# same running max as the dense kernel, so A/w/x tiles stream through
+# VMEM in (R, bv)/(be, bv) slices and V = 131 072 fleets never need a
+# V-resident row.  R pads to the lane width (zero rows of mass/A add
 # exact zeros to the product).
 
 
-def _edge_latency_structured_blocked_kernel(v_real: int, xi_ref, xj_ref,
-                                            mass_ref, a_ref, corr_ref,
-                                            o_ref):
+def _check_structured(x_i, a, w):
+    B = x_i.shape[0]
+    if a.shape[0] not in (1, B):
+        raise ValueError(f"scenario batch dim {a.shape[0]} must be 1 or {B}")
+    if w.shape != x_i.shape:
+        raise ValueError(f"w has shape {w.shape}, want {x_i.shape}")
+
+
+def _edge_latency_structured_blocked_kernel(v_real: int, xi_ref, mass_ref,
+                                            a_ref, w_ref, o_ref):
     u = pl.program_id(2)
     xi = xi_ref[0].astype(jnp.float32)      # (be, bv) — pre-scaled by s_i
-    xj = xj_ref[0].astype(jnp.float32)      # (be, bv)
     mass = mass_ref[0].astype(jnp.float32)  # (be, Rp)
     a = a_ref[0].astype(jnp.float32)        # (Rp, bv)
-    corr = corr_ref[0].astype(jnp.float32)  # (1, bv)
+    w = w_ref[0].astype(jnp.float32)        # (be, bv)
     t = jax.lax.dot_general(mass, a, (((1,), (0,)), ((), ())),
                             precision=F32_DOT,
                             preferred_element_type=jnp.float32)
     u_ix = u * xi.shape[1] + jax.lax.broadcasted_iota(jnp.int32, xi.shape, 1)
     _fold_row_max(u, o_ref,
-                  jnp.where(u_ix < v_real, xi * (t + corr * xj), -jnp.inf))
+                  jnp.where(u_ix < v_real, xi * (t + w), -jnp.inf))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_edges", "block_v", "interpret"))
-def edge_latency_structured_pallas(x_i, x_j, mass, a, corr,
+def edge_latency_structured_pallas(x_i, mass, a, w,
                                    block_edges: int = 128,
                                    block_v: int = 512,
                                    interpret: bool = False):
-    """x_i, x_j: (B, E, V); mass: (B, E, R); a: (Bc, R, V); corr: (Bc, 1, V)
-    with Bc ∈ {1, B} → (B, E) latencies ``max_u x_i·(mass @ a + corr·x_j)``.
+    """x_i, w: (B, E, V); mass: (B, E, R); a: (Bc, R, V) with Bc ∈ {1, B}
+    → (B, E) latencies ``max_u x_i·(mass @ a + w)``.
 
     V-blocked over the u axis with a running max (module docstring); R pads
     to the lane width with exact-zero rows.  A singleton scenario batch
@@ -267,28 +277,24 @@ def edge_latency_structured_pallas(x_i, x_j, mass, a, corr,
     R = mass.shape[-1]
     if E == 0:
         return jnp.zeros((B, 0), jnp.float32)
-    if a.shape[0] not in (1, B) or corr.shape[0] != a.shape[0]:
-        raise ValueError(
-            f"scenario batch dims {a.shape[0]}/{corr.shape[0]} must match "
-            f"and be 1 or {B}")
+    _check_structured(x_i, a, w)
     shared = a.shape[0] == 1
     g = block_geometry("structured", E, V, R, block_edges, block_v)
     x_i = _pad_axis(_pad_axis(x_i, 2, g.v_pad), 1, g.e_pad)
-    x_j = _pad_axis(_pad_axis(x_j, 2, g.v_pad), 1, g.e_pad)
+    w = _pad_axis(_pad_axis(w, 2, g.v_pad), 1, g.e_pad)
     mass = _pad_axis(_pad_axis(mass, 2, g.r_pad), 1, g.e_pad)
     a = _pad_axis(_pad_axis(a, 2, g.v_pad), 1, g.r_pad)
-    corr = _pad_axis(corr, 2, g.v_pad)
     scen_ix = (lambda b, e, u: (0, 0, u)) if shared \
         else (lambda b, e, u: (b, 0, u))
+    rows = pl.BlockSpec((1, g.be, g.bv), lambda b, e, u: (b, e, u))
     out = pl.pallas_call(
         functools.partial(_edge_latency_structured_blocked_kernel, V),
         grid=(B, g.n_e, g.n_u),
         in_specs=[
-            pl.BlockSpec((1, g.be, g.bv), lambda b, e, u: (b, e, u)),
-            pl.BlockSpec((1, g.be, g.bv), lambda b, e, u: (b, e, u)),
+            rows,
             pl.BlockSpec((1, g.be, g.r_pad), lambda b, e, u: (b, e, 0)),
             pl.BlockSpec((1, g.r_pad, g.bv), scen_ix),
-            pl.BlockSpec((1, 1, g.bv), scen_ix),
+            rows,
         ],
         out_specs=pl.BlockSpec((1, g.be, g.out_lanes),
                                lambda b, e, u: (b, e, 0)),
@@ -297,7 +303,7 @@ def edge_latency_structured_pallas(x_i, x_j, mass, a, corr,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(x_i, x_j, mass, a, corr)
+    )(x_i, mass, a, w)
     return out[:, :E, 0]
 
 
@@ -352,20 +358,19 @@ def edge_latency_pallas_single_tile(x_i, x_j, com, block_edges: int = 128,
     return out[:, :E]
 
 
-def _edge_latency_structured_single_tile_kernel(xi_ref, xj_ref, mass_ref,
-                                                a_ref, corr_ref, o_ref):
+def _edge_latency_structured_single_tile_kernel(xi_ref, mass_ref, a_ref,
+                                                w_ref, o_ref):
     xi = xi_ref[0].astype(jnp.float32)      # (be, V) — pre-scaled by s_i
-    xj = xj_ref[0].astype(jnp.float32)      # (be, V)
     mass = mass_ref[0].astype(jnp.float32)  # (be, R)
     a = a_ref[0].astype(jnp.float32)        # (R, V)
-    corr = corr_ref[0].astype(jnp.float32)  # (1, V)
+    w = w_ref[0].astype(jnp.float32)        # (be, V)
     t = jax.lax.dot_general(mass, a, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    o_ref[0] = jnp.max(xi * (t + corr * xj), axis=1)
+    o_ref[0] = jnp.max(xi * (t + w), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_edges", "interpret"))
-def edge_latency_structured_pallas_single_tile(x_i, x_j, mass, a, corr,
+def edge_latency_structured_pallas_single_tile(x_i, mass, a, w,
                                                block_edges: int = 128,
                                                interpret: bool = False):
     """The original whole-V structured kernel (parity reference; (R, V) and
@@ -374,33 +379,29 @@ def edge_latency_structured_pallas_single_tile(x_i, x_j, mass, a, corr,
     R = mass.shape[-1]
     if E == 0:
         return jnp.zeros((B, 0), jnp.float32)
-    if a.shape[0] not in (1, B) or corr.shape[0] != a.shape[0]:
-        raise ValueError(
-            f"scenario batch dims {a.shape[0]}/{corr.shape[0]} must match "
-            f"and be 1 or {B}")
+    _check_structured(x_i, a, w)
     shared = a.shape[0] == 1
     be = min(block_edges, E)
     e_pad = _round_up(E, be)
     x_i = _pad_axis(x_i, 1, e_pad)
-    x_j = _pad_axis(x_j, 1, e_pad)
+    w = _pad_axis(w, 1, e_pad)
     mass = _pad_axis(mass, 1, e_pad)
     n_blocks = x_i.shape[1] // be
-    scen_index = (lambda b, e: (0, 0, 0)) if shared \
-        else (lambda b, e: (b, 0, 0))
+    rows = pl.BlockSpec((1, be, V), lambda b, e: (b, e, 0))
     out = pl.pallas_call(
         _edge_latency_structured_single_tile_kernel,
         grid=(B, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, be, V), lambda b, e: (b, e, 0)),
-            pl.BlockSpec((1, be, V), lambda b, e: (b, e, 0)),
+            rows,
             pl.BlockSpec((1, be, R), lambda b, e: (b, e, 0)),
-            pl.BlockSpec((1, R, V), scen_index),
-            pl.BlockSpec((1, 1, V), scen_index),
+            pl.BlockSpec((1, R, V), (lambda b, e: (0, 0, 0)) if shared
+                         else (lambda b, e: (b, 0, 0))),
+            rows,
         ],
         out_specs=pl.BlockSpec((1, be), lambda b, e: (b, e)),
         out_shape=jax.ShapeDtypeStruct((B, x_i.shape[1]), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x_i, x_j, mass, a, corr)
+    )(x_i, mass, a, w)
     return out[:, :E]

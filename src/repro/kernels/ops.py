@@ -53,7 +53,7 @@ def edge_latency_max(x_i, x_j, com, interpret: bool | None = None,
                                  block_edges=block_edges, block_v=block_v)
 
 
-def edge_latency_structured_max(x_i, x_j, mass, a, corr,
+def edge_latency_structured_max(x_i, mass, a, w,
                                 interpret: bool | None = None,
                                 block_edges: int | None = None,
                                 block_v: int | None = None):
@@ -61,7 +61,7 @@ def edge_latency_structured_max(x_i, x_j, mass, a, corr,
     the RegionFleetFamily hot path (kernels/edge_latency.py), dispatched
     like :func:`edge_latency_max`."""
     return dispatch.edge_latency_structured(
-        x_i, x_j, mass, a, corr, use_pallas=True, interpret=interpret,
+        x_i, mass, a, w, use_pallas=True, interpret=interpret,
         block_edges=block_edges, block_v=block_v)
 
 

@@ -54,7 +54,8 @@ from repro.serve.admission import (AdmissionConfig, Admitted, Degraded,
 from repro.serve.bucketing import (CoalesceKey, fleet_digest, next_pow2,
                                    pad_rows)
 from repro.serve.cache import ServeStats
-from repro.sim.batched import BatchedEvaluator
+from repro.sim.batched import (BatchedEvaluator, SparsePlacements,
+                               sparse_placements)
 
 __all__ = ["WhatIfQuery", "QueryTicket", "ResultChunk", "QueryResult",
            "WhatIfService"]
@@ -192,6 +193,7 @@ class _Pending:
     dq_values: np.ndarray | None    # post-degrade
     predicted_s: float
     degraded: Degraded | None
+    sparse: SparsePlacements | None = None   # region fleets' upload form
     done_rows: int = 0
     score_cols: list = dataclasses.field(default_factory=list)
     grid_cols: dict = dataclasses.field(default_factory=dict)
@@ -356,11 +358,16 @@ class WhatIfService:
                 self.stats.admitted += 1
         qid = self._next_id
         self._next_id += 1
+        # a region fleet's rows are dense in V ~ 1e5 with a few devices
+        # each: they cross to the device as their nonzeros.  A dense
+        # fleet's V is bounded by its S·V² pack, so its rows stay dense
+        sparse = (sparse_placements(placements)
+                  if isinstance(fleet.pack, RegionFleetFamily) else None)
         with obs.span("serve.enqueue", query_id=qid):
             self._queues.setdefault(fleet.key, []).append(_Pending(
                 query_id=qid, tenant=tenant, query=q, placements=placements,
                 dq_values=dq_vals, predicted_s=verdict.predicted_s,
-                degraded=degraded))
+                degraded=degraded, sparse=sparse))
         return QueryTicket(query_id=qid, tenant=tenant, admission=verdict,
                            rows=placements.shape[0],
                            dq_steps=None if dq_vals is None
@@ -442,12 +449,20 @@ class WhatIfService:
     # -- dispatch + accounting ----------------------------------------------
     def _assemble(self, fleet: _Fleet, bucket: int,
                   live: list[tuple[_Pending, slice]]):
-        """The chunk's rows padded to ``bucket``, each query's columns
-        carrying its own dq/β (joint and padding columns 0)."""
+        """The chunk's rows padded to ``bucket`` (as SparsePlacements
+        where every live query has them), each query's columns carrying
+        its own dq/β (joint and padding columns 0)."""
         with obs.span("serve.assemble", bucket=bucket):
-            padded = pad_rows(np.concatenate(
-                [p.placements[p.done_rows:p.done_rows + sl.stop - sl.start]
-                 for p, sl in live]), bucket)
+            if all(p.sparse is not None for p, _ in live):
+                padded = SparsePlacements.concat(
+                    [p.sparse.rows(p.done_rows,
+                                   p.done_rows + sl.stop - sl.start)
+                     for p, sl in live], bucket)
+            else:
+                padded = pad_rows(np.concatenate(
+                    [p.placements[p.done_rows:
+                                  p.done_rows + sl.stop - sl.start]
+                     for p, sl in live]), bucket)
             dq = np.zeros((fleet.n_scenarios, bucket), np.float32)
             beta = np.zeros(bucket, np.float32)
             for p, sl in live:

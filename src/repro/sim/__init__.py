@@ -2,8 +2,10 @@
 (dense or structured RegionFleetFamily), trace replay (see sim/README.md for
 the generators → batched eval → replay pipeline)."""
 
-from repro.sim.batched import (BatchedEvaluator, pack_fleets, pack_placements,
-                               pack_region_fleets, pack_speeds)
+from repro.sim.batched import (BatchedEvaluator, SparsePlacements,
+                               pack_fleets, pack_placements,
+                               pack_region_fleets, pack_speeds,
+                               sparse_placements)
 from repro.sim.execache import (ExecutableCache, executable_cache,
                                 fresh_cache, graph_key, set_executable_cache)
 from repro.sim.replay import (ReplayReport, ReplayStep, apply_fleet_event,
@@ -18,7 +20,7 @@ from repro.sim.training import TrainingTuples, merge_tuples, training_tuples
 
 __all__ = [
     "BatchedEvaluator", "pack_fleets", "pack_placements", "pack_region_fleets",
-    "pack_speeds",
+    "pack_speeds", "SparsePlacements", "sparse_placements",
     "ExecutableCache", "executable_cache", "fresh_cache", "graph_key",
     "set_executable_cache",
     "ReplayReport", "ReplayStep", "apply_fleet_event", "replay_trace",

@@ -40,6 +40,7 @@ Pareto extraction and normalization (see ``src/repro/search/README.md``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,15 +52,17 @@ from repro.core.costmodel import CostConfig
 from repro.sim.execache import ExecutableCache, executable_cache, graph_key
 from repro.core.devices import ExplicitFleet, RegionFleet, RegionFleetFamily
 from repro.core.graph import OpGraph
-from repro.core.jaxmodel import (SmoothConfig, _edge_arrays, _region_factors,
-                                 critical_path_dp,
+from repro.core.jaxmodel import (SmoothConfig, _edge_arrays,
+                                 critical_path_dp, region_a_off, region_own,
+                                 region_terms,
                                  make_edge_latencies_com_fn,
                                  make_edge_latencies_region_fn)
 from repro.core.objectives import (ObjectiveGrids, ObjectiveSet,
                                    as_objective_set)
 
-__all__ = ["BatchedEvaluator", "pack_fleets", "pack_placements",
-           "pack_region_fleets", "pack_speeds"]
+__all__ = ["BatchedEvaluator", "SparsePlacements", "pack_fleets",
+           "pack_placements", "pack_region_fleets", "pack_speeds",
+           "sparse_placements"]
 
 # instance memo behind BatchedEvaluator.shared(): one evaluator per
 # (graph content, cfg, pallas flags), so independent consumers (search
@@ -103,6 +106,96 @@ def pack_region_fleets(fleets: list[RegionFleet]) -> RegionFleetFamily:
 def pack_placements(xs: list[np.ndarray], dtype=jnp.float32) -> jnp.ndarray:
     """(P, n_ops, V) stacked candidate placements."""
     return jnp.asarray(np.stack([np.asarray(x) for x in xs]), dtype=dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class SparsePlacements:
+    """(P, n_ops, V) float32 placements held as the nonzero entries of each
+    (row, operator): ``idx`` (P, n_ops, k) int32 device indices and ``val``
+    (P, n_ops, k) float32 masses, unused slots at index ``n_devices``.  A
+    row over a few devices of a 10⁵-device fleet is then ``8·k`` bytes an
+    operator instead of ``4·V``; ``score_grid`` rebuilds the dense rows on
+    the device, bit for bit."""
+
+    idx: np.ndarray
+    val: np.ndarray
+    n_devices: int
+
+    @property
+    def shape(self) -> tuple[int, int, int]:
+        return (*self.idx.shape[:2], self.n_devices)
+
+    def rows(self, start: int, stop: int) -> "SparsePlacements":
+        return SparsePlacements(self.idx[start:stop], self.val[start:stop],
+                                self.n_devices)
+
+    @staticmethod
+    def concat(parts: list["SparsePlacements"],
+               bucket: int) -> "SparsePlacements":
+        """The parts' rows, slots padded to the widest part, and the last
+        row repeated up to ``bucket`` rows (as ``serve.bucketing.pad_rows``
+        pads dense rows)."""
+        k = max(p.idx.shape[2] for p in parts)
+        V = parts[0].n_devices
+        idx = np.concatenate([np.pad(p.idx, ((0, 0), (0, 0),
+                                             (0, k - p.idx.shape[2])),
+                                     constant_values=V) for p in parts])
+        val = np.concatenate([np.pad(p.val, ((0, 0), (0, 0),
+                                             (0, k - p.val.shape[2])))
+                              for p in parts])
+        pad = bucket - idx.shape[0]
+        if pad < 0:
+            raise ValueError(f"batch of {idx.shape[0]} rows exceeds "
+                             f"bucket {bucket}")
+        if pad:
+            idx = np.concatenate([idx, np.repeat(idx[-1:], pad, axis=0)])
+            val = np.concatenate([val, np.repeat(val[-1:], pad, axis=0)])
+        return SparsePlacements(idx, val, V)
+
+
+_MIN_SLOTS = 8
+
+
+def sparse_placements(x) -> SparsePlacements | None:
+    """``x`` (P, n_ops, V) as :class:`SparsePlacements`, or None where it
+    would save too little: more than ``V/16`` nonzeros in some (row,
+    operator).  Slots are a power of two, at least ``_MIN_SLOTS``, so a
+    traffic of similar rows reuses one compiled shape.  Entries are kept
+    by their bits, so ``-0.0`` and NaN payloads come back as they were."""
+    x = np.ascontiguousarray(x, np.float32)
+    P, n, V = x.shape
+    bits = x.view(np.uint32).reshape(-1)
+    # scan for nonzero 512-word blocks first: a row over a few devices is
+    # almost all zero blocks, and the block maxima run at memory speed
+    blk = 512 if V % 512 == 0 else V
+    words = bits.reshape(-1, blk)
+    blocks = np.flatnonzero(words.max(axis=1))
+    b, c = np.nonzero(words[blocks])
+    flat = blocks[b] * blk + c
+    group, dev = np.divmod(flat, V)
+    count = np.bincount(group, minlength=P * n)
+    k = max(_MIN_SLOTS, 1 << max(int(count.max(initial=0)) - 1,
+                                 0).bit_length())
+    if k > V // 16:
+        return None
+    slot = np.arange(flat.size) - np.repeat(np.cumsum(count) - count, count)
+    idx = np.full((P * n, k), V, np.int32)
+    val = np.zeros((P * n, k), np.float32)
+    idx[group, slot] = dev
+    val.view(np.uint32)[group, slot] = bits[flat]
+    return SparsePlacements(idx.reshape(P, n, k), val.reshape(P, n, k), V)
+
+
+@functools.partial(jax.jit, static_argnames="n_devices")
+def _densify(idx: jnp.ndarray, val: jnp.ndarray,
+             n_devices: int) -> jnp.ndarray:
+    """The dense (P, n_ops, V) rows of a :class:`SparsePlacements` on the
+    device; slots at index ``n_devices`` are dropped."""
+    P, n, _ = idx.shape
+    rows = jnp.arange(P, dtype=jnp.int32)[:, None, None]
+    ops = jnp.arange(n, dtype=jnp.int32)[None, :, None]
+    return jnp.zeros((P, n, n_devices), val.dtype).at[rows, ops, idx].set(
+        val, mode="drop")
 
 
 def pack_speeds(fleets: list[Fleet], dtype=jnp.float32) -> jnp.ndarray:
@@ -305,24 +398,47 @@ class BatchedEvaluator:
                     return jax.vmap(elat_single, in_axes=(0, None, None))(
                         x, inter[0], degrade[0])           # (B, E)
                 return jax.vmap(elat_single)(x, inter, degrade)
-            # Pallas route: precompute the region-space factors (XLA
-            # gathers/scatters, all O(V·R) or smaller), fuse the rest;
-            # the pricing rule itself lives in jaxmodel._region_factors,
-            # shared with the vmap route's elat twin
-            x_i = x[:, self._src] * self._sel[None, :, None]   # (B, E, V)
-            x_j = x[:, self._dst]                              # (B, E, V)
-            dj = degrade[:, None, :] * x_j                     # (B, E, V)
-            B, E, V = x_i.shape
-            mass = jnp.zeros((B, E, n_regions), x.dtype)
-            mass = mass.at[:, :, region_ix].add(dj)            # (B, E, R)
-            a, corr = jax.vmap(
-                lambda i, d: _region_factors(i, d, region_ix, self_cost)
-            )(inter, degrade)                        # (Sb, R, V), (Sb, V)
+            # Pallas route: the region terms per destination operator, by
+            # the same jaxmodel.region_terms as the vmap route, on the MXU:
+            # the region sums in the Pallas region-sum kernel (a row's bits
+            # do not depend on its batch; a scatter-add over V costs about
+            # as much whatever its rows), the per-device gathers as matmuls
+            # with the layout's one-hot, exact at HIGHEST (one product per
+            # output, by a one) and faster than the gather on a v5e; the
+            # edge kernel fuses t = mass @ a_off + w with the row max
+            from repro.kernels.region_sum import region_sum_pallas
+            with jax.named_scope("region.terms"):
+                onehot = (jnp.arange(n_regions)[:, None]
+                          == region_ix[None, :]).astype(jnp.float32)
+
+                def segment_sum(v):
+                    return region_sum_pallas(v, onehot,
+                                             interpret=self.interpret)
+
+                def per_device(m):
+                    return jnp.matmul(m, onehot,
+                                      precision=jax.lax.Precision.HIGHEST)
+
+                def terms(x1, i, d):
+                    mass, w = region_terms(x1, d, region_own(i, d, region_ix),
+                                           region_ix, n_regions, self_cost,
+                                           segment_sum, per_device)
+                    return (region_a_off(i, d, region_ix), mass[:, self._dst],
+                            w[:, self._dst])
+
+                if inter.shape[0] == 1:
+                    a, mass, w = terms(x, inter[0], degrade[0])
+                    a = a[None]                         # (1, R, V)
+                else:
+                    a, mass, w = jax.vmap(
+                        lambda x1, i, d: terms(x1[None], i, d))(
+                        x, inter, degrade)
+                    mass, w = mass[:, 0], w[:, 0]       # (B, E, ·)
+            x_i = x[:, self._src] * self._sel[None, :, None]
             from repro.kernels.dispatch import edge_latency_structured
             out = edge_latency_structured(
-                x_i.astype(jnp.float32), x_j.astype(jnp.float32),
-                mass.astype(jnp.float32), a.astype(jnp.float32),
-                corr[:, None, :].astype(jnp.float32),
+                x_i.astype(jnp.float32), mass.astype(jnp.float32),
+                a.astype(jnp.float32), w.astype(jnp.float32),
                 use_pallas=True, interpret=self.interpret)
             return out + self._links_term(x, out.dtype)
 
@@ -519,15 +635,17 @@ class BatchedEvaluator:
         """
         structured = isinstance(coms, RegionFleetFamily)
         S = coms.n_scenarios if structured else int(np.shape(coms)[0])
-        P = int(np.shape(placements)[0])
+        P = int(placements.shape[0] if isinstance(placements, SparsePlacements)
+                else np.shape(placements)[0])
         path = "structured" if structured else "dense"
         multi = objectives is not None
         reg = obs.registry()
         if reg.enabled:
             reg.counter("eval.score_grid.dispatches", path=path).add(1)
             reg.histogram("eval.score_grid.cells", lo=1.0).observe(S * P)
-        with obs.span("score_grid", S=S, P=P, path=path,
-                      multi=multi) as sp:
+        regions = {"R": coms.n_regions} if structured else {}
+        with obs.span("score_grid", S=S, P=P, path=path, multi=multi,
+                      **regions) as sp:
             placements, pack, dq_arr, beta = self._upload(
                 placements, coms, dq, beta, S, P)
             san = sanitize.state()
@@ -549,27 +667,35 @@ class BatchedEvaluator:
         return out
 
     def _upload(self, placements, coms, dq, beta, S: int, P: int):
-        """The grid's operands as device arrays: placements, the pack (a
-        dense stack, or a family's ``(inter, degrade)``), dq and β.  In a
+        """The grid's operands as device arrays: placements (rebuilt dense
+        on the device from :class:`SparsePlacements`), the pack (a dense
+        stack, or a family's ``(inter, degrade)``), dq and β.  In a
         ``grid.upload`` span that, with telemetry on, waits for the copies
         and counts ``h2d_bytes``: the device bytes of every operand that
         was not already a ``jax.Array``."""
         with obs.span("grid.upload") as up:
             structured = isinstance(coms, RegionFleetFamily)
-            pairs = [(placements, jnp.asarray(placements))]
-            if structured:
-                pairs += zip((coms.inter, coms.degrade),
-                             self._family_args(coms))
+            if isinstance(placements, SparsePlacements):
+                idx = jnp.asarray(placements.idx)
+                val = jnp.asarray(placements.val)
+                x = _densify(idx, val, n_devices=placements.n_devices)
+                sent = [(placements.idx, idx), (placements.val, val)]
             else:
-                pairs.append((coms, jnp.asarray(coms)))
+                x = jnp.asarray(placements)
+                sent = [(placements, x)]
+            if structured:
+                pairs = list(zip((coms.inter, coms.degrade),
+                                 self._family_args(coms)))
+            else:
+                pairs = [(coms, jnp.asarray(coms))]
             pairs += [(dq, self._validate_dq(dq, S, P)),
                       (beta, self._validate_beta(beta, P))]
             out = [d for _, d in pairs]
             if obs.enabled():
-                up.sync(out)
-                up.set(h2d_bytes=_host_bytes(pairs))
-        pack = tuple(out[1:3]) if structured else out[1]
-        return out[0], pack, out[-2], out[-1]
+                up.sync([x, *out])
+                up.set(h2d_bytes=_host_bytes(sent + pairs))
+        pack = tuple(out[:2]) if structured else out[0]
+        return x, pack, out[-2], out[-1]
 
     def _dispatch_grid(self, placements, coms, pack, dq_arr, beta,
                        objectives, speed, structured: bool):

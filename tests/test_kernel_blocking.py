@@ -32,17 +32,14 @@ def _dense_oracle(xi, xj, com):
     return np.max(xi * t, axis=-1)
 
 
-def _structured_oracle(xi, xj, mass, a, corr):
-    """float64 reference: max_u xi · (mass @ a + corr·xj)_u."""
+def _structured_oracle(xi, mass, a, w):
+    """float64 reference: max_u xi · (mass @ a + w)_u."""
     xi = np.asarray(xi, np.float64)
-    xj = np.asarray(xj, np.float64)
     B = xi.shape[0]
     a64 = np.broadcast_to(np.asarray(a, np.float64),
                           (B,) + np.asarray(a).shape[1:])
-    corr64 = np.broadcast_to(np.asarray(corr, np.float64),
-                             (B,) + np.asarray(corr).shape[1:])
     t = np.einsum("ber,bru->beu", np.asarray(mass, np.float64), a64)
-    return np.max(xi * (t + corr64 * xj), axis=-1)
+    return np.max(xi * (t + np.asarray(w, np.float64)), axis=-1)
 
 
 def _rel_err(got, want):
@@ -60,12 +57,11 @@ def _dense_inputs(rng, B, E, V, shared_com):
 
 def _structured_inputs(rng, B, E, V, R, shared):
     xi = jnp.asarray(rng.standard_normal((B, E, V)), jnp.float32)
-    xj = jnp.asarray(rng.standard_normal((B, E, V)), jnp.float32)
     mass = jnp.asarray(rng.standard_normal((B, E, R)), jnp.float32)
     bc = 1 if shared else B
     a = jnp.asarray(rng.standard_normal((bc, R, V)), jnp.float32)
-    corr = jnp.asarray(rng.standard_normal((bc, 1, V)), jnp.float32)
-    return xi, xj, mass, a, corr
+    w = jnp.asarray(rng.standard_normal((B, E, V)), jnp.float32)
+    return xi, mass, a, w
 
 
 # -- geometry -----------------------------------------------------------------
@@ -164,43 +160,43 @@ def test_structured_oracle_parity_odd_R(R, shared):
     """R not a multiple of the lane width (including R > LANE) pads with
     exact-zero rows; ≤1e-5 oracle parity at odd V too."""
     rng = np.random.default_rng(R)
-    xi, xj, mass, a, corr = _structured_inputs(rng, B=2, E=5, V=300, R=R,
-                                               shared=shared)
-    got = edge_latency_structured_pallas(xi, xj, mass, a, corr,
+    xi, mass, a, w = _structured_inputs(rng, B=2, E=5, V=300, R=R,
+                                        shared=shared)
+    got = edge_latency_structured_pallas(xi, mass, a, w,
                                          block_edges=16, block_v=128,
                                          interpret=True)
-    assert _rel_err(got, _structured_oracle(xi, xj, mass, a, corr)) <= REL
+    assert _rel_err(got, _structured_oracle(xi, mass, a, w)) <= REL
 
 
 @pytest.mark.parametrize("E", [1, 33])
 def test_structured_oracle_parity_odd_E(E):
     rng = np.random.default_rng(E + 100)
-    xi, xj, mass, a, corr = _structured_inputs(rng, B=2, E=E, V=129, R=8,
-                                               shared=True)
-    got = edge_latency_structured_pallas(xi, xj, mass, a, corr,
-                                         interpret=True)
+    xi, mass, a, w = _structured_inputs(rng, B=2, E=E, V=129, R=8,
+                                        shared=True)
+    got = edge_latency_structured_pallas(xi, mass, a, w, interpret=True)
     assert got.shape == (2, E)
-    assert _rel_err(got, _structured_oracle(xi, xj, mass, a, corr)) <= REL
+    assert _rel_err(got, _structured_oracle(xi, mass, a, w)) <= REL
 
 
 def test_structured_empty_edge_set_returns_empty():
     xi = jnp.zeros((2, 0, 64), jnp.float32)
     mass = jnp.zeros((2, 0, 4), jnp.float32)
     a = jnp.zeros((1, 4, 64), jnp.float32)
-    corr = jnp.zeros((1, 1, 64), jnp.float32)
-    out = edge_latency_structured_pallas(xi, xi, mass, a, corr,
-                                         interpret=True)
+    out = edge_latency_structured_pallas(xi, mass, a, xi, interpret=True)
     assert out.shape == (2, 0)
 
 
-def test_structured_rejects_mismatched_scenario_batch():
+@pytest.mark.parametrize("a_batch, w_shape", [(2, (3, 2, 64)),
+                                             (1, (1, 2, 64))])
+def test_structured_rejects_mismatched_scenario_batch(a_batch, w_shape):
+    """A scenario batch that is neither 1 nor B, or a ``w`` that is not
+    one row per (placement, edge, device), is refused."""
     xi = jnp.zeros((3, 2, 64), jnp.float32)
     mass = jnp.zeros((3, 2, 4), jnp.float32)
-    a = jnp.zeros((2, 4, 64), jnp.float32)
-    corr = jnp.zeros((2, 1, 64), jnp.float32)
+    a = jnp.zeros((a_batch, 4, 64), jnp.float32)
+    w = jnp.zeros(w_shape, jnp.float32)
     with pytest.raises(ValueError):
-        edge_latency_structured_pallas(xi, xi, mass, a, corr,
-                                       interpret=True)
+        edge_latency_structured_pallas(xi, mass, a, w, interpret=True)
 
 
 # -- exact parity vs the single-tile kernels ----------------------------------
@@ -221,12 +217,12 @@ def test_dense_blocked_exact_vs_single_tile_small_V(shared_com):
 @pytest.mark.parametrize("shared", [True, False])
 def test_structured_blocked_exact_vs_single_tile_small_V(shared):
     rng = np.random.default_rng(1)
-    xi, xj, mass, a, corr = _structured_inputs(rng, B=2, E=5, V=64, R=4,
-                                               shared=shared)
+    xi, mass, a, w = _structured_inputs(rng, B=2, E=5, V=64, R=4,
+                                        shared=shared)
     blocked = np.asarray(edge_latency_structured_pallas(
-        xi, xj, mass, a, corr, interpret=True))
+        xi, mass, a, w, interpret=True))
     single = np.asarray(edge_latency_structured_pallas_single_tile(
-        xi, xj, mass, a, corr, interpret=True))
+        xi, mass, a, w, interpret=True))
     np.testing.assert_array_equal(blocked, single)
 
 
@@ -247,10 +243,49 @@ def test_dense_result_invariant_to_block_shape():
 
 def test_structured_result_invariant_to_block_shape():
     rng = np.random.default_rng(4)
-    xi, xj, mass, a, corr = _structured_inputs(rng, B=2, E=17, V=300, R=5,
-                                               shared=True)
+    xi, mass, a, w = _structured_inputs(rng, B=2, E=17, V=300, R=5,
+                                        shared=True)
     outs = [np.asarray(edge_latency_structured_pallas(
-        xi, xj, mass, a, corr, block_edges=be, block_v=bv, interpret=True))
+        xi, mass, a, w, block_edges=be, block_v=bv, interpret=True))
         for be, bv in [(8, 128), (16, 256), (64, 512)]]
     for other in outs[1:]:
         np.testing.assert_allclose(other, outs[0], rtol=1e-5, atol=1e-5)
+
+
+# -- region sums (the structured path's precompute) ---------------------------
+
+@pytest.mark.parametrize("M, V, R", [(1, 7, 1), (37, 300, 5), (300, 2500, 32)])
+def test_region_sum_matches_segment_sums(M, V, R):
+    """Rows, devices and regions off every block and tile multiple: each
+    row's region sums at float32 accuracy against float64."""
+    from repro.kernels.region_sum import region_sum_pallas
+
+    rng = np.random.default_rng(M + V)
+    v = rng.uniform(0.0, 4.0, (M, V)).astype(np.float32)
+    region = rng.integers(0, R, V)
+    onehot = (np.arange(R)[:, None] == region[None, :]).astype(np.float32)
+    got = np.asarray(region_sum_pallas(jnp.asarray(v), jnp.asarray(onehot),
+                                       interpret=True))
+    want = np.zeros((M, R))
+    np.add.at(want.T, region, v.astype(np.float64).T)
+    assert got.shape == (M, R)
+    np.testing.assert_allclose(got, want, rtol=REL, atol=1e-6)
+
+
+def test_region_sum_rows_independent_of_batch():
+    """A row's region sums are the same bits alone, in a small batch and
+    in a large one, at any offset: the served path's answers must equal
+    direct scoring bit for bit."""
+    from repro.kernels.region_sum import region_sum_pallas
+
+    rng = np.random.default_rng(11)
+    V, R = 2100, 6
+    v = jnp.asarray(rng.uniform(0.0, 1e4, (300, V)), jnp.float32)
+    region = rng.integers(0, R, V)
+    onehot = jnp.asarray((np.arange(R)[:, None] == region[None, :])
+                         .astype(np.float32))
+    full = np.asarray(region_sum_pallas(v, onehot, interpret=True))
+    for lo, hi in [(0, 1), (5, 21), (130, 300)]:
+        part = np.asarray(region_sum_pallas(v[lo:hi], onehot,
+                                            interpret=True))
+        np.testing.assert_array_equal(part, full[lo:hi])

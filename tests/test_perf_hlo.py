@@ -132,17 +132,16 @@ def test_structured_edge_latency_kernel_flops_pinned():
                                             edge_latency_structured_pallas)
 
     text = _kernel_hlo(
-        lambda xi, xj, m, a, c: edge_latency_structured_pallas(
-            xi, xj, m, a, c, interpret=True),
-        (_B, _E, _V), (_B, _E, _V), (_B, _E, _R), (1, _R, _V), (1, 1, _V))
+        lambda xi, m, a, w: edge_latency_structured_pallas(
+            xi, m, a, w, interpret=True),
+        (_B, _E, _V), (_B, _E, _R), (1, _R, _V), (_B, _E, _V))
     s = analyze_module(text)
     g = block_geometry("structured", _E, _V, _R, 128, 512)
     lo, hi = _flops_band(2 * _B * g.e_pad * g.r_pad * g.v_pad,
                          _B * g.e_pad * g.v_pad)
     assert lo <= s.flops <= hi
     io_floor = 4 * (2 * _B * g.e_pad * g.v_pad + _B * g.e_pad * g.r_pad
-                    + g.r_pad * g.v_pad + g.v_pad
-                    + _B * g.e_pad * g.out_lanes)
+                    + g.r_pad * g.v_pad + _B * g.e_pad * g.out_lanes)
     assert s.hbm_bytes >= io_floor
 
 

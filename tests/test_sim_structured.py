@@ -7,9 +7,12 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ModuleNotFoundError:  # container lacks hypothesis — use the shim
     from repro.testing.propcheck import given, settings, strategies as st
+
+    def example(**_):  # the shim replays no pinned examples
+        return lambda f: f
 
 from repro.core import (
     CostConfig,
@@ -51,9 +54,12 @@ def _random_region_fleets(rng, n_dev, n_fleets):
 
 @st.composite
 def instances(draw):
-    seed = draw(st.integers(0, 2**31 - 1))
-    alpha = draw(st.sampled_from([0.0, 0.25, 1.0]))
-    use_pallas = draw(st.sampled_from([False, True]))
+    return _instance(draw(st.integers(0, 2**31 - 1)),
+                     draw(st.sampled_from([0.0, 0.25, 1.0])),
+                     draw(st.sampled_from([False, True])))
+
+
+def _instance(seed, alpha, use_pallas):
     rng = np.random.default_rng(seed)
     n_ops = int(rng.integers(2, 8))
     n_dev = int(rng.integers(2, 9))
@@ -67,6 +73,9 @@ def instances(draw):
 
 @given(instances())
 @settings(**SETTINGS)
+# both ends of an edge on one device of a two-device region: the oracle
+# reads 0.0 where a self-pair added and subtracted again left 2.45e-06
+@example(inst=_instance(31834, 0.0, False))
 def test_structured_matches_oracle(inst):
     """score_grid / latency / edge_latencies over a RegionFleetFamily ==
     numpy oracle to ≤1e-5 relative, vmap AND Pallas routes, alpha 0/>0."""
@@ -137,19 +146,16 @@ def test_structured_kernel_against_ref():
     for B, E, V, R, Bc in [(1, 1, 2, 1, 1), (3, 7, 5, 2, 3),
                            (2, 130, 16, 4, 2), (4, 33, 12, 3, 1)]:
         xi = jnp.asarray(rng.random((B, E, V)), jnp.float32)
-        xj = jnp.asarray(rng.random((B, E, V)), jnp.float32)
         mass = jnp.asarray(rng.random((B, E, R)), jnp.float32)
         a = jnp.asarray(rng.random((Bc, R, V)), jnp.float32)
-        corr = jnp.asarray(rng.random((Bc, 1, V)), jnp.float32)
-        out = edge_latency_structured_max(xi, xj, mass, a, corr,
-                                          interpret=True)
+        w = jnp.asarray(rng.random((B, E, V)), jnp.float32)
+        out = edge_latency_structured_max(xi, mass, a, w, interpret=True)
         # one batched device→host transfer per shape, not one per operand
-        out_h, xi_h, xj_h, mass_h, a_h, corr_h = jax.device_get(
-            (out, xi, xj, mass, a, corr))
+        out_h, xi_h, mass_h, a_h, w_h = jax.device_get(
+            (out, xi, mass, a, w))
         t = np.einsum("ber,brv->bev", mass_h,
                       np.broadcast_to(a_h, (B, R, V)))
-        t = t + np.broadcast_to(corr_h, (B, 1, V)) * xj_h
-        want = (xi_h * t).max(axis=2)
+        want = (xi_h * (t + w_h)).max(axis=2)
         np.testing.assert_allclose(out_h, want, atol=1e-5, rtol=1e-5)
 
 

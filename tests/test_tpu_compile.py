@@ -101,8 +101,8 @@ def test_blocked_kernel_compiles(chip, smoke, kind, blocks):
         args = _shapes(sharding, (P, E, V), (P, E, V), (1, V, V))
     else:
         V, R = full.struct_devices, full.regions
-        args = _shapes(sharding, (P, E, V), (P, E, V), (P, E, R),
-                       (1, R, V), (1, 1, V))
+        args = _shapes(sharding, (P, E, V), (P, E, R), (1, R, V),
+                       (P, E, V))
     cfg = (autotune.get_config(kind, P, E, V, R, backend="tpu",
                                device_kind=device_kind)
            if blocks == "tuned" else autotune.DEFAULT_CONFIG)
