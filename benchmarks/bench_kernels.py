@@ -136,11 +136,9 @@ def _structured_races(rng, sweep, interpret: bool, backend: str):
                                     STRUCT_R, com_batch=1, backend=backend)
         xla_t = _time(lambda: xla(xi, mass, a, w))
         fixed_t = _time(lambda: edge_latency_structured_pallas(
-            xi, mass, a, w, block_edges=FIXED.block_edges,
-            block_v=FIXED.block_v, interpret=interpret))
+            xi, mass, a, w, block_v=FIXED.block_v, interpret=interpret))
         tuned_t = _time(lambda: edge_latency_structured_pallas(
-            xi, mass, a, w, block_edges=tuned.block_edges,
-            block_v=tuned.block_v, interpret=interpret))
+            xi, mass, a, w, block_v=tuned.block_v, interpret=interpret))
         races.append(_race_entry(
             "structured", V, STRUCT_E, STRUCT_B, STRUCT_R, xla_t, fixed_t,
             tuned_t, tuned, _rel_err(fixed_t.result, xla_t.result),

@@ -20,7 +20,11 @@ and then serves the same queries again warm.  It checks that
     the float64 oracle in ``repro.core.costmodel`` within ``ORACLE_RTOL``;
   * the edge kernels ran as compiled Pallas: ``kernels.dispatch.plans``
     with ``impl=pallas, interpret=False`` is positive, and nothing was
-    interpreted on the accelerator or coerced.
+    interpreted on the accelerator or coerced;
+  * the structured edge kernel, given per-operator rows and the edge
+    list, agrees with the XLA route given rows gathered to edges, on one
+    scenario's region terms at the deployment's V and R: bitwise, or
+    within ``KERNEL_GAP`` relative (it prints which).
 
 Run it from the root of a checkout:
 
@@ -45,11 +49,16 @@ if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import obs  # noqa: E402
 from repro.core.costmodel import latency, objective_F  # noqa: E402
+from repro.core.jaxmodel import (region_a_off, region_own,  # noqa: E402
+                                 region_terms)
 from repro.core.objectives import ObjectiveGrids, ObjectiveSet  # noqa: E402
+from repro.kernels import dispatch  # noqa: E402
+from repro.kernels.edge_latency import edge_list  # noqa: E402
 from repro.obs import jaxhooks  # noqa: E402
 from repro.search.decision import (joint_dq_scores, pareto_front,  # noqa: E402
                                    split_dq_term)
@@ -104,6 +113,11 @@ ADMISSION = AdmissionConfig(p99_budget_s=3600.0)
 ORACLE_PAIRS = 8
 # f32 scores against the float64 oracle: relative error, per pair
 ORACLE_RTOL = 1e-5
+
+
+# the structured kernel against the gathered XLA route: relative, as the
+# structured benchmark cell's ``gap`` limit
+KERNEL_GAP = 2e-06
 
 
 @dataclasses.dataclass
@@ -287,6 +301,42 @@ def check_oracle(graph, dep: Deployment, served: list[Served],
     return worst
 
 
+def check_structured_kernel(graph, dep: Deployment, seed: int,
+                            shape: Shape, rows: int = 8) -> dict:
+    """The structured edge kernel on per-operator rows with the graph's
+    edge list against the XLA route on the same rows gathered to edges
+    (``x[src]·sel``, ``mass[dst]``, ``w[dst]``), for ``rows`` placements
+    and the region terms of the family's first scenario.  Raises
+    AssertionError beyond ``KERNEL_GAP`` relative."""
+    fam = dep.pack
+    region_ix = jnp.asarray(np.asarray(fam.region, dtype=np.int64))
+    inter = jnp.asarray(fam.inter[0], jnp.float32)
+    degrade = jnp.asarray(fam.degrade[0], jnp.float32)
+    x = jnp.asarray(sparse_placements(np.random.default_rng([seed, 1]),
+                                      rows, graph.n_ops, dep.n_devices,
+                                      shape.devices_per_op))
+    mass, w = region_terms(x, degrade, region_own(inter, degrade, region_ix),
+                           region_ix, fam.n_regions, fam.self_cost)
+    a = region_a_off(inter, degrade, region_ix)[None]
+    src = np.array([i for i, _ in graph.edges])
+    dst = np.array([j for _, j in graph.edges])
+    edges = edge_list(src, dst, [graph.operators[i].selectivity
+                                 for i in src])
+    kernel = dispatch.edge_latency_structured(x, mass, a, w, edges,
+                                              use_pallas=True)
+    x_i = x[:, src] * jnp.asarray(edges[2], jnp.float32)[None, :, None]
+    xla = dispatch.edge_latency_structured(x_i, mass[:, dst], a, w[:, dst],
+                                           use_pallas=False)
+    got, want = (np.asarray(v, np.float64) for v in (kernel, xla))
+    rel = float(np.max(np.abs(got - want) / np.abs(want)))
+    if not rel <= KERNEL_GAP:
+        raise AssertionError(f"structured kernel vs gathered XLA route: "
+                             f"rel {rel:.3g} > {KERNEL_GAP}")
+    return {"kernel_bitwise": bool(np.array_equal(got, want)),
+            "kernel_max_rel": rel, "kernel_gap": KERNEL_GAP,
+            "devices": dep.n_devices, "regions": fam.n_regions}
+
+
 def dispatch_counts(reg) -> dict[str, float]:
     """Compiled-Pallas plans and the two counters that would mean the
     device was bypassed."""
@@ -360,6 +410,9 @@ def main(argv: list[str] | None = None) -> int:
                         max_chunk_rows=FULL.chunk_rows)
     for dep in deployments:
         run_phase(svc, graph, dep, args.seed, FULL)
+        if dep.name == "structured":
+            print("smoke kernel " + json.dumps(check_structured_kernel(
+                graph, dep, args.seed, FULL)))
 
     counts = dispatch_counts(obs.registry())
     print("smoke dispatch " + json.dumps(counts))

@@ -19,9 +19,9 @@ that price EXACTLY what the kernels run (both sides share
     ranking non-trivial.
 
 Decisions persist in a process-wide table keyed by
-``(backend, kind, V, E, R, B-bucket)`` — B buckets to powers of two, the
-same rule the serving layer uses, so one warm entry covers the whole
-bucket.  ``get_config`` consults the table first; a miss ranks candidates
+``(backend, kind, V, E, R, B-bucket, n_ops)`` — B buckets to powers of
+two, the same rule the serving layer uses, so one warm entry covers the
+whole bucket.  ``get_config`` consults the table first; a miss ranks candidates
 analytically and (optionally, when the caller supplies a ``timer`` — real
 accelerators only; interpret-mode timings rank Python overhead, not
 hardware) races the top candidates empirically.  The table round-trips to
@@ -44,7 +44,7 @@ import threading
 import jax
 
 from repro import obs
-from repro.kernels.edge_latency import block_geometry
+from repro.kernels.edge_latency import block_geometry, structured_row_block
 from repro.perf.roofline import peaks_for
 
 __all__ = ["KernelConfig", "ShapeKey", "DEFAULT_CONFIG", "VMEM_BUDGET_BYTES",
@@ -92,39 +92,55 @@ class ShapeKey:
     E: int
     R: int | None
     b_bucket: int
+    n_ops: int | None = None   # structured: operators whose rows it reads
 
     @classmethod
     def of(cls, backend: str, kind: str, B: int, E: int, V: int,
-           R: int | None) -> "ShapeKey":
+           R: int | None, n_ops: int | None = None) -> "ShapeKey":
         return cls(backend=backend, kind=kind, V=int(V), E=int(E),
                    R=None if R is None else int(R),
-                   b_bucket=1 << max(int(B) - 1, 0).bit_length())
+                   b_bucket=1 << max(int(B) - 1, 0).bit_length(),
+                   n_ops=_n_ops(kind, E, n_ops))
 
 
 _lock = threading.Lock()
 _table: dict[ShapeKey, tuple[KernelConfig, str]] = {}
 
 
+def _n_ops(kind: str, E: int, n_ops: int | None) -> int | None:
+    """The structured kernel's operator rows; by default one per edge
+    (rows already gathered to edges).  None for the dense kernel."""
+    if kind != "structured":
+        return None
+    return int(E if n_ops is None else n_ops)
+
+
 def vmem_bytes(kind: str, E: int, V: int, R: int | None,
-               config: KernelConfig) -> int:
+               config: KernelConfig, n_ops: int | None = None,
+               B: int = 1) -> int:
     """Per-grid-step VMEM footprint of the blocked kernel under ``config``:
     streamed input tiles double-buffered (the compiler overlaps the next
-    tile's DMA with compute), scratch and output single-buffered."""
+    tile's DMA with compute), scratch and output single-buffered.  The
+    structured kernel's tiles hold a row block of ``B`` rows (at most 8)
+    of every operator, ``n_ops`` of them (default: one per edge)."""
     g = block_geometry(kind, E, V, R, config.block_edges, config.block_v)
     if kind == "dense":
         inputs = g.be * g.bv + g.be * g.bv + g.bv * g.bv  # xi, xj, com
         scratch = g.be * g.bv                             # t accumulator
-    else:
-        # xi, mass, a, w
-        inputs = 2 * g.be * g.bv + g.be * g.r_pad + g.r_pad * g.bv
-        scratch = 0
-    return BYTES_F32 * (2 * inputs + scratch + 2 * g.be)
+        return BYTES_F32 * (2 * inputs + scratch + 2 * g.be)
+    rows = structured_row_block(B) * _n_ops(kind, E, n_ops)
+    # x, w, mass, a tiles; x and T parked by lane chunk
+    inputs = 2 * rows * g.bv + rows * g.r_pad + g.r_pad * g.bv
+    temps = 2 * rows * g.bv
+    out = E * structured_row_block(B) * g.out_lanes
+    return BYTES_F32 * (2 * inputs + temps + 2 * out)
 
 
 def predict_seconds(kind: str, B: int, E: int, V: int, R: int | None,
                     config: KernelConfig, com_batch: int = 1,
                     backend: str = "tpu",
-                    device_kind: str | None = None) -> float:
+                    device_kind: str | None = None,
+                    n_ops: int | None = None) -> float:
     """Analytic time estimate for one kernel launch: roofline terms over
     the PADDED shape (so over-padding from a too-coarse block is priced),
     with HBM traffic counting every tile revisit the index maps imply.
@@ -140,20 +156,25 @@ def predict_seconds(kind: str, B: int, E: int, V: int, R: int | None,
                    + com_batch * g.n_e * g.v_pad * g.v_pad  # com: per e blk
                    + B * g.e_pad)                   # output
     else:
-        steps = B * g.n_e * g.n_u
-        flops = 2.0 * B * g.e_pad * g.r_pad * g.v_pad \
-            + 3.0 * B * g.e_pad * g.v_pad
-        traffic = (2 * B * g.e_pad * g.v_pad        # xi, w: once per (e, u)
-                   + B * g.e_pad * g.r_pad * g.n_u  # mass: re-read per u blk
-                   + com_batch * g.r_pad * g.v_pad * g.n_e  # a: per e block
-                   + B * g.e_pad)
+        n = _n_ops(kind, E, n_ops)
+        rb = structured_row_block(B)
+        b_pad = -(-B // rb) * rb
+        steps = b_pad // rb * g.n_u
+        flops = (2.0 * b_pad * n * g.r_pad * g.v_pad  # T = mass @ a
+                 + 3.0 * b_pad * E * g.v_pad)         # x·s, ·T, max
+        traffic = (2 * b_pad * n * g.v_pad            # x, w: read once
+                   + b_pad * n * g.r_pad              # mass: once
+                   + (b_pad // rb if com_batch == 1 else B)
+                   * g.r_pad * g.v_pad                # a: per row block
+                   + E * b_pad * g.out_lanes)         # output
     overhead = STEP_OVERHEAD_S.get(backend, STEP_OVERHEAD_DEFAULT_S)
     return max(flops / peaks.flops, BYTES_F32 * traffic / peaks.hbm_bw) \
         + steps * overhead
 
 
-def candidate_configs(kind: str, E: int, V: int,
-                      R: int | None) -> list[KernelConfig]:
+def candidate_configs(kind: str, E: int, V: int, R: int | None,
+                      n_ops: int | None = None,
+                      B: int = 1) -> list[KernelConfig]:
     """VMEM-feasible (block_edges, block_v) pairs, deduplicated by the
     geometry they actually clamp to (a 512-wide block over V = 300 is the
     same kernel as a 384-wide one).  Raises ValueError when not even the
@@ -165,7 +186,7 @@ def candidate_configs(kind: str, E: int, V: int,
             g = block_geometry(kind, E, V, R, be, bv)
             if (g.be, g.bv) in seen:
                 continue
-            if vmem_bytes(kind, E, V, R, cfg) > VMEM_BUDGET_BYTES:
+            if vmem_bytes(kind, E, V, R, cfg, n_ops, B) > VMEM_BUDGET_BYTES:
                 continue
             seen.add((g.be, g.bv))
             out.append(cfg)
@@ -178,15 +199,17 @@ def candidate_configs(kind: str, E: int, V: int,
 
 def rank(kind: str, B: int, E: int, V: int, R: int | None = None,
          com_batch: int = 1, backend: str = "tpu",
-         device_kind: str | None = None) -> list[KernelConfig]:
+         device_kind: str | None = None,
+         n_ops: int | None = None) -> list[KernelConfig]:
     """Feasible candidates, best predicted first (deterministic: ties break
     toward the larger blocks, which also minimize grid-sequencing steps)."""
-    cands = candidate_configs(kind, E, V, R)
+    cands = candidate_configs(kind, E, V, R, n_ops, B)
     return sorted(
         cands,
         key=lambda c: (predict_seconds(kind, B, E, V, R, c,
                                        com_batch=com_batch, backend=backend,
-                                       device_kind=device_kind),
+                                       device_kind=device_kind,
+                                       n_ops=n_ops),
                        -c.block_v, -c.block_edges))
 
 
@@ -198,7 +221,8 @@ def local_device_kind(backend: str) -> str:
 
 def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
                com_batch: int = 1, backend: str | None = None,
-               device_kind: str | None = None, timer=None) -> KernelConfig:
+               device_kind: str | None = None, timer=None,
+               n_ops: int | None = None) -> KernelConfig:
     """The block config for one shape: decision-table hit, else analytic
     ranking (plus an empirical race over the top candidates when ``timer``
     — a ``callable(KernelConfig) -> seconds`` — is supplied), stored.
@@ -208,7 +232,7 @@ def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
     Safe to call at trace time: pure host work, deterministic per key."""
     if backend is None:
         backend = jax.default_backend()
-    key = ShapeKey.of(backend, kind, B, E, V, R)
+    key = ShapeKey.of(backend, kind, B, E, V, R, n_ops)
     with _lock:
         hit = _table.get(key)
     reg = obs.registry()
@@ -220,7 +244,8 @@ def get_config(kind: str, B: int, E: int, V: int, R: int | None = None,
     if backend != "cpu" and device_kind is None:
         device_kind = local_device_kind(backend)
     ranked = rank(kind, key.b_bucket, E, V, R, com_batch=com_batch,
-                  backend=backend, device_kind=device_kind)
+                  backend=backend, device_kind=device_kind,
+                  n_ops=key.n_ops)
     best, source = ranked[0], "analytic"
     if timer is not None:
         timed = [(timer(c), c) for c in ranked[:EMPIRICAL_TOP_K]]
@@ -246,7 +271,7 @@ def table_rows() -> list[dict]:
                        key=lambda kv: (kv[0].backend, kv[0].kind, kv[0].V,
                                        kv[0].E, kv[0].b_bucket))
     return [{"backend": k.backend, "kind": k.kind, "V": k.V, "E": k.E,
-             "R": k.R, "b_bucket": k.b_bucket,
+             "R": k.R, "b_bucket": k.b_bucket, "n_ops": k.n_ops,
              "block_edges": cfg.block_edges, "block_v": cfg.block_v,
              "source": source}
             for k, (cfg, source) in items]
@@ -273,7 +298,9 @@ def load_table(path) -> int:
             key = ShapeKey(backend=row["backend"], kind=row["kind"],
                            V=int(row["V"]), E=int(row["E"]),
                            R=None if row["R"] is None else int(row["R"]),
-                           b_bucket=int(row["b_bucket"]))
+                           b_bucket=int(row["b_bucket"]),
+                           n_ops=_n_ops(row["kind"], int(row["E"]),
+                                        row.get("n_ops")))
             if key in _table:
                 continue
             _table[key] = (KernelConfig(block_edges=int(row["block_edges"]),

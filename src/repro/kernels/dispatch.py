@@ -31,11 +31,13 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.kernels import autotune
-from repro.kernels.edge_latency import (edge_latency_pallas,
-                                        edge_latency_structured_pallas)
+from repro.kernels.edge_latency import (F32_DOT, edge_latency_pallas,
+                                        edge_latency_structured_pallas,
+                                        edge_list)
 
 __all__ = ["backend_name", "resolve_flags", "KernelPlan", "plan_edge_kernel",
            "edge_latency", "edge_latency_structured"]
@@ -91,7 +93,8 @@ def plan_edge_kernel(kind: str, B: int, E: int, V: int, R: int | None = None,
                      interpret: bool | None = None,
                      backend: str | None = None, com_batch: int = 1,
                      block_edges: int | None = None,
-                     block_v: int | None = None) -> KernelPlan:
+                     block_v: int | None = None,
+                     n_ops: int | None = None) -> KernelPlan:
     """Resolve flags and block shapes for one shape.  Caller-pinned blocks
     bypass the autotuner; otherwise the decision table supplies them,
     ranked with the peaks of this process's device kind."""
@@ -109,7 +112,7 @@ def plan_edge_kernel(kind: str, B: int, E: int, V: int, R: int | None = None,
         plan = KernelPlan(impl="pallas", interpret=interpret_r, config=cfg)
     else:
         cfg = autotune.get_config(kind, B, E, V, R, com_batch=com_batch,
-                                  backend=backend)
+                                  backend=backend, n_ops=n_ops)
         plan = KernelPlan(impl="pallas", interpret=interpret_r, config=cfg)
     reg = obs.registry()
     if reg.enabled:
@@ -126,11 +129,14 @@ def _edge_latency_xla(x_i, x_j, com):
     return jnp.max(x_i.astype(jnp.float32) * t, axis=-1)
 
 
-def _edge_latency_structured_xla(x_i, mass, a, w):
-    t = jnp.einsum("ber,bru->beu", mass.astype(jnp.float32),
-                   a.astype(jnp.float32))
-    return jnp.max(x_i.astype(jnp.float32) * (t + w.astype(jnp.float32)),
-                   axis=-1)
+def _edge_latency_structured_xla(x, mass, a, w, edges):
+    src, dst, sel = (np.asarray(e) for e in edges)
+    t = jnp.einsum("bnr,bru->bnu", mass.astype(jnp.float32),
+                   a.astype(jnp.float32), precision=F32_DOT) \
+        + w.astype(jnp.float32)
+    x_i = x.astype(jnp.float32)[:, src] * jnp.asarray(
+        sel, jnp.float32)[None, :, None]
+    return jnp.max(x_i * t[:, dst], axis=-1)
 
 
 def edge_latency(x_i, x_j, com, *, use_pallas: bool | None = None,
@@ -154,23 +160,28 @@ def edge_latency(x_i, x_j, com, *, use_pallas: bool | None = None,
                                interpret=plan.interpret)
 
 
-def edge_latency_structured(x_i, mass, a, w, *,
+def edge_latency_structured(x, mass, a, w, edges=None, *,
                             use_pallas: bool | None = None,
                             interpret: bool | None = None,
                             backend: str | None = None,
-                            block_edges: int | None = None,
                             block_v: int | None = None):
     """Structured (RegionFleet) edge-latency max through the dispatch
-    policy: t = mass @ a + w with R ≪ V (see kernels/edge_latency)."""
-    B, E, V = x_i.shape
+    policy: per-operator rows x, w (B, n_ops, V) and masses (B, n_ops, R),
+    ``a`` (B|1, R, V), and the graph's static :func:`edge_list` → (B, E)
+    ``max_u (x[src]·sel)·(mass @ a + w)[dst]`` (see kernels/edge_latency);
+    ``edges=None`` is one edge per row, ``src = dst``, ``sel = 1``."""
+    B, n_ops, V = x.shape
+    if edges is None:
+        edges = edge_list(range(n_ops), range(n_ops), [1.0] * n_ops)
+    E = len(edges[0])
     if E == 0:
         return jnp.zeros((B, 0), jnp.float32)
     plan = plan_edge_kernel("structured", B, E, V, mass.shape[-1],
                             use_pallas=use_pallas, interpret=interpret,
                             backend=backend, com_batch=a.shape[0],
-                            block_edges=block_edges, block_v=block_v)
+                            block_v=block_v, n_ops=n_ops)
     if plan.impl == "xla":
-        return _edge_latency_structured_xla(x_i, mass, a, w)
+        return _edge_latency_structured_xla(x, mass, a, w, edges)
     return edge_latency_structured_pallas(
-        x_i, mass, a, w, block_edges=plan.config.block_edges,
-        block_v=plan.config.block_v, interpret=plan.interpret)
+        x, mass, a, w, edges, block_v=plan.config.block_v,
+        interpret=plan.interpret)

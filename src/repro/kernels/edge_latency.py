@@ -6,11 +6,12 @@ The paper's edge latency (§3) is, per edge ``i→j`` with placement rows
     edgeLat = max_u  x_{i,u} · s_i · Σ_v com_{u,v} · x_{j,v}
 
 The batched what-if evaluator (repro.sim.batched) scores (scenario ×
-placement) grids, so the reduction runs over a (B, E, V) tensor of gathered
-edge endpoint rows against a (B, V, V) tensor of per-scenario com matrices —
-a fused matvec + row-max that dominates evaluation time once B·E·V² grows.
-Selectivity is folded into ``x_i`` by the caller, keeping the kernels pure
-bilinear-maxes.
+placement) grids.  The DENSE reduction runs over a (B, E, V) tensor of
+gathered edge endpoint rows against a (B, V, V) tensor of per-scenario com
+matrices — a fused matvec + row-max that dominates evaluation time once
+B·E·V² grows; selectivity is folded into ``x_i`` by the caller.  The
+STRUCTURED reduction takes per-operator rows and the graph's static edge
+list, and gathers rows to edges itself.
 
 Compiled-ready blocking scheme (see kernels/README.md for the full story):
 
@@ -23,14 +24,18 @@ Compiled-ready blocking scheme (see kernels/README.md for the full story):
     u axis folds per-block row maxima into the output with a running max —
     so the (E, V) endpoint rows and the (V, V) com matrix stream through
     VMEM in (be, bv) / (bv, bv) tiles instead of requiring residency;
-  * the STRUCTURED kernel (RegionFleetFamily: ``t = mass @ A + w`` with
-    R ≪ V) runs a (B, E/be, V/bv) grid, V-blocking its (be, R)@(R, bv)
-    product and the own-region term ``w`` with the same running max over
-    u-tiles;
-  * both kernels write a lane-dense (B, e_pad, LANE) output in (1, be, LANE)
-    blocks, every lane holding the edge's running max: Mosaic requires an
-    output block's last two dims to be (8, 128)-aligned or whole, which a
-    (1, be) block over a (B, e_pad) array is not.  The wrapper keeps lane 0.
+  * the STRUCTURED kernel (RegionFleetFamily: ``T = mass @ A + w`` per
+    operator, R ≪ V) runs a (B/rb, V/bv) grid over (rb·n_ops, bv) tiles
+    of the rows ``x`` and ``w`` of rb ≤ 8 placement rows: each step forms
+    T for every (row, operator), reads each edge's two operators for all
+    rb rows by sublane-strided loads, and folds the row max over u-tiles,
+    so no per-edge copy of a V-sized row exists;
+  * both kernels write lane-dense outputs: Mosaic requires an output
+    block's last two dims to be (8, 128)-aligned or whole, which a
+    (1, be) block over a (B, e_pad) array is not.  The dense kernel's
+    (B, e_pad, LANE) holds the edge's running max in every lane, and the
+    wrapper keeps lane 0; the structured kernel's (E, B, LANE) holds a
+    running max per lane, and the wrapper takes the max over lanes.
 
 Block shapes come from :mod:`repro.kernels.autotune` via the dispatch layer
 (:mod:`repro.kernels.dispatch`); the single-tile kernels the blocked ones
@@ -45,10 +50,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["LANE", "SUBLANE", "BlockGeometry", "block_geometry",
+           "edge_list", "structured_row_block",
            "edge_latency_pallas", "edge_latency_structured_pallas",
            "edge_latency_pallas_single_tile",
            "edge_latency_structured_pallas_single_tile"]
@@ -73,8 +80,10 @@ class BlockGeometry:
     models price exactly it, so the model can never drift from the kernel.
     """
 
-    be: int           # edge-block rows (≤ padded E, multiple of SUBLANE)
-    bv: int           # V-block width (≤ padded V, multiple of LANE)
+    be: int           # edge-block rows (dense: ≤ padded E, multiple of
+                      # SUBLANE; structured: E)
+    bv: int           # V-block width (≤ padded V, multiple of LANE;
+                      # structured: of SUBLANE·LANE, or all of V)
     e_pad: int        # E padded to a multiple of be
     v_pad: int        # V padded to a multiple of bv
     r_pad: int | None  # R padded to a multiple of LANE (structured only)
@@ -89,16 +98,23 @@ def block_geometry(kind: str, E: int, V: int, R: int | None,
     """Clamp a requested (block_edges, block_v) to a legal geometry for the
     shape: blocks are rounded to hardware tile multiples, then the axes pad
     up to block multiples (never the other way round — a requested block
-    larger than the padded axis shrinks to it)."""
+    larger than the padded axis shrinks to it).  ``block_edges`` shapes
+    the dense kernel only: the structured one holds every edge in one
+    block and pads no edge axis."""
     if kind not in ("dense", "structured"):
         raise ValueError(f"kind must be dense|structured, got {kind!r}")
     if E < 1 or V < 1:
         raise ValueError(f"need E >= 1 and V >= 1, got E={E}, V={V}")
-    bv = _round_up(max(1, block_v), LANE)
-    bv = min(bv, _round_up(V, LANE))
+    # the structured kernel views a V tile as (bv/LANE, LANE): a whole
+    # number of sublane tiles, or all of V
+    v_tile = LANE if kind == "dense" else SUBLANE * LANE
+    bv = min(_round_up(max(1, block_v), v_tile), _round_up(V, LANE))
     v_pad = _round_up(V, bv)
-    be = _round_up(max(1, block_edges), SUBLANE)
-    be = min(be, _round_up(E, SUBLANE))
+    if kind == "dense":
+        be = min(_round_up(max(1, block_edges), SUBLANE),
+                 _round_up(E, SUBLANE))
+    else:  # every edge in one unpadded block, applied in VMEM
+        be = E
     e_pad = _round_up(E, be)
     n_v = v_pad // bv if kind == "dense" else 1
     r_pad = None
@@ -221,90 +237,162 @@ def edge_latency_pallas(x_i, x_j, com, block_edges: int = 128,
 # factors the per-edge matvec through region space
 # (``repro.core.jaxmodel.region_a_off`` / ``region_terms``):
 #
-#   t[e, u] = Σ_r A[r, u] · mass[e, r]  +  w[e, u]
+#   T[o, u] = Σ_r A[r, u] · mass[o, r]  +  w[o, u]
 #   A[r, u] = degrade_u · inter[region_u, r], 0 in u's own region (R, V)
-#   mass[e, r] = Σ_{v ∈ region r} degrade_v · x_j[e, v]          (E, R)
-#   w[e, u] = u's own-region transfer over the region's other devices,
-#             plus its transfer to itself                          (E, V)
+#   mass[o, r] = Σ_{v ∈ region r} degrade_v · x[o, v]              (n_ops, R)
+#   w[o, u] = u's own-region transfer over the region's other devices,
+#             plus its transfer to itself                          (n_ops, V)
 #
-# so the kernel's inner product is (be, R) @ (R, bv) — R ≪ V — and the only
-# V-sized operands are two (E, V) rows, as many as the dense kernel
-# streams.  The caller precomputes ``mass``/``A``/``w`` (XLA gathers and
-# segment sums, no V² anywhere); here the u axis is V-blocked with the
-# same running max as the dense kernel, so A/w/x tiles stream through
-# VMEM in (R, bv)/(be, bv) slices and V = 131 072 fleets never need a
-# V-resident row.  R pads to the lane width (zero rows of mass/A add
-# exact zeros to the product).
+# per destination OPERATOR o, and edge i→j scores max_u (x[i]·s)·T[j].
+# The kernel takes the per-operator rows and the DAG's static edge list and
+# applies the edges itself, so no per-edge (E, V) copy is ever written.
+#
+# grid = (B/rb, V/bv) over blocks of rb ≤ 8 placement rows.  The rows of a
+# block arrive as (rb·n_ops, bv) tiles in (row, operator) order.  Each step
+# parks x and T = mass @ a + w for every (row, operator) in VMEM by lane
+# chunk — one (rb·n_ops, Rp) @ (Rp, LANE) product a chunk, R ≪ V — and
+# then, per edge and lane chunk, reads operator i's x and operator j's T
+# for all rb rows with one sublane-strided load each: an (rb, LANE) tile,
+# every sublane a placement row, where a (1, bv) row slice would fill one
+# sublane of eight.  The running max over u-tiles folds into a lane-dense
+# (E, rb, LANE) output block; the wrapper takes the max over lanes.  R
+# pads to the lane width (zero rows of mass/A add exact zeros); a shared
+# (Bc = 1) A tile is read once per row block.
 
 
-def _check_structured(x_i, a, w):
-    B = x_i.shape[0]
+def edge_list(src, dst, sel) -> tuple:
+    """A graph's static edge list as the structured kernels take it:
+    ``(src, dst, sel)`` tuples of operator indices and float32
+    selectivities (hashable, so it can be a static jit argument)."""
+    return (tuple(int(i) for i in src), tuple(int(j) for j in dst),
+            tuple(float(s) for s in np.asarray(sel, np.float32)))
+
+
+def structured_row_block(B: int) -> int:
+    """rb, the placement rows of one structured row block: one sublane
+    tile of rows, or all B when fewer.  B pads up to a multiple of it."""
+    return min(B, SUBLANE)
+
+
+def _check_structured(x, mass, a, w, edges):
+    B, n = x.shape[:2]
     if a.shape[0] not in (1, B):
         raise ValueError(f"scenario batch dim {a.shape[0]} must be 1 or {B}")
-    if w.shape != x_i.shape:
-        raise ValueError(f"w has shape {w.shape}, want {x_i.shape}")
+    if w.shape != x.shape:
+        raise ValueError(f"w has shape {w.shape}, want {x.shape}")
+    if mass.shape[:2] != (B, n):
+        raise ValueError(f"mass has shape {mass.shape}, want ({B}, {n}, R)")
+    src, dst, sel = edges
+    if not len(src) == len(dst) == len(sel):
+        raise ValueError("edge list: src, dst and sel differ in length")
+    if any(not 0 <= o < n for o in src + dst):
+        raise ValueError(f"edge list names an operator outside 0..{n - 1}")
 
 
-def _edge_latency_structured_blocked_kernel(v_real: int, xi_ref, mass_ref,
-                                            a_ref, w_ref, o_ref):
-    u = pl.program_id(2)
-    xi = xi_ref[0].astype(jnp.float32)      # (be, bv) — pre-scaled by s_i
-    mass = mass_ref[0].astype(jnp.float32)  # (be, Rp)
-    a = a_ref[0].astype(jnp.float32)        # (Rp, bv)
-    w = w_ref[0].astype(jnp.float32)        # (be, bv)
-    t = jax.lax.dot_general(mass, a, (((1,), (0,)), ((), ())),
-                            precision=F32_DOT,
-                            preferred_element_type=jnp.float32)
-    u_ix = u * xi.shape[1] + jax.lax.broadcasted_iota(jnp.int32, xi.shape, 1)
-    _fold_row_max(u, o_ref,
-                  jnp.where(u_ix < v_real, xi * (t + w), -jnp.inf))
+def _edge_latency_structured_blocked_kernel(v_real: int, n_ops: int, edges,
+                                            x_ref, mass_ref, a_ref, w_ref,
+                                            o_ref, x_k, t_k):
+    u = pl.program_id(1)
+    n_k, rb = t_k.shape[0], o_ref.shape[1]
+    mass = mass_ref[...].astype(jnp.float32)   # (rb·n_ops, Rp)
+    # x and T = mass @ a + w by lane chunk: a strided load needs a
+    # LANE-wide ref
+    for k in range(n_k):
+        lanes = slice(k * LANE, (k + 1) * LANE)
+        x_k[k] = x_ref[:, lanes].astype(jnp.float32)
+        t_k[k] = jax.lax.dot_general(
+            mass, a_ref[:, lanes].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), precision=F32_DOT,
+            preferred_element_type=jnp.float32) \
+            + w_ref[:, lanes].astype(jnp.float32)
+    masked = v_real % (n_k * LANE) != 0
+
+    @pl.when(u == 0)
+    def _init():
+        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, jnp.float32)
+
+    def chunk(k, carry):
+        if masked:
+            lane = (u * n_k + k) * LANE + jax.lax.broadcasted_iota(
+                jnp.int32, o_ref.shape[1:], 1)
+        for e, (i, j, s) in enumerate(zip(*edges)):
+            # x·s first, then x_i·(t + w): the gathered route's order
+            x_i = x_k[k, pl.ds(i, rb, stride=n_ops), :] * s
+            vals = x_i * t_k[k, pl.ds(j, rb, stride=n_ops), :]
+            if masked:
+                vals = jnp.where(lane < v_real, vals, -jnp.inf)
+            o_ref[e] = jnp.maximum(o_ref[e], vals)
+        return carry
+
+    # rolled over the lane chunks: the edges' unrolled body is traced and
+    # lowered once per program, not once per chunk
+    jax.lax.fori_loop(0, n_k, chunk, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_edges", "block_v", "interpret"))
-def edge_latency_structured_pallas(x_i, mass, a, w,
-                                   block_edges: int = 128,
-                                   block_v: int = 512,
-                                   interpret: bool = False):
-    """x_i, w: (B, E, V); mass: (B, E, R); a: (Bc, R, V) with Bc ∈ {1, B}
-    → (B, E) latencies ``max_u x_i·(mass @ a + w)``.
-
-    V-blocked over the u axis with a running max (module docstring); R pads
-    to the lane width with exact-zero rows.  A singleton scenario batch
-    (Bc == 1) is shared across all B placement rows via the index map,
-    mirroring the dense kernel's shared-com path."""
-    B, E, V = x_i.shape
+def _structured_shared(x, mass, a, w, edges, block_v, interpret):
+    """The kernel for one scenario shared by all B rows: a (1, R, V)."""
+    B, n_ops, V = x.shape
     R = mass.shape[-1]
-    if E == 0:
-        return jnp.zeros((B, 0), jnp.float32)
-    _check_structured(x_i, a, w)
-    shared = a.shape[0] == 1
-    g = block_geometry("structured", E, V, R, block_edges, block_v)
-    x_i = _pad_axis(_pad_axis(x_i, 2, g.v_pad), 1, g.e_pad)
-    w = _pad_axis(_pad_axis(w, 2, g.v_pad), 1, g.e_pad)
-    mass = _pad_axis(_pad_axis(mass, 2, g.r_pad), 1, g.e_pad)
-    a = _pad_axis(_pad_axis(a, 2, g.v_pad), 1, g.r_pad)
-    scen_ix = (lambda b, e, u: (0, 0, u)) if shared \
-        else (lambda b, e, u: (b, 0, u))
-    rows = pl.BlockSpec((1, g.be, g.bv), lambda b, e, u: (b, e, u))
+    E = len(edges[0])
+    g = block_geometry("structured", E, V, R, E, block_v)
+    rb = structured_row_block(B)
+    b_pad = -(-B // rb) * rb
+    x = _pad_axis(_pad_axis(x, 2, g.v_pad), 0, b_pad)
+    w = _pad_axis(_pad_axis(w, 2, g.v_pad), 0, b_pad)
+    mass = _pad_axis(_pad_axis(mass, 2, g.r_pad), 0, b_pad)
+    a = _pad_axis(_pad_axis(a[0], 1, g.v_pad), 0, g.r_pad)
+    rows = rb * n_ops
+    tile = pl.BlockSpec((rows, g.bv), lambda i, u: (i, u))
+    chunks = pltpu.VMEM((g.bv // LANE, rows, LANE), jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_edge_latency_structured_blocked_kernel, V),
-        grid=(B, g.n_e, g.n_u),
+        functools.partial(_edge_latency_structured_blocked_kernel, V, n_ops,
+                          edges),
+        grid=(b_pad // rb, g.n_u),
         in_specs=[
-            rows,
-            pl.BlockSpec((1, g.be, g.r_pad), lambda b, e, u: (b, e, 0)),
-            pl.BlockSpec((1, g.r_pad, g.bv), scen_ix),
-            rows,
+            tile,
+            pl.BlockSpec((rows, g.r_pad), lambda i, u: (i, 0)),
+            pl.BlockSpec((g.r_pad, g.bv), lambda i, u: (0, u)),
+            tile,
         ],
-        out_specs=pl.BlockSpec((1, g.be, g.out_lanes),
-                               lambda b, e, u: (b, e, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, g.e_pad, g.out_lanes),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((E, rb, LANE), lambda i, u: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((E, b_pad, LANE), jnp.float32),
+        scratch_shapes=[chunks, chunks],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x_i, mass, a, w)
-    return out[:, :E, 0]
+    )(x.reshape(b_pad * n_ops, g.v_pad), mass.reshape(b_pad * n_ops, g.r_pad),
+      a, w.reshape(b_pad * n_ops, g.v_pad))
+    return jnp.max(out[:, :B], axis=-1).T
+
+
+@functools.partial(jax.jit, static_argnames=("edges", "block_v",
+                                             "interpret"))
+def edge_latency_structured_pallas(x, mass, a, w, edges=None,
+                                   block_v: int = 2048,
+                                   interpret: bool = False):
+    """x, w: (B, n_ops, V); mass: (B, n_ops, R); a: (Bc, R, V) with
+    Bc ∈ {1, B}; ``edges`` the static :func:`edge_list` ``(src, dst, sel)``
+    → (B, E) latencies ``max_u (x[src]·sel)·(mass @ a + w)[dst]``.
+
+    ``edges=None`` is the per-edge special case ``src = dst = range(E)``,
+    ``sel = 1``: rows already gathered to edges (multiplying by 1.0 is
+    exact).  V-blocked over the u axis with a running max (module
+    docstring); R pads to the lane width with exact-zero rows.  A
+    singleton scenario batch (Bc == 1) is shared by all B placement rows
+    via the index map; a per-row one (Bc == B) maps the kernel over rows."""
+    B, n_ops, V = x.shape
+    if edges is None:
+        edges = edge_list(range(n_ops), range(n_ops), [1.0] * n_ops)
+    if len(edges[0]) == 0:
+        return jnp.zeros((B, 0), jnp.float32)
+    _check_structured(x, mass, a, w, edges)
+    if a.shape[0] == 1:
+        return _structured_shared(x, mass, a, w, edges, block_v, interpret)
+    return jax.lax.map(
+        lambda r: _structured_shared(r[0][None], r[1][None], r[2][None],
+                                     r[3][None], edges, block_v,
+                                     interpret)[0],
+        (x, mass, a, w))
 
 
 # -- single-tile parity references --------------------------------------------
@@ -379,7 +467,8 @@ def edge_latency_structured_pallas_single_tile(x_i, mass, a, w,
     R = mass.shape[-1]
     if E == 0:
         return jnp.zeros((B, 0), jnp.float32)
-    _check_structured(x_i, a, w)
+    _check_structured(x_i, mass, a, w,
+                      edge_list(range(E), range(E), [1.0] * E))
     shared = a.shape[0] == 1
     be = min(block_edges, E)
     e_pad = _round_up(E, be)

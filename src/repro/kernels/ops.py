@@ -55,14 +55,13 @@ def edge_latency_max(x_i, x_j, com, interpret: bool | None = None,
 
 def edge_latency_structured_max(x_i, mass, a, w,
                                 interpret: bool | None = None,
-                                block_edges: int | None = None,
                                 block_v: int | None = None):
-    """(B, E) structured edge-latency max over precomputed region masses —
-    the RegionFleetFamily hot path (kernels/edge_latency.py), dispatched
-    like :func:`edge_latency_max`."""
+    """(B, E) structured edge-latency max over precomputed region masses,
+    one edge per row — the RegionFleetFamily hot path
+    (kernels/edge_latency.py), dispatched like :func:`edge_latency_max`."""
     return dispatch.edge_latency_structured(
         x_i, mass, a, w, use_pallas=True, interpret=interpret,
-        block_edges=block_edges, block_v=block_v)
+        block_v=block_v)
 
 
 def _largest_divisor_block(n: int, target: int) -> int:

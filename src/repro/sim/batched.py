@@ -59,6 +59,7 @@ from repro.core.jaxmodel import (SmoothConfig, _edge_arrays,
                                  make_edge_latencies_region_fn)
 from repro.core.objectives import (ObjectiveGrids, ObjectiveSet,
                                    as_objective_set)
+from repro.kernels.edge_latency import block_geometry, edge_list
 
 __all__ = ["BatchedEvaluator", "SparsePlacements", "pack_fleets",
            "pack_placements", "pack_region_fleets", "pack_speeds",
@@ -267,6 +268,7 @@ class BatchedEvaluator:
         self._src = jnp.asarray(src)
         self._dst = jnp.asarray(dst)
         self._sel = jnp.asarray(sel, dtype=jnp.float32)
+        self._edges = edge_list(src, dst, sel)
         if self.cfg.include_compute:
             raise NotImplementedError(
                 "batched evaluator covers the paper-faithful model "
@@ -398,14 +400,16 @@ class BatchedEvaluator:
                     return jax.vmap(elat_single, in_axes=(0, None, None))(
                         x, inter[0], degrade[0])           # (B, E)
                 return jax.vmap(elat_single)(x, inter, degrade)
-            # Pallas route: the region terms per destination operator, by
-            # the same jaxmodel.region_terms as the vmap route, on the MXU:
-            # the region sums in the Pallas region-sum kernel (a row's bits
+            # Pallas route: the region terms per operator, by the same
+            # jaxmodel.region_terms as the vmap route, on the MXU: the
+            # region sums in the Pallas region-sum kernel (a row's bits
             # do not depend on its batch; a scatter-add over V costs about
             # as much whatever its rows), the per-device gathers as matmuls
             # with the layout's one-hot, exact at HIGHEST (one product per
             # output, by a one) and faster than the gather on a v5e; the
-            # edge kernel fuses t = mass @ a_off + w with the row max
+            # edge kernel takes the per-operator rows with the edge list
+            # and fuses t = mass @ a_off + w, the gather to edges and the
+            # row max, so no (B, E, V) copy is written
             from repro.kernels.region_sum import region_sum_pallas
             with jax.named_scope("region.terms"):
                 onehot = (jnp.arange(n_regions)[:, None]
@@ -423,8 +427,7 @@ class BatchedEvaluator:
                     mass, w = region_terms(x1, d, region_own(i, d, region_ix),
                                            region_ix, n_regions, self_cost,
                                            segment_sum, per_device)
-                    return (region_a_off(i, d, region_ix), mass[:, self._dst],
-                            w[:, self._dst])
+                    return region_a_off(i, d, region_ix), mass, w
 
                 if inter.shape[0] == 1:
                     a, mass, w = terms(x, inter[0], degrade[0])
@@ -433,12 +436,11 @@ class BatchedEvaluator:
                     a, mass, w = jax.vmap(
                         lambda x1, i, d: terms(x1[None], i, d))(
                         x, inter, degrade)
-                    mass, w = mass[:, 0], w[:, 0]       # (B, E, ·)
-            x_i = x[:, self._src] * self._sel[None, :, None]
+                    mass, w = mass[:, 0], w[:, 0]       # (B, n, ·)
             from repro.kernels.dispatch import edge_latency_structured
             out = edge_latency_structured(
-                x_i.astype(jnp.float32), mass.astype(jnp.float32),
-                a.astype(jnp.float32), w.astype(jnp.float32),
+                x.astype(jnp.float32), mass.astype(jnp.float32),
+                a.astype(jnp.float32), w.astype(jnp.float32), self._edges,
                 use_pallas=True, interpret=self.interpret)
             return out + self._links_term(x, out.dtype)
 
@@ -635,8 +637,9 @@ class BatchedEvaluator:
         """
         structured = isinstance(coms, RegionFleetFamily)
         S = coms.n_scenarios if structured else int(np.shape(coms)[0])
-        P = int(placements.shape[0] if isinstance(placements, SparsePlacements)
-                else np.shape(placements)[0])
+        shape = (placements.shape if isinstance(placements, SparsePlacements)
+                 else np.shape(placements))
+        P, V = int(shape[0]), int(shape[-1])
         path = "structured" if structured else "dense"
         multi = objectives is not None
         reg = obs.registry()
@@ -646,6 +649,8 @@ class BatchedEvaluator:
         regions = {"R": coms.n_regions} if structured else {}
         with obs.span("score_grid", S=S, P=P, path=path, multi=multi,
                       **regions) as sp:
+            if reg.enabled and self.use_pallas:
+                sp.set(kernel_rows=self._kernel_rows(structured, P, V))
             placements, pack, dq_arr, beta = self._upload(
                 placements, coms, dq, beta, S, P)
             san = sanitize.state()
@@ -665,6 +670,20 @@ class BatchedEvaluator:
                 "score_grid",
                 out.scalarized if isinstance(out, ObjectiveGrids) else out)
         return out
+
+    def _kernel_rows(self, structured: bool, P: int, V: int) -> int:
+        """The V-sized rows per placement row that the edge kernel reads:
+        x and w per operator on the structured route, the two padded
+        per-edge endpoint rows on the dense one."""
+        if structured:
+            return 2 * self.graph.n_ops
+        E = self.graph.n_edges
+        if not E:
+            return 0
+        from repro.kernels import autotune
+        cfg = autotune.get_config("dense", P, E, V)
+        return 2 * block_geometry("dense", E, V, None, cfg.block_edges,
+                                  cfg.block_v).e_pad
 
     def _upload(self, placements, coms, dq, beta, S: int, P: int):
         """The grid's operands as device arrays: placements (rebuilt dense

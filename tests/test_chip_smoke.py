@@ -63,6 +63,17 @@ def test_phase_parity_and_oracle_on_cpu(deployment, metrics):
     assert counts["interpret_on_accelerator"] == counts["coerced"] == 0
 
 
+def test_structured_kernel_check_on_cpu():
+    """The per-operator structured kernel (interpreted here) against the
+    gathered XLA route, at the tiny deployment's V and R: within the gap
+    (the two contract R in different orders, so bits may differ)."""
+    graph, deps = cs.build_deployments(5, TINY)
+    dep = next(d for d in deps if d.name == "structured")
+    got = cs.check_structured_kernel(graph, dep, 5, TINY)
+    assert 0.0 <= got["kernel_max_rel"] <= cs.KERNEL_GAP
+    assert (got["devices"], got["regions"]) == (96, 4)
+
+
 def test_oracle_check_catches_a_wrong_score():
     """A served score off by more than ORACLE_RTOL fails the oracle check."""
     graph, deps = cs.build_deployments(4, TINY)

@@ -162,8 +162,7 @@ def test_structured_oracle_parity_odd_R(R, shared):
     rng = np.random.default_rng(R)
     xi, mass, a, w = _structured_inputs(rng, B=2, E=5, V=300, R=R,
                                         shared=shared)
-    got = edge_latency_structured_pallas(xi, mass, a, w,
-                                         block_edges=16, block_v=128,
+    got = edge_latency_structured_pallas(xi, mass, a, w, block_v=128,
                                          interpret=True)
     assert _rel_err(got, _structured_oracle(xi, mass, a, w)) <= REL
 
@@ -197,6 +196,60 @@ def test_structured_rejects_mismatched_scenario_batch(a_batch, w_shape):
     w = jnp.zeros(w_shape, jnp.float32)
     with pytest.raises(ValueError):
         edge_latency_structured_pallas(xi, mass, a, w, interpret=True)
+
+
+# -- per-operator rows with the edge list vs rows gathered to edges ----------
+
+# (n_ops, src, dst): more edges than operators, fewer, and an operator
+# (4) that no edge touches
+_DAGS = {
+    "more_edges": (4, (0, 0, 1, 2, 1, 0), (1, 2, 3, 3, 2, 3)),
+    "fewer_edges": (6, (0, 2, 4), (1, 3, 5)),
+    "idle_operator": (5, (0, 1, 2, 0), (1, 2, 3, 3)),
+}
+
+
+@pytest.mark.parametrize("route", ["pallas", "xla"])
+@pytest.mark.parametrize("shared", [True, False])
+@pytest.mark.parametrize("dag", sorted(_DAGS))
+def test_structured_edge_list_matches_gathered_route(dag, shared, route):
+    """Per-operator rows, masses and ``w`` with the static edge list give
+    the bits the same route gives on rows gathered to edges
+    (``x[src]·sel``, ``mass[dst]``, ``w[dst]``): selectivities off 1, V
+    not a multiple of the lane width (padded u-columns masked), and row 0
+    with an outage-sized device term that sets its max."""
+    from repro.kernels.dispatch import edge_latency_structured
+    from repro.kernels.edge_latency import edge_list
+
+    n, src, dst = _DAGS[dag]
+    B, V, R = 3, 300, 5
+    rng = np.random.default_rng(len(src) * 10 + n)
+    sel = rng.uniform(0.3, 2.0, len(src))
+    x = rng.uniform(0.0, 1.0, (B, n, V)).astype(np.float32)
+    w = rng.uniform(0.0, 1.0, (B, n, V)).astype(np.float32)
+    w[0, :, 17] *= 1e4
+    mass = jnp.asarray(rng.uniform(0.0, 1.0, (B, n, R)), jnp.float32)
+    a = jnp.asarray(rng.uniform(0.0, 1.0, (1 if shared else B, R, V)),
+                    jnp.float32)
+    x, w = jnp.asarray(x), jnp.asarray(w)
+    edges = edge_list(src, dst, sel)
+    s, d = np.array(src), np.array(dst)
+    x_i = x[:, s] * jnp.asarray(edges[2], jnp.float32)[None, :, None]
+    if route == "pallas":
+        got = edge_latency_structured_pallas(x, mass, a, w, edges,
+                                             block_v=128, interpret=True)
+        want = edge_latency_structured_pallas(x_i, mass[:, d], a, w[:, d],
+                                              block_v=128, interpret=True)
+    else:
+        got = edge_latency_structured(x, mass, a, w, edges, use_pallas=False)
+        want = edge_latency_structured(x_i, mass[:, d], a, w[:, d],
+                                       use_pallas=False)
+    assert got.shape == (B, len(src))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(np.argmax(np.asarray(x_i[0, 0]) * np.asarray(w[0, d[0]]))) \
+        == 17
+    assert _rel_err(got, _structured_oracle(x_i, mass[:, d], a,
+                                            w[:, d])) <= REL
 
 
 # -- exact parity vs the single-tile kernels ----------------------------------
@@ -246,8 +299,8 @@ def test_structured_result_invariant_to_block_shape():
     xi, mass, a, w = _structured_inputs(rng, B=2, E=17, V=300, R=5,
                                         shared=True)
     outs = [np.asarray(edge_latency_structured_pallas(
-        xi, mass, a, w, block_edges=be, block_v=bv, interpret=True))
-        for be, bv in [(8, 128), (16, 256), (64, 512)]]
+        xi, mass, a, w, block_v=bv, interpret=True))
+        for bv in [128, 256, 512]]
     for other in outs[1:]:
         np.testing.assert_allclose(other, outs[0], rtol=1e-5, atol=1e-5)
 

@@ -240,3 +240,25 @@ def test_evaluator_pallas_path_matches_jnp_path():
         BatchedEvaluator(g, use_pallas=True, interpret=True)
         .score_grid(xs, coms))
     np.testing.assert_allclose(pal_grid, jnp_grid, rtol=1e-5, atol=1e-6)
+
+
+def test_dense_score_grid_span_counts_kernel_rows(telemetry):
+    """On the Pallas route the ``score_grid`` span records ``kernel_rows``,
+    the V-sized rows a placement row costs the edge kernel: the dense
+    kernel reads the two padded per-edge endpoint rows, 2·e_pad."""
+    from repro.core import ExplicitFleet
+    from repro.kernels.edge_latency import block_geometry
+
+    rng = np.random.default_rng(6)
+    g = linear_graph([1.0, 0.5, 2.0, 1.5])
+    coms = pack_fleets([ExplicitFleet(com_cost=rng.uniform(0.1, 2.0,
+                                                           (6, 6)))])
+    xs = pack_placements([rng.uniform(0, 1, (4, 6)) for _ in range(3)])
+    BatchedEvaluator(g, use_pallas=True, interpret=True).score_grid(xs, coms)
+    BatchedEvaluator(g, use_pallas=False).score_grid(xs, coms)
+    cfg = autotune.get_config("dense", 3, g.n_edges, 6)
+    e_pad = block_geometry("dense", g.n_edges, 6, None, cfg.block_edges,
+                           cfg.block_v).e_pad
+    spans = [e["args"] for e in obs.trace_events()
+             if e["name"] == "score_grid"]
+    assert [a.get("kernel_rows") for a in spans] == [2 * e_pad, None]

@@ -86,14 +86,15 @@ def test_roofline_terms_and_dominance():
 # -- pinned against actually-compiled edge-latency kernels --------------------
 # Costs of the paper's edge-latency contraction (B=2, E=6, V=8, R=4) as the
 # V-BLOCKED kernels actually compile it: the wrappers pad V (and R) to the
-# lane width and E to the sublane width (block_geometry is the single
-# source of truth), so the dominant dot costs 2·B·e_pad·v_pad² (dense) /
-# 2·B·e_pad·r_pad·v_pad (structured).  FLOPs are pinned to a tight band
-# around that dot — exact equality would re-pin XLA's deterministic but
+# lane width and, on the dense kernel, E to the sublane width
+# (block_geometry is the single source of truth), so the dominant dot
+# costs 2·B·e_pad·v_pad² (dense) / 2·B·n_ops·r_pad·v_pad (structured: per
+# operator, not per edge).  FLOPs are pinned to a tight band around that
+# dot — exact equality would re-pin XLA's deterministic but
 # version-dependent accounting of the elementwise mask/mul/max tail, which
 # is O(1/v_pad) of the dot.  HBM bytes only as >= the PADDED I/O lower
 # bound, since interpret-mode Pallas lowering adds interpreter traffic;
-# the output term is the kernels' lane-dense (B, e_pad, out_lanes) block.
+# the output term is the kernels' lane-dense output block.
 
 _B, _E, _V, _R = 2, 6, 8, 4
 
@@ -128,20 +129,28 @@ def test_dense_edge_latency_kernel_flops_pinned():
 
 
 def test_structured_edge_latency_kernel_flops_pinned():
+    """The structured kernel contracts ``mass @ a`` once per OPERATOR row
+    (n_ops = 4 here), not once per edge (E = 6), and reads the per-operator
+    rows x and w once: 2·B·n_ops·r_pad·v_pad dot flops, and an I/O floor
+    of those rows, the masses, ``a`` and the lane-dense (E, B, 128)
+    output."""
     from repro.kernels.edge_latency import (block_geometry,
-                                            edge_latency_structured_pallas)
+                                            edge_latency_structured_pallas,
+                                            edge_list)
 
+    n = 4
+    edges = edge_list((0, 0, 1, 2, 1, 0), (1, 2, 3, 3, 2, 3), np.ones(_E))
     text = _kernel_hlo(
-        lambda xi, m, a, w: edge_latency_structured_pallas(
-            xi, m, a, w, interpret=True),
-        (_B, _E, _V), (_B, _E, _R), (1, _R, _V), (_B, _E, _V))
+        lambda x, m, a, w: edge_latency_structured_pallas(
+            x, m, a, w, edges, interpret=True),
+        (_B, n, _V), (_B, n, _R), (1, _R, _V), (_B, n, _V))
     s = analyze_module(text)
-    g = block_geometry("structured", _E, _V, _R, 128, 512)
-    lo, hi = _flops_band(2 * _B * g.e_pad * g.r_pad * g.v_pad,
-                         _B * g.e_pad * g.v_pad)
+    g = block_geometry("structured", _E, _V, _R, _E, 2048)
+    lo, hi = _flops_band(2 * _B * n * g.r_pad * g.v_pad,
+                         _B * (_E + n) * g.v_pad)
     assert lo <= s.flops <= hi
-    io_floor = 4 * (2 * _B * g.e_pad * g.v_pad + _B * g.e_pad * g.r_pad
-                    + g.r_pad * g.v_pad + _B * g.e_pad * g.out_lanes)
+    io_floor = 4 * (2 * _B * n * g.v_pad + _B * n * g.r_pad
+                    + g.r_pad * g.v_pad + _E * _B * g.out_lanes)
     assert s.hbm_bytes >= io_floor
 
 
@@ -158,3 +167,37 @@ def test_kernel_roofline_terms_finite():
                       wire_bytes=0.0, chips=1, model_flops=s.flops)
     assert t.step_time_s > 0 and np.isfinite(t.step_time_s)
     assert t.dominant in ("compute", "memory")
+
+
+def test_structured_score_grid_writes_no_per_edge_rows():
+    """A compiled structured score_grid (the Pallas route, interpreted)
+    holds no float32 buffer of per-edge rows, (·, E, V) or (·, e_pad, V):
+    the edge kernel reads per-operator rows and gathers to edges itself
+    (its (e_pad, bv) tile is the kernel body's own, two-dimensional)."""
+    import re
+
+    from repro.kernels.edge_latency import SUBLANE
+    from repro.sim import (BatchedEvaluator, ScenarioConfig, fresh_cache,
+                           random_graph, region_fleet_family)
+
+    rng = np.random.default_rng(0)
+    graph = random_graph(rng, ScenarioConfig(n_ops=(7, 7),
+                                             graph_families=("layered",)))
+    V, R, P, S = 300, 4, 5, 2
+    fam = region_fleet_family(rng, S, ScenarioConfig(n_regions=(R, R)),
+                              n_devices=V)
+    E, n = graph.n_edges, graph.n_ops
+    e_pad = -(-E // SUBLANE) * SUBLANE
+    assert n not in (E, e_pad)  # operator rows are told from edge rows
+    with fresh_cache():
+        ev = BatchedEvaluator(graph, use_pallas=True, interpret=True)
+        args = [jax.ShapeDtypeStruct(s, jnp.float32)
+                for s in ((P, n, V), (S, R, R), (S, V), (), ())]
+        text = jax.jit(ev._structured(fam).grid).lower(*args).compile() \
+            .as_text()
+    shapes = {tuple(int(d) for d in m.split(","))
+              for m in re.findall(r"f32\[(\d+(?:,\d+)+)\]", text)}
+    assert (P, n, V) in shapes or (P, n, 384) in shapes  # rows are there
+    per_edge = [s for s in shapes if len(s) >= 3 and s[-2] in (E, e_pad)
+                and s[-1] >= V]
+    assert per_edge == [], per_edge

@@ -184,8 +184,9 @@ def test_colocated_weighted_movement_matches_oracle(case):
 
 def test_structured_span_and_scope(telemetry):
     """The ``score_grid`` span carries ``R`` on the structured path, and
-    the region terms' device operations carry the ``region.terms`` scope
-    on both routes."""
+    on the Pallas route ``kernel_rows``: the edge kernel reads x and w per
+    operator, 2·n_ops V-sized rows a placement row.  The region terms'
+    device operations carry the ``region.terms`` scope on both routes."""
     import jax
 
     from repro import obs
@@ -204,3 +205,4 @@ def test_structured_span_and_scope(telemetry):
     spans = [e["args"] for e in obs.trace_events()
              if e["name"] == "score_grid"]
     assert [(a["path"], a["R"]) for a in spans] == [("structured", R)] * 2
+    assert [a.get("kernel_rows") for a in spans] == [None, 2 * g.n_ops]

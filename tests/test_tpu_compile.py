@@ -23,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import autotune, dispatch
 from repro.kernels.edge_latency import (edge_latency_pallas,
-                                        edge_latency_structured_pallas)
+                                        edge_latency_structured_pallas,
+                                        edge_list)
 from repro.sim import BatchedEvaluator, ScenarioConfig, fresh_cache, \
     region_fleet_family
 
@@ -92,24 +93,32 @@ def _compiled_text(fn, args) -> str:
 @pytest.mark.parametrize("kind", ["dense", "structured"])
 def test_blocked_kernel_compiles(chip, smoke, kind, blocks):
     """Both blocked kernels at the smoke's chunk shape, with the block
-    shapes the tuner picks for a v5e and with the wrappers' defaults."""
+    shapes the tuner picks for a v5e and with the wrappers' defaults: the
+    dense one on per-edge rows, the structured one on per-operator rows
+    with the smoke graph's edge list."""
     sharding, device_kind = chip
     cs, graph = smoke
-    P, E, full = cs.FULL.chunk_rows, graph.n_edges, cs.FULL
+    P, E, n, full = cs.FULL.chunk_rows, graph.n_edges, graph.n_ops, cs.FULL
     if kind == "dense":
         V, R = full.dense_devices, None
         args = _shapes(sharding, (P, E, V), (P, E, V), (1, V, V))
     else:
         V, R = full.struct_devices, full.regions
-        args = _shapes(sharding, (P, E, V), (P, E, R), (1, R, V),
-                       (P, E, V))
+        args = _shapes(sharding, (P, n, V), (P, n, R), (1, R, V),
+                       (P, n, V))
     cfg = (autotune.get_config(kind, P, E, V, R, backend="tpu",
-                               device_kind=device_kind)
+                               device_kind=device_kind, n_ops=n)
            if blocks == "tuned" else autotune.DEFAULT_CONFIG)
-    kernel = (edge_latency_pallas if kind == "dense"
-              else edge_latency_structured_pallas)
-    _compiled_text(lambda *a: kernel(*a, block_edges=cfg.block_edges,
-                                     block_v=cfg.block_v), args)
+    if kind == "dense":
+        _compiled_text(lambda *a: edge_latency_pallas(
+            *a, block_edges=cfg.block_edges, block_v=cfg.block_v), args)
+    else:
+        edges = edge_list([i for i, _ in graph.edges],
+                          [j for _, j in graph.edges],
+                          [graph.operators[i].selectivity
+                           for i, _ in graph.edges])
+        _compiled_text(lambda *a: edge_latency_structured_pallas(
+            *a, edges, block_v=cfg.block_v), args)
 
 
 @pytest.fixture
