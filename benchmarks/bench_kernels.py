@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import autotune, ops, ref
-from repro.kernels.dispatch import backend_name, resolve_flags
+from repro.kernels.dispatch import backend_name, route
 from repro.kernels.edge_latency import (edge_latency_pallas,
                                         edge_latency_structured_pallas)
 from repro.obs import bench as obench
@@ -196,7 +196,7 @@ def _micro_rows() -> list[str]:
 def run(smoke: bool = False) -> list[str]:
     rng = np.random.default_rng(0)
     backend = backend_name()
-    _, interpret = resolve_flags(use_pallas=True)
+    interpret = route().interpret
     dense_sweep = DENSE_SMOKE_V if smoke else DENSE_FULL_V
     struct_sweep = STRUCT_SMOKE_V if smoke else STRUCT_FULL_V
     autotune.clear_table()  # race against THIS run's decisions, not a

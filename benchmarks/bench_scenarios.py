@@ -1,7 +1,7 @@
 """Scenario-simulation throughput: (scenario × placement) grids scored by
-the batched evaluator vs looping the scalar ``latency()`` path, plus the
-Pallas edge-latency kernel variant (compiled on an accelerator,
-interpreted on the CPU, as the dispatch policy resolves it).  Writes BENCH_scenarios.json with
+the batched evaluator (on the route the dispatch gives the backend: the
+jaxmodel twin on the CPU, compiled Pallas on an accelerator) vs looping
+the scalar ``latency()`` path.  Writes BENCH_scenarios.json with
 candidates-scored-per-second and the batched-vs-scalar speedup (the ISSUE's
 ≥10× acceptance gate)."""
 
@@ -41,10 +41,6 @@ def run() -> list[str]:
     ev = BatchedEvaluator(g)
     s_batched = _time(lambda: np.asarray(ev.score_grid(P, coms, dq=0.3,
                                                        beta=0.5)))
-    evp = BatchedEvaluator(g, use_pallas=True)
-    s_pallas = _time(lambda: np.asarray(evp.score_grid(P, coms, dq=0.3,
-                                                       beta=0.5)),
-                     n=2)
 
     # scalar reference: python loop over a subset, extrapolated per-candidate
     sub = 32
@@ -59,7 +55,6 @@ def run() -> list[str]:
 
     batched_per = s_batched / n_cand
     speedup = s_scalar_per / batched_per
-    pallas_per = s_pallas / n_cand
     report = {
         "n_scenarios": n_scenarios,
         "n_placements": n_placements,
@@ -69,8 +64,6 @@ def run() -> list[str]:
         "candidates_per_second": 1.0 / batched_per,
         "batched_us_per_candidate": batched_per * 1e6,
         "backend": jax.default_backend(),
-        "pallas_interpret": evp.interpret,
-        "pallas_us_per_candidate": pallas_per * 1e6,
         "scalar_us_per_candidate": s_scalar_per * 1e6,
         "batched_vs_scalar_speedup": speedup,
     }
@@ -80,6 +73,4 @@ def run() -> list[str]:
         f"{batched_per * 1e6:.2f},"
         f"cands_per_s={1.0 / batched_per:.0f};speedup_vs_scalar={speedup:.1f}",
         f"scenarios_scalar_loop_dev{v},{s_scalar_per * 1e6:.2f},per_candidate",
-        f"scenarios_pallas_dev{v},{pallas_per * 1e6:.2f},"
-        f"per_candidate;interpret={evp.interpret}",
     ]

@@ -19,12 +19,11 @@ and then serves the same queries again warm.  It checks that
   * ``ORACLE_PAIRS`` (scenario, placement) pairs per deployment agree with
     the float64 oracle in ``repro.core.costmodel`` within ``ORACLE_RTOL``;
   * the edge kernels ran as compiled Pallas: ``kernels.dispatch.plans``
-    with ``impl=pallas, interpret=False`` is positive, and nothing was
-    interpreted on the accelerator or coerced;
+    with ``interpret=False`` is positive, and no plan was interpreted;
   * the structured edge kernel, given per-operator rows and the edge
-    list, agrees with the XLA route given rows gathered to edges, on one
-    scenario's region terms at the deployment's V and R: bitwise, or
-    within ``KERNEL_GAP`` relative (it prints which).
+    list, agrees with ``ref.edge_latency_structured_ref`` given rows
+    gathered to edges, on one scenario's region terms at the deployment's
+    V and R: bitwise, or within ``KERNEL_GAP`` relative (it prints which).
 
 Run it from the root of a checkout:
 
@@ -57,7 +56,7 @@ from repro.core.costmodel import latency, objective_F  # noqa: E402
 from repro.core.jaxmodel import (region_a_off, region_own,  # noqa: E402
                                  region_terms)
 from repro.core.objectives import ObjectiveGrids, ObjectiveSet  # noqa: E402
-from repro.kernels import dispatch  # noqa: E402
+from repro.kernels import dispatch, ref  # noqa: E402
 from repro.kernels.edge_latency import edge_list  # noqa: E402
 from repro.obs import jaxhooks  # noqa: E402
 from repro.search.decision import (joint_dq_scores, pareto_front,  # noqa: E402
@@ -115,7 +114,7 @@ ORACLE_PAIRS = 8
 ORACLE_RTOL = 1e-5
 
 
-# the structured kernel against the gathered XLA route: relative, as the
+# the structured kernel against the gathered jnp reference: relative, as the
 # structured benchmark cell's ``gap`` limit
 KERNEL_GAP = 2e-06
 
@@ -304,7 +303,7 @@ def check_oracle(graph, dep: Deployment, served: list[Served],
 def check_structured_kernel(graph, dep: Deployment, seed: int,
                             shape: Shape, rows: int = 8) -> dict:
     """The structured edge kernel on per-operator rows with the graph's
-    edge list against the XLA route on the same rows gathered to edges
+    edge list against the jnp reference on the same rows gathered to edges
     (``x[src]·sel``, ``mass[dst]``, ``w[dst]``), for ``rows`` placements
     and the region terms of the family's first scenario.  Raises
     AssertionError beyond ``KERNEL_GAP`` relative."""
@@ -322,15 +321,14 @@ def check_structured_kernel(graph, dep: Deployment, seed: int,
     dst = np.array([j for _, j in graph.edges])
     edges = edge_list(src, dst, [graph.operators[i].selectivity
                                  for i in src])
-    kernel = dispatch.edge_latency_structured(x, mass, a, w, edges,
-                                              use_pallas=True)
+    kernel = dispatch.edge_latency_structured(x, mass, a, w, edges)
     x_i = x[:, src] * jnp.asarray(edges[2], jnp.float32)[None, :, None]
-    xla = dispatch.edge_latency_structured(x_i, mass[:, dst], a, w[:, dst],
-                                           use_pallas=False)
-    got, want = (np.asarray(v, np.float64) for v in (kernel, xla))
+    reference = ref.edge_latency_structured_ref(x_i, mass[:, dst], a,
+                                                w[:, dst])
+    got, want = (np.asarray(v, np.float64) for v in (kernel, reference))
     rel = float(np.max(np.abs(got - want) / np.abs(want)))
     if not rel <= KERNEL_GAP:
-        raise AssertionError(f"structured kernel vs gathered XLA route: "
+        raise AssertionError(f"structured kernel vs gathered reference: "
                              f"rel {rel:.3g} > {KERNEL_GAP}")
     return {"kernel_bitwise": bool(np.array_equal(got, want)),
             "kernel_max_rel": rel, "kernel_gap": KERNEL_GAP,
@@ -338,8 +336,7 @@ def check_structured_kernel(graph, dep: Deployment, seed: int,
 
 
 def dispatch_counts(reg) -> dict[str, float]:
-    """Compiled-Pallas plans and the two counters that would mean the
-    device was bypassed."""
+    """Edge-kernel plans, compiled and interpreted."""
     rows = [r for r in reg.snapshot() if r["type"] == "counter"]
 
     def total(name, **labels):
@@ -348,11 +345,9 @@ def dispatch_counts(reg) -> dict[str, float]:
 
     return {
         "pallas_compiled_plans": total("kernels.dispatch.plans",
-                                       impl="pallas", interpret="False"),
-        "xla_plans": total("kernels.dispatch.plans", impl="xla"),
-        "interpret_on_accelerator": total(
-            "kernels.dispatch.interpret_on_accelerator"),
-        "coerced": total("kernels.dispatch.coerced"),
+                                       interpret="False"),
+        "pallas_interpreted_plans": total("kernels.dispatch.plans",
+                                          interpret="True"),
     }
 
 
@@ -417,8 +412,7 @@ def main(argv: list[str] | None = None) -> int:
     counts = dispatch_counts(obs.registry())
     print("smoke dispatch " + json.dumps(counts))
     if not (counts["pallas_compiled_plans"] > 0
-            and counts["interpret_on_accelerator"] == 0
-            and counts["coerced"] == 0):
+            and counts["pallas_interpreted_plans"] == 0):
         raise AssertionError(f"edge kernels did not run as compiled "
                              f"Pallas: {counts}")
     mem = dev.memory_stats() or {}

@@ -2,21 +2,21 @@
 oracle in ref.py; validated with interpret=True on CPU.
 
 The edge-latency hot path is V-blocked for compiled execution and routed
-through :mod:`repro.kernels.dispatch` (XLA einsum vs Pallas, interpret vs
-compiled, autotuned block shapes) — see kernels/README.md.
+through :mod:`repro.kernels.dispatch`, which decides from the backend
+whether the kernels run interpreted or compiled and picks autotuned block
+shapes — see kernels/README.md.
 """
 
 from repro.kernels.autotune import (DEFAULT_CONFIG, KernelConfig, ShapeKey,
                                     get_config)
 from repro.kernels.dispatch import (KernelPlan, backend_name, edge_latency,
-                                    edge_latency_structured, plan_edge_kernel,
-                                    resolve_flags)
+                                    edge_latency_structured, plan_edge_kernel)
 from repro.kernels.edge_latency import (LANE, SUBLANE, BlockGeometry,
                                         block_geometry)
 
 __all__ = [
     "LANE", "SUBLANE", "BlockGeometry", "block_geometry",
     "KernelConfig", "ShapeKey", "DEFAULT_CONFIG", "get_config",
-    "KernelPlan", "backend_name", "resolve_flags", "plan_edge_kernel",
+    "KernelPlan", "backend_name", "plan_edge_kernel",
     "edge_latency", "edge_latency_structured",
 ]

@@ -37,8 +37,9 @@ Compiled-ready blocking scheme (see kernels/README.md for the full story):
     wrapper keeps lane 0; the structured kernel's (E, B, LANE) holds a
     running max per lane, and the wrapper takes the max over lanes.
 
-Block shapes come from :mod:`repro.kernels.autotune` via the dispatch layer
-(:mod:`repro.kernels.dispatch`); the single-tile kernels the blocked ones
+The dispatch layer (:mod:`repro.kernels.dispatch`) calls these kernels,
+compiled on an accelerator and interpreted on the CPU, with block shapes
+from :mod:`repro.kernels.autotune`; the single-tile kernels the blocked ones
 replaced are kept as ``*_single_tile`` parity references — at small V the
 blocked kernels reproduce them bitwise (gated in tests/test_kernel_blocking).
 """

@@ -9,9 +9,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["flash_attention_ref", "ssd_ref", "rmsnorm_ref",
-           "edge_latency_ref"]
+           "edge_latency_ref", "edge_latency_structured_ref"]
 
 
 def flash_attention_ref(q, k, v, causal: bool = True):
@@ -68,3 +69,23 @@ def edge_latency_ref(x_i, x_j, com):
     t = jnp.einsum("buv,bev->beu", com.astype(jnp.float32),
                    x_j.astype(jnp.float32))
     return jnp.max(x_i.astype(jnp.float32) * t, axis=-1)
+
+
+def edge_latency_structured_ref(x, mass, a, w, edges=None):
+    """x, w: (B, n, V) per-operator rows; mass: (B, n, R); a: (B|1, R, V);
+    ``edges`` the static ``(src, dst, sel)`` edge list (None: one edge per
+    row, ``src = dst``, ``sel = 1``).
+
+    out[b, e] = max_u x[b,src,u]·sel · (mass[b,dst] @ a[b] + w[b,dst])_u —
+    the structured kernel's contract, with ``t`` materialized per operator."""
+    if edges is None:
+        n = x.shape[1]
+        edges = (np.arange(n), np.arange(n), np.ones(n))
+    src, dst, sel = (np.asarray(e) for e in edges)
+    t = jnp.einsum("bnr,bru->bnu", mass.astype(jnp.float32),
+                   a.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) \
+        + w.astype(jnp.float32)
+    x_i = x.astype(jnp.float32)[:, src] * jnp.asarray(
+        sel, jnp.float32)[None, :, None]
+    return jnp.max(x_i * t[:, dst], axis=-1)

@@ -65,7 +65,6 @@ class BatchedProblem:
 
     prob: PlacementProblem
     chunk: int = 4096
-    use_pallas: bool | None = None
     # an already-built evaluator to reuse (same graph/cfg): callers that
     # re-solve the same problem shape against CHANGING fleets — the
     # closed-loop controller re-optimizing after every recalibration — keep
@@ -88,8 +87,7 @@ class BatchedProblem:
         if self.scalar_fallback:
             return
         self._ev = self.evaluator if self.evaluator is not None else \
-            BatchedEvaluator.shared(self.prob.graph, self.prob.cost_cfg,
-                                    use_pallas=self.use_pallas)
+            BatchedEvaluator.shared(self.prob.graph, self.prob.cost_cfg)
         fleet = self.prob.fleet
         if isinstance(fleet, RegionFleet):
             self._pack = RegionFleetFamily.from_fleets([fleet])

@@ -87,7 +87,6 @@ def robust_placement(graph: OpGraph, scenarios, rng: np.random.Generator,
                      cfg: CostConfig = CostConfig(), beta: float = 0.0,
                      dq: float | np.ndarray = 0.0, sparsity: float = 0.5,
                      extra_candidates: list[np.ndarray] | None = None,
-                     use_pallas: bool | None = None,
                      objectives: ObjectiveSet | None = None):
     """Min–max what-if selection: the placement minimizing the worst-case
     score over the scenario batch.
@@ -112,7 +111,7 @@ def robust_placement(graph: OpGraph, scenarios, rng: np.random.Generator,
         raise ValueError("need at least one scenario")
     candidates = _candidates(graph, scenarios[0].n_devices, rng,
                              n_candidates, sparsity, extra_candidates)
-    ev = BatchedEvaluator(graph, cfg, use_pallas=use_pallas)
+    ev = BatchedEvaluator(graph, cfg)
     pack = _pack_scenario_fleets(scenarios)
     speed = None
     if objectives is not None and not isinstance(pack, RegionFleetFamily):
@@ -128,12 +127,11 @@ def _joint_robust_placement(graph: OpGraph, scenarios,
                             candidates: list[np.ndarray],
                             cfg: CostConfig, beta: float,
                             dq_values: np.ndarray, dq_coupling,
-                            objectives: ObjectiveSet | None,
-                            use_pallas: bool | None = None):
+                            objectives: ObjectiveSet | None):
     """Joint (placement × dq) min–max: ONE raw dispatch at dq = 0, then the
     analytic per-scenario dq expansion.  Returns
     ``(x_best, worst, scores (S, P), dq_sel (S,) for the winner)``."""
-    ev = BatchedEvaluator(graph, cfg, use_pallas=use_pallas)
+    ev = BatchedEvaluator(graph, cfg)
     pack = _pack_scenario_fleets(scenarios)
     placements = pack_placements(candidates)
     if objectives is None:

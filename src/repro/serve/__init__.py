@@ -4,8 +4,8 @@
 :class:`WhatIfService` answers heterogeneous tenant queries — score a
 placement batch, rank candidates (weighted or ε-constraint), extract a
 Pareto front, co-optimize placement × dq — through shared dispatches:
-queries normalize to a :class:`CoalesceKey` (evaluator family + fleet
-content digest + objective set; dq/β deliberately excluded because they
+queries normalize to a :class:`CoalesceKey` (fleet content digest +
+objective set; dq/β deliberately excluded because they
 are per-row operands of the dispatch), merge across tenants into power-of-two-padded
 super-batches, resolve compiled executables through the process-wide
 :mod:`repro.sim.execache`, and stream results back per tenant.  Every

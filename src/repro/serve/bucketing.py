@@ -15,10 +15,11 @@ Two facts make that sharing wide instead of narrow:
     individual row's result (bitwise; gated in ``bench_serve`` and
     ``tests/test_serve.py``).
 
-What remains in the coalescing key is exactly what the compiled executable
-and the operands pin: the evaluator family (graph content + CostConfig +
-pallas flags), the scenario pack (content digest — two tenants registering
-equal fleets coalesce), and the objective set.  The padded row count is
+What remains in the coalescing key is exactly what separates one
+service's queues: the scenario pack (content digest — two tenants
+registering equal fleets coalesce) and the objective set.  The graph and
+CostConfig need no place in it, since each service owns one of each and
+its own queues.  The padded row count is
 the *shape bucket*: the unit of executable-cache identity, admission
 pricing, and per-bucket telemetry.
 """
@@ -30,10 +31,8 @@ import hashlib
 
 import numpy as np
 
-from repro.core.costmodel import CostConfig
 from repro.core.devices import RegionFleetFamily
 from repro.core.objectives import ObjectiveSet
-from repro.sim.execache import graph_key
 
 __all__ = ["CoalesceKey", "fleet_digest", "next_pow2", "pad_rows"]
 
@@ -70,27 +69,16 @@ def fleet_digest(pack) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class CoalesceKey:
-    """Everything two queries must agree on to share one raw dispatch.
+    """Everything two queries of one service must agree on to share one
+    raw dispatch.
 
-    ``graph`` / ``cfg`` / pallas flags pin the compiled evaluator family,
     ``fleet`` (the registration-time content digest) pins the scenario
     operands, ``objectives`` pins the multi-objective executable (None =
     the single-objective latency grid).  dq/β are deliberately ABSENT —
     they are per-row operands of the shared dispatch."""
 
-    graph: tuple
-    cfg: CostConfig
-    use_pallas: bool
-    interpret: bool
     fleet: str
     objectives: ObjectiveSet | None
-
-    @classmethod
-    def of(cls, graph, cfg: CostConfig, use_pallas: bool, interpret: bool,
-           fleet_id: str, objectives: ObjectiveSet | None) -> "CoalesceKey":
-        return cls(graph=graph_key(graph), cfg=cfg, use_pallas=use_pallas,
-                   interpret=interpret, fleet=fleet_id,
-                   objectives=objectives)
 
 
 def pad_rows(xs: np.ndarray, bucket: int) -> np.ndarray:

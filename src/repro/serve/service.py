@@ -215,8 +215,6 @@ class WhatIfService:
     """
 
     def __init__(self, graph: OpGraph, cfg: CostConfig = CostConfig(),
-                 use_pallas: bool | None = None,
-                 interpret: bool | None = None,
                  admission: AdmissionConfig = AdmissionConfig(),
                  max_chunk_rows: int = 1024):
         if max_chunk_rows < 1 or max_chunk_rows & (max_chunk_rows - 1):
@@ -224,21 +222,11 @@ class WhatIfService:
                              f"got {max_chunk_rows}")
         self.graph = graph
         self.cfg = cfg
-        # kernel flags resolve ONCE through the dispatch policy (None =
-        # auto for the backend), so the service can never pin interpreted
-        # kernels on an accelerator — and the resolved booleans feed both
-        # the shared evaluator and every CoalesceKey, keeping the serving
-        # layer and sim layer on the same executables
-        from repro.kernels.dispatch import resolve_flags
-        self.use_pallas, self.interpret = resolve_flags(use_pallas,
-                                                        interpret)
         self.admission = admission
         self.max_chunk_rows = max_chunk_rows
         # evaluator resolves through the process-wide executable cache:
         # services, search engines and scripts over equal graphs share one
-        self._ev = BatchedEvaluator.shared(graph, cfg,
-                                           use_pallas=use_pallas,
-                                           interpret=interpret)
+        self._ev = BatchedEvaluator.shared(graph, cfg)
         self._fleets: dict[str, _Fleet] = {}
         self._packs: dict[str, object] = {}    # dense, by content digest
         self._queues: dict[CoalesceKey, list[_Pending]] = {}
@@ -280,8 +268,7 @@ class WhatIfService:
                     pack, moved = self._resident(digest, pack)
                     S, V = int(pack.shape[0]), int(pack.shape[1])
                     R = None
-                key = CoalesceKey.of(self.graph, self.cfg, self.use_pallas,
-                                     self.interpret, fid, obj_set)
+                key = CoalesceKey(fleet=fid, objectives=obj_set)
                 self._fleets[fid] = _Fleet(
                     pack=pack, key=key, n_scenarios=S, n_devices=V,
                     objectives=obj_set,

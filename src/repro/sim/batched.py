@@ -59,14 +59,16 @@ from repro.core.jaxmodel import (SmoothConfig, _edge_arrays,
                                  make_edge_latencies_region_fn)
 from repro.core.objectives import (ObjectiveGrids, ObjectiveSet,
                                    as_objective_set)
+from repro.kernels import dispatch
 from repro.kernels.edge_latency import block_geometry, edge_list
+from repro.kernels.region_sum import region_sum_pallas
 
 __all__ = ["BatchedEvaluator", "SparsePlacements", "pack_fleets",
            "pack_placements", "pack_region_fleets", "pack_speeds",
            "sparse_placements"]
 
 # instance memo behind BatchedEvaluator.shared(): one evaluator per
-# (graph content, cfg, pallas flags), so independent consumers (search
+# (graph content, cfg, dispatch route), so independent consumers (search
 # engines, the serving layer, examples) converge on the same instance —
 # and therefore the same compiled executables — instead of warming their
 # own.  The compiled state itself lives in repro.sim.execache either way;
@@ -246,24 +248,18 @@ class BatchedEvaluator:
       objective(x, com, dq, beta)         -> (B,)
       score_grid(x (P,n,V), com [S scen]) -> (S, P)   — ONE dispatch
 
-    ``use_pallas`` routes the inner reduction through the Pallas kernels
-    (dense bilinear-max or structured region-mass matmul).  Both flags
-    default to ``None`` = "auto for the backend" and resolve ONCE through
-    :func:`repro.kernels.dispatch.resolve_flags` (CPU: jnp path +
-    interpret; accelerators: Pallas + compiled), so no caller silently
-    runs interpreted kernels on an accelerator or compiled mode on CPU.
-    After construction both attributes are concrete booleans.
+    The inner reduction takes the route :func:`repro.kernels.dispatch.route`
+    gives at construction: on an accelerator the compiled Pallas kernels
+    (dense bilinear-max or structured region-mass matmul), on the CPU the
+    ``core/jaxmodel`` twin vmapped — the reference the CPU tests compare
+    the interpreted kernels against.
     """
 
     graph: OpGraph
     cfg: CostConfig = CostConfig()
-    use_pallas: bool | None = None
-    interpret: bool | None = None
 
     def __post_init__(self):
-        from repro.kernels.dispatch import resolve_flags
-        self.use_pallas, self.interpret = resolve_flags(self.use_pallas,
-                                                        self.interpret)
+        self._route = dispatch.route()
         src, dst, sel = _edge_arrays(self.graph)
         self._src = jnp.asarray(src)
         self._dst = jnp.asarray(dst)
@@ -284,9 +280,8 @@ class BatchedEvaluator:
         # configs share ONE jitted function object, so jax's compilation
         # cache hits instead of recompiling per instance.  The builder
         # closures bind this instance, which is safe exactly because the
-        # key pins everything they read (graph content, cfg, pallas flags).
-        ek = self._eval_key = (graph_key(self.graph), self.cfg,
-                              self.use_pallas, self.interpret)
+        # key pins everything they read (graph content, cfg, route).
+        ek = self._eval_key = (graph_key(self.graph), self.cfg, self._route)
         cache = executable_cache()
         self._jit_elat = cache.get_or_build(
             ("dense_elat", ek), lambda: jax.jit(self._elat_batched))
@@ -298,37 +293,28 @@ class BatchedEvaluator:
             ("dense_grid", ek), lambda: jax.jit(self._grid))
 
     @classmethod
-    def shared(cls, graph: OpGraph, cfg: CostConfig = CostConfig(),
-               use_pallas: bool | None = None,
-               interpret: bool | None = None) -> "BatchedEvaluator":
-        """The process-shared evaluator for this (graph, cfg, flags) —
-        equal-content graphs map to the SAME instance, so every consumer
-        (search engines, :mod:`repro.serve`, scripts) reuses one set of
-        compiled executables instead of warming its own.  Flags resolve
-        through the dispatch policy BEFORE the memo key, so ``None`` and
-        its concrete resolution map to the same instance."""
-        from repro.kernels.dispatch import resolve_flags
-        use_pallas, interpret = resolve_flags(use_pallas, interpret)
-        key = ("evaluator", graph_key(graph), cfg, use_pallas, interpret)
-        return _shared_evaluators.get_or_build(
-            key, lambda: cls(graph, cfg, use_pallas=use_pallas,
-                             interpret=interpret))
+    def shared(cls, graph: OpGraph,
+               cfg: CostConfig = CostConfig()) -> "BatchedEvaluator":
+        """The process-shared evaluator for this (graph, cfg) on the current
+        route — equal-content graphs map to the SAME instance, so every
+        consumer (search engines, :mod:`repro.serve`, scripts) reuses one
+        set of compiled executables instead of warming its own."""
+        key = ("evaluator", graph_key(graph), cfg, dispatch.route())
+        return _shared_evaluators.get_or_build(key, lambda: cls(graph, cfg))
 
     # -- dense batched math (all shapes carry a leading B) -------------------
     def _elat_batched(self, x: jnp.ndarray, com: jnp.ndarray) -> jnp.ndarray:
         """x (B, n, V) against com (B, V, V), or (1, V, V) = one shared
         scenario (the Pallas index map / vmap in_axes share it without
         replicating it in memory)."""
-        if not self.use_pallas:
+        if not self._route.pallas:
             if com.shape[0] == 1 and x.shape[0] != 1:
                 return jax.vmap(self._elat_single, in_axes=(0, None))(
                     x, com[0])                             # (B, E)
             return jax.vmap(self._elat_single)(x, com)     # (B, E)
         x_i = x[:, self._src] * self._sel[None, :, None]   # (B, E, V)
         x_j = x[:, self._dst]                              # (B, E, V)
-        from repro.kernels.dispatch import edge_latency
-        out = edge_latency(x_i, x_j, com, use_pallas=True,
-                           interpret=self.interpret)
+        out = dispatch.edge_latency(x_i, x_j, com)
         return out + self._links_term(x, out.dtype)
 
     def _links_term(self, x: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -395,7 +381,7 @@ class BatchedEvaluator:
 
         def elat_b(x, inter, degrade):
             """x (B, n, V); inter (Sb, R, R), degrade (Sb, V), Sb ∈ {1, B}."""
-            if not self.use_pallas:
+            if not self._route.pallas:
                 if inter.shape[0] == 1 and x.shape[0] != 1:
                     return jax.vmap(elat_single, in_axes=(0, None, None))(
                         x, inter[0], degrade[0])           # (B, E)
@@ -410,14 +396,13 @@ class BatchedEvaluator:
             # edge kernel takes the per-operator rows with the edge list
             # and fuses t = mass @ a_off + w, the gather to edges and the
             # row max, so no (B, E, V) copy is written
-            from repro.kernels.region_sum import region_sum_pallas
             with jax.named_scope("region.terms"):
                 onehot = (jnp.arange(n_regions)[:, None]
                           == region_ix[None, :]).astype(jnp.float32)
 
                 def segment_sum(v):
-                    return region_sum_pallas(v, onehot,
-                                             interpret=self.interpret)
+                    return region_sum_pallas(
+                        v, onehot, interpret=self._route.interpret)
 
                 def per_device(m):
                     return jnp.matmul(m, onehot,
@@ -437,11 +422,9 @@ class BatchedEvaluator:
                         lambda x1, i, d: terms(x1[None], i, d))(
                         x, inter, degrade)
                     mass, w = mass[:, 0], w[:, 0]       # (B, n, ·)
-            from repro.kernels.dispatch import edge_latency_structured
-            out = edge_latency_structured(
+            out = dispatch.edge_latency_structured(
                 x.astype(jnp.float32), mass.astype(jnp.float32),
-                a.astype(jnp.float32), w.astype(jnp.float32), self._edges,
-                use_pallas=True, interpret=self.interpret)
+                a.astype(jnp.float32), w.astype(jnp.float32), self._edges)
             return out + self._links_term(x, out.dtype)
 
         def lat_b(x, inter, degrade):
@@ -477,7 +460,7 @@ class BatchedEvaluator:
     # after the map, where per-scenario dq broadcasts over the grid.
     #
     # latency_f is carved out by name: it rides the evaluator's own edge
-    # machinery (which honors use_pallas and is already built per graph)
+    # machinery (which takes the route and is already built per graph)
     # instead of the spec's reference builders — a test pins the two routes
     # to the same oracle so they can't drift.
 
@@ -649,7 +632,7 @@ class BatchedEvaluator:
         regions = {"R": coms.n_regions} if structured else {}
         with obs.span("score_grid", S=S, P=P, path=path, multi=multi,
                       **regions) as sp:
-            if reg.enabled and self.use_pallas:
+            if reg.enabled and self._route.pallas:
                 sp.set(kernel_rows=self._kernel_rows(structured, P, V))
             placements, pack, dq_arr, beta = self._upload(
                 placements, coms, dq, beta, S, P)

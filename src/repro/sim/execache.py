@@ -14,7 +14,7 @@ The fix is an LRU of *callables* keyed by semantic identity:
   * the evaluator family — :func:`graph_key` (operator tuple + edge list,
     so separately-constructed but identical graphs collide on purpose),
     the frozen :class:`~repro.core.costmodel.CostConfig`, and the
-    ``use_pallas`` / ``interpret`` flags;
+    :class:`~repro.kernels.dispatch.Route` the evaluator was built on;
   * the entry point kind (dense grid, structured layout, multi-objective
     set, ...) plus whatever static state it closes over (region layout
     bytes, the hashable ``ObjectiveSet``).

@@ -185,7 +185,6 @@ def robust_placement(graph: OpGraph, scenarios: list[Scenario],
                      cfg: CostConfig = CostConfig(), beta: float = 0.0,
                      dq: float | np.ndarray = 0.0, sparsity: float = 0.5,
                      extra_candidates: list[np.ndarray] | None = None,
-                     use_pallas: bool | None = None,
                      objectives: ObjectiveSet | None = None):
     """Min–max what-if selection over a scenario batch — a
     signature-preserving delegator to
@@ -196,8 +195,7 @@ def robust_placement(graph: OpGraph, scenarios: list[Scenario],
 
     return impl(graph, scenarios, rng, n_candidates=n_candidates, cfg=cfg,
                 beta=beta, dq=dq, sparsity=sparsity,
-                extra_candidates=extra_candidates, use_pallas=use_pallas,
-                objectives=objectives)
+                extra_candidates=extra_candidates, objectives=objectives)
 
 
 def scenario_robust_search(graph: OpGraph, scenarios: list[Scenario],

@@ -27,6 +27,26 @@ def telemetry():
             obs.set_registry(saved)
 
 
+@contextlib.contextmanager
+def _interpreted_pallas():
+    from repro.kernels import dispatch
+
+    substituted = dispatch.Route(pallas=True, interpret=True)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(dispatch, "route", lambda: substituted)
+        yield substituted
+
+
+@pytest.fixture(scope="session")
+def pallas_route():
+    """``with pallas_route():`` substitutes the dispatch route with the
+    Pallas kernels, interpreted: evaluators built (and called) inside score
+    through the edge kernels on the CPU as they do compiled on a chip.
+    Session-scoped because it holds no state, so hypothesis tests can use
+    it too."""
+    return _interpreted_pallas
+
+
 @pytest.fixture
 def host_trace(tmp_path):
     """``with host_trace() as events:`` runs the block under the JAX

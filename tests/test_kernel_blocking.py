@@ -217,9 +217,10 @@ def test_structured_edge_list_matches_gathered_route(dag, shared, route):
     the bits the same route gives on rows gathered to edges
     (``x[src]·sel``, ``mass[dst]``, ``w[dst]``): selectivities off 1, V
     not a multiple of the lane width (padded u-columns masked), and row 0
-    with an outage-sized device term that sets its max."""
-    from repro.kernels.dispatch import edge_latency_structured
+    with an outage-sized device term that sets its max.  The ``xla`` route
+    is the jnp reference."""
     from repro.kernels.edge_latency import edge_list
+    from repro.kernels.ref import edge_latency_structured_ref
 
     n, src, dst = _DAGS[dag]
     B, V, R = 3, 300, 5
@@ -241,9 +242,8 @@ def test_structured_edge_list_matches_gathered_route(dag, shared, route):
         want = edge_latency_structured_pallas(x_i, mass[:, d], a, w[:, d],
                                               block_v=128, interpret=True)
     else:
-        got = edge_latency_structured(x, mass, a, w, edges, use_pallas=False)
-        want = edge_latency_structured(x_i, mass[:, d], a, w[:, d],
-                                       use_pallas=False)
+        got = edge_latency_structured_ref(x, mass, a, w, edges)
+        want = edge_latency_structured_ref(x_i, mass[:, d], a, w[:, d])
     assert got.shape == (B, len(src))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert int(np.argmax(np.asarray(x_i[0, 0]) * np.asarray(w[0, d[0]]))) \

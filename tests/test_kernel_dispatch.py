@@ -1,8 +1,6 @@
-"""The backend dispatch policy and the VMEM-aware autotuner: flag
-resolution (the serve/kernels interpret-default divergence fix), plan
-construction, decision-table caching and persistence, and end-to-end
-agreement of the consumers (BatchedEvaluator, WhatIfService) that now
-route through one policy."""
+"""The backend dispatch route and the VMEM-aware autotuner: the route per
+backend, plan construction, decision-table caching and persistence, and
+the consumers (BatchedEvaluator, WhatIfService) that share one route."""
 
 import json
 
@@ -12,12 +10,15 @@ import pytest
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core import RegionFleet
 from repro.core.graph import linear_graph
-from repro.kernels import autotune, dispatch
-from repro.kernels.autotune import KernelConfig, ShapeKey
+from repro.kernels import autotune, dispatch, ref
+from repro.kernels.autotune import ShapeKey
+from repro.kernels.edge_latency import edge_list
 from repro.obs.registry import MetricsRegistry, set_registry
 from repro.perf import roofline
-from repro.sim.batched import BatchedEvaluator, pack_fleets, pack_placements
+from repro.sim.batched import (BatchedEvaluator, pack_fleets,
+                               pack_placements, pack_region_fleets)
 from repro.serve.service import WhatIfService
 
 
@@ -41,69 +42,64 @@ def metrics():
 V5E = "TPU v5 lite"
 
 
-def _counter_total(reg, name):
-    return sum(r["value"] for r in reg.snapshot()
-               if r["name"] == name and r["type"] == "counter")
+# -- the route ----------------------------------------------------------------
 
-
-# -- resolve_flags policy -----------------------------------------------------
-
-def test_auto_flags_resolve_per_backend():
-    assert dispatch.resolve_flags(None, None, backend="cpu") == (False, True)
-    assert dispatch.resolve_flags(None, None, backend="tpu") == (True, False)
-
-
-def test_explicit_pallas_on_cpu_keeps_interpret():
-    assert dispatch.resolve_flags(True, None, backend="cpu") == (True, True)
-
-
-def test_compiled_on_cpu_is_coerced_to_interpret(metrics):
-    """The divergence fix's teeth: an explicit interpret=False on CPU
-    cannot survive resolution (compiled Pallas can't lower there) and the
-    coercion is observable."""
-    assert dispatch.resolve_flags(True, False, backend="cpu") == (True, True)
-    assert _counter_total(metrics, "kernels.dispatch.coerced") == 1
-
-
-def test_interpret_on_accelerator_is_honored_but_counted(metrics):
-    assert dispatch.resolve_flags(True, True, backend="tpu") == (True, True)
-    assert _counter_total(
-        metrics, "kernels.dispatch.interpret_on_accelerator") == 1
+@pytest.mark.parametrize("backend", ["cpu", "tpu", "gpu"])
+def test_auto_flags_resolve_per_backend(monkeypatch, backend):
+    """The route is the backend's alone: the jaxmodel twin with any Pallas
+    call interpreted on the CPU, compiled Pallas on an accelerator."""
+    monkeypatch.setattr(dispatch, "backend_name", lambda: backend)
+    on_cpu = backend == "cpu"
+    assert dispatch.route() == dispatch.Route(pallas=not on_cpu,
+                                              interpret=on_cpu)
 
 
 # -- plans --------------------------------------------------------------------
 
-def test_plan_auto_on_cpu_is_xla():
-    plan = dispatch.plan_edge_kernel("dense", 4, 24, 256, backend="cpu")
-    assert plan.impl == "xla" and plan.interpret and plan.config is None
-
-
 def test_plan_pallas_config_fits_vmem_budget(monkeypatch):
+    monkeypatch.setattr(dispatch, "backend_name", lambda: "tpu")
     monkeypatch.setattr(autotune, "local_device_kind", lambda backend: V5E)
-    plan = dispatch.plan_edge_kernel("dense", 4, 24, 8192, use_pallas=True,
-                                     backend="tpu")
-    assert plan.impl == "pallas" and not plan.interpret
+    plan = dispatch.plan_edge_kernel("dense", 4, 24, 8192)
+    assert not plan.interpret
     assert autotune.vmem_bytes("dense", 24, 8192, None, plan.config) \
         <= autotune.VMEM_BUDGET_BYTES
 
 
-def test_plan_pinned_blocks_bypass_autotuner():
-    plan = dispatch.plan_edge_kernel("dense", 4, 24, 1024, use_pallas=True,
-                                     backend="cpu", block_edges=64,
-                                     block_v=256)
-    assert plan.config == KernelConfig(block_edges=64, block_v=256)
-    assert autotune.table_rows() == []  # no decision was recorded
+@pytest.mark.parametrize("kind,R", [("dense", None), ("structured", 4)])
+def test_plans_take_the_table_and_are_counted(metrics, kind, R):
+    """A plan's blocks are the decision table's, and each plan is counted
+    by kind and by whether it runs interpreted (the CPU's route)."""
+    plan = dispatch.plan_edge_kernel(kind, 4, 24, 1024, R, n_ops=6)
+    assert plan.interpret
+    assert plan.config == autotune.get_config(kind, 4, 24, 1024, R,
+                                              n_ops=6)
+    rows = [r for r in metrics.snapshot()
+            if r["name"] == "kernels.dispatch.plans"]
+    assert [(r["labels"], r["value"]) for r in rows] == [
+        ({"kind": kind, "interpret": "True"}, 1)]
 
 
-def test_dispatch_routes_agree_numerically():
+@pytest.mark.parametrize("kind", ["dense", "structured"])
+def test_dispatch_routes_agree_numerically(kind):
+    """The dispatched kernel (interpreted here) against its jnp reference,
+    with a shared scenario and V off the lane width."""
     rng = np.random.default_rng(0)
-    xi = jnp.asarray(rng.standard_normal((2, 5, 300)), jnp.float32)
-    xj = jnp.asarray(rng.standard_normal((2, 5, 300)), jnp.float32)
-    com = jnp.asarray(rng.standard_normal((1, 300, 300)), jnp.float32)
-    xla = np.asarray(dispatch.edge_latency(xi, xj, com, use_pallas=False))
-    pal = np.asarray(dispatch.edge_latency(xi, xj, com, use_pallas=True,
-                                           interpret=True))
-    np.testing.assert_allclose(pal, xla, rtol=1e-5, atol=1e-5)
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    if kind == "dense":
+        xi, xj, com = arr(2, 5, 300), arr(2, 5, 300), arr(1, 300, 300)
+        got = dispatch.edge_latency(xi, xj, com)
+        want = ref.edge_latency_ref(xi, xj, com)
+    else:
+        x, mass, a, w = arr(2, 4, 300), arr(2, 4, 5), arr(1, 5, 300), \
+            arr(2, 4, 300)
+        edges = edge_list([0, 0, 1, 2], [1, 2, 3, 3], [0.5, 1.0, 2.0, 1.5])
+        got = dispatch.edge_latency_structured(x, mass, a, w, edges)
+        want = ref.edge_latency_structured_ref(x, mass, a, w, edges)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 # -- autotuner ----------------------------------------------------------------
@@ -202,30 +198,38 @@ def test_shape_key_buckets_batch():
     assert ShapeKey.of("cpu", "dense", 1, 24, 64, None).b_bucket == 1
 
 
-# -- consumers agree through the one policy -----------------------------------
+# -- consumers share the one route --------------------------------------------
 
 def test_evaluator_and_service_resolve_to_same_flags():
-    """The interpret-default divergence fix: a default-constructed service
-    and a default-constructed shared evaluator land on the SAME concrete
-    flags (and therefore the same executables / coalesce keys)."""
+    """The service scores through the process-shared evaluator, so the
+    serving layer and the sim layer run the same executables."""
     g = linear_graph([1.0, 1.0, 1.0])
     svc = WhatIfService(g)
     ev = BatchedEvaluator.shared(g)
-    assert isinstance(svc.use_pallas, bool)
-    assert isinstance(svc.interpret, bool)
-    assert (svc.use_pallas, svc.interpret) == (ev.use_pallas, ev.interpret)
     assert svc._ev is ev  # literally the same shared instance
 
 
-def test_shared_memo_key_uses_resolved_flags():
+def test_substituted_route_gets_its_own_evaluator(pallas_route):
+    """An evaluator built under a substituted route is its own shared
+    instance with its own executables, and never the default's."""
     g = linear_graph([1.0, 1.0])
-    auto = BatchedEvaluator.shared(g)
-    concrete = BatchedEvaluator.shared(g, use_pallas=auto.use_pallas,
-                                       interpret=auto.interpret)
-    assert auto is concrete
+    fam = pack_region_fleets([RegionFleet(np.array([0, 0, 1]),
+                                          np.array([[0.0, 1.0],
+                                                    [1.0, 0.0]]))])
+    default = BatchedEvaluator.shared(g)
+    with pallas_route() as substituted_route:
+        substituted = BatchedEvaluator.shared(g)
+        assert BatchedEvaluator.shared(g) is substituted
+        assert substituted._route == substituted_route
+        substituted_structured = substituted._structured(fam)
+    assert substituted is not default
+    assert default._route == dispatch.route()
+    assert substituted._jit_grid is not default._jit_grid
+    assert substituted_structured is not default._structured(fam)
+    assert BatchedEvaluator.shared(g) is default
 
 
-def test_evaluator_pallas_path_matches_jnp_path():
+def test_evaluator_pallas_path_matches_jnp_path(pallas_route):
     from repro.core import ExplicitFleet
     rng = np.random.default_rng(5)
     g = linear_graph([1.0, 0.5, 2.0, 1.5])
@@ -234,15 +238,13 @@ def test_evaluator_pallas_path_matches_jnp_path():
     np.fill_diagonal(com, 0.0)
     coms = pack_fleets([ExplicitFleet(com_cost=com)])
     xs = pack_placements([rng.uniform(0, 1, (4, 6)) for _ in range(3)])
-    jnp_grid = np.asarray(BatchedEvaluator(g, use_pallas=False)
-                          .score_grid(xs, coms))
-    pal_grid = np.asarray(
-        BatchedEvaluator(g, use_pallas=True, interpret=True)
-        .score_grid(xs, coms))
+    jnp_grid = np.asarray(BatchedEvaluator(g).score_grid(xs, coms))
+    with pallas_route():
+        pal_grid = np.asarray(BatchedEvaluator(g).score_grid(xs, coms))
     np.testing.assert_allclose(pal_grid, jnp_grid, rtol=1e-5, atol=1e-6)
 
 
-def test_dense_score_grid_span_counts_kernel_rows(telemetry):
+def test_dense_score_grid_span_counts_kernel_rows(telemetry, pallas_route):
     """On the Pallas route the ``score_grid`` span records ``kernel_rows``,
     the V-sized rows a placement row costs the edge kernel: the dense
     kernel reads the two padded per-edge endpoint rows, 2·e_pad."""
@@ -254,8 +256,9 @@ def test_dense_score_grid_span_counts_kernel_rows(telemetry):
     coms = pack_fleets([ExplicitFleet(com_cost=rng.uniform(0.1, 2.0,
                                                            (6, 6)))])
     xs = pack_placements([rng.uniform(0, 1, (4, 6)) for _ in range(3)])
-    BatchedEvaluator(g, use_pallas=True, interpret=True).score_grid(xs, coms)
-    BatchedEvaluator(g, use_pallas=False).score_grid(xs, coms)
+    with pallas_route():
+        BatchedEvaluator(g).score_grid(xs, coms)
+    BatchedEvaluator(g).score_grid(xs, coms)
     cfg = autotune.get_config("dense", 3, g.n_edges, 6)
     e_pad = block_geometry("dense", g.n_edges, 6, None, cfg.block_edges,
                            cfg.block_v).e_pad

@@ -169,7 +169,7 @@ def test_kernel_roofline_terms_finite():
     assert t.dominant in ("compute", "memory")
 
 
-def test_structured_score_grid_writes_no_per_edge_rows():
+def test_structured_score_grid_writes_no_per_edge_rows(pallas_route):
     """A compiled structured score_grid (the Pallas route, interpreted)
     holds no float32 buffer of per-edge rows, (·, E, V) or (·, e_pad, V):
     the edge kernel reads per-operator rows and gathers to edges itself
@@ -189,8 +189,8 @@ def test_structured_score_grid_writes_no_per_edge_rows():
     E, n = graph.n_edges, graph.n_ops
     e_pad = -(-E // SUBLANE) * SUBLANE
     assert n not in (E, e_pad)  # operator rows are told from edge rows
-    with fresh_cache():
-        ev = BatchedEvaluator(graph, use_pallas=True, interpret=True)
+    with fresh_cache(), pallas_route():
+        ev = BatchedEvaluator(graph)
         args = [jax.ShapeDtypeStruct(s, jnp.float32)
                 for s in ((P, n, V), (S, R, R), (S, V), (), ())]
         text = jax.jit(ev._structured(fam).grid).lower(*args).compile() \

@@ -136,6 +136,28 @@ def test_equal_fleets_coalesce_across_tenants():
     assert snap["buckets"][0]["rows"] == 8
 
 
+def test_queues_key_on_fleet_and_objectives():
+    """A service's queues are keyed by what separates them: the fleet's
+    content digest and the objective set (the service owns one graph and
+    one CostConfig, and the kernel route is the evaluator's own)."""
+    import dataclasses
+
+    from repro.serve import CoalesceKey
+
+    assert [f.name for f in dataclasses.fields(CoalesceKey)] == [
+        "fleet", "objectives"]
+    g, coms, placements = _setup()
+    svc = WhatIfService(g, admission=RELAXED)
+    plain = svc.register_fleet("a", coms)
+    multi = svc.register_fleet("b", coms, objectives=OBJ2)
+    for tenant, fid in (("a", plain), ("b", multi), ("c", plain)):
+        svc.submit(tenant, fid, WhatIfQuery(kind="score",
+                                            placements=placements(2)))
+    assert {k: len(v) for k, v in svc._queues.items()} == {
+        CoalesceKey(fleet=plain, objectives=None): 2,
+        CoalesceKey(fleet=multi, objectives=OBJ2): 1}
+
+
 def test_multi_objective_grids_parity():
     """Multi-objective serving finishes dq/β in the shared dispatch: every
     per-objective grid and the scalarization are bitwise a direct

@@ -88,15 +88,17 @@ def test_batched_matches_oracle(inst):
 
 @given(instances())
 @settings(max_examples=10, deadline=None)
-def test_pallas_path_matches_jnp_path(inst):
-    """use_pallas=True (interpret) produces the same grid as the jnp path."""
+def test_pallas_path_matches_jnp_path(pallas_route, inst):
+    """The Pallas route (interpreted) produces the same grid as the jnp
+    path."""
     g, fleets, xs, cfg, _ = inst
     coms = pack_fleets(fleets)
     P = pack_placements(xs)
     a = np.asarray(BatchedEvaluator(g, cfg).score_grid(P, coms, beta=0.5,
                                                        dq=0.5))
-    b = np.asarray(BatchedEvaluator(g, cfg, use_pallas=True, interpret=True)
-                   .score_grid(P, coms, beta=0.5, dq=0.5))
+    with pallas_route():
+        b = np.asarray(BatchedEvaluator(g, cfg)
+                       .score_grid(P, coms, beta=0.5, dq=0.5))
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
 
 
@@ -105,14 +107,14 @@ def test_pallas_kernel_against_ref():
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import ops, ref
+    from repro.kernels import dispatch, ref
 
     rng = np.random.default_rng(0)
     for B, E, V in [(1, 1, 2), (3, 7, 5), (2, 128, 16), (4, 33, 12)]:
         xi = jnp.asarray(rng.random((B, E, V)), jnp.float32)
         xj = jnp.asarray(rng.random((B, E, V)), jnp.float32)
         com = jnp.asarray(rng.random((B, V, V)), jnp.float32)
-        out = ops.edge_latency_max(xi, xj, com, interpret=True)
+        out = dispatch.edge_latency(xi, xj, com)
         # one batched device→host transfer per shape, not one per operand
         got, want = jax.device_get((out, ref.edge_latency_ref(xi, xj, com)))
         np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)

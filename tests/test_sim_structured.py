@@ -3,6 +3,8 @@ BatchedEvaluator) against the float64 numpy oracle, on both the vmap and
 Pallas routes, including ``alpha > 0`` and the shared-family (S == 1)
 broadcast case — plus the family pack/generator contracts."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -59,7 +61,7 @@ def instances(draw):
                      draw(st.sampled_from([False, True])))
 
 
-def _instance(seed, alpha, use_pallas):
+def _instance(seed, alpha, pallas):
     rng = np.random.default_rng(seed)
     n_ops = int(rng.integers(2, 8))
     n_dev = int(rng.integers(2, 9))
@@ -68,7 +70,7 @@ def _instance(seed, alpha, use_pallas):
     xs = [random_placement(n_ops, np.ones((n_ops, n_dev), bool), rng,
                            sparsity=float(rng.uniform(0.0, 0.7)))
           for _ in range(int(rng.integers(1, 5)))]
-    return g, fleets, xs, CostConfig(alpha=alpha), use_pallas
+    return g, fleets, xs, CostConfig(alpha=alpha), pallas
 
 
 @given(instances())
@@ -76,25 +78,26 @@ def _instance(seed, alpha, use_pallas):
 # both ends of an edge on one device of a two-device region: the oracle
 # reads 0.0 where a self-pair added and subtracted again left 2.45e-06
 @example(inst=_instance(31834, 0.0, False))
-def test_structured_matches_oracle(inst):
+def test_structured_matches_oracle(pallas_route, inst):
     """score_grid / latency / edge_latencies over a RegionFleetFamily ==
     numpy oracle to ≤1e-5 relative, vmap AND Pallas routes, alpha 0/>0."""
-    g, fleets, xs, cfg, use_pallas = inst
+    g, fleets, xs, cfg, pallas = inst
     fam = pack_region_fleets(fleets)
-    ev = BatchedEvaluator(g, cfg, use_pallas=use_pallas, interpret=True)
     P = pack_placements(xs)
     beta, dq = 0.7, 0.3
-    grid = np.asarray(ev.score_grid(P, fam, dq=dq, beta=beta))
+    b = len(fleets)
+    xb = np.stack([xs[0]] * b)
+    with pallas_route() if pallas else contextlib.nullcontext():
+        ev = BatchedEvaluator(g, cfg)
+        grid = np.asarray(ev.score_grid(P, fam, dq=dq, beta=beta))
+        el = np.asarray(ev.edge_latencies(xb, fam))
+        lat = np.asarray(ev.latency(xb, fam))
     assert grid.shape == (len(fleets), len(xs))
     for si, fleet in enumerate(fleets):
         for pi, x in enumerate(xs):
             want = objective_F(latency(g, fleet, x, cfg), dq, beta)
             assert grid[si, pi] == pytest.approx(want, rel=REL, abs=1e-6)
     # per-edge + latency agreement on the first placement across every fleet
-    b = len(fleets)
-    xb = np.stack([xs[0]] * b)
-    el = np.asarray(ev.edge_latencies(xb, fam))
-    lat = np.asarray(ev.latency(xb, fam))
     for si, fleet in enumerate(fleets):
         np.testing.assert_allclose(
             el[si], edge_latencies(g, fleet, xs[0], cfg), rtol=REL, atol=1e-6)
@@ -104,13 +107,14 @@ def test_structured_matches_oracle(inst):
 
 @given(instances())
 @settings(**SETTINGS)
-def test_structured_shared_family_broadcast(inst):
+def test_structured_shared_family_broadcast(pallas_route, inst):
     """An S == 1 family broadcasts against a placement batch exactly like a
     (1, V, V) dense com — on both routes."""
-    g, fleets, xs, cfg, use_pallas = inst
+    g, fleets, xs, cfg, pallas = inst
     fam1 = pack_region_fleets(fleets[:1])
-    ev = BatchedEvaluator(g, cfg, use_pallas=use_pallas, interpret=True)
-    lat = np.asarray(ev.latency(pack_placements(xs), fam1))
+    with pallas_route() if pallas else contextlib.nullcontext():
+        lat = np.asarray(BatchedEvaluator(g, cfg).latency(
+            pack_placements(xs), fam1))
     assert lat.shape == (len(xs),)
     for pi, x in enumerate(xs):
         assert lat[pi] == pytest.approx(latency(g, fleets[0], x, cfg),
@@ -140,7 +144,7 @@ def test_structured_kernel_against_ref():
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.ops import edge_latency_structured_max
+    from repro.kernels.dispatch import edge_latency_structured
 
     rng = np.random.default_rng(0)
     for B, E, V, R, Bc in [(1, 1, 2, 1, 1), (3, 7, 5, 2, 3),
@@ -149,7 +153,7 @@ def test_structured_kernel_against_ref():
         mass = jnp.asarray(rng.random((B, E, R)), jnp.float32)
         a = jnp.asarray(rng.random((Bc, R, V)), jnp.float32)
         w = jnp.asarray(rng.random((B, E, V)), jnp.float32)
-        out = edge_latency_structured_max(xi, mass, a, w, interpret=True)
+        out = edge_latency_structured(xi, mass, a, w)
         # one batched device→host transfer per shape, not one per operand
         out_h, xi_h, mass_h, a_h, w_h = jax.device_get(
             (out, xi, mass, a, w))

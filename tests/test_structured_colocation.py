@@ -11,6 +11,8 @@ the batched evaluator, the scalar twin and the weighted network movement
 to the float64 oracle, and show that the replaced formulation, rebuilt
 here, misses them."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -93,19 +95,19 @@ def _rel(got, want) -> float:
     return float(np.max(np.abs(got - want) / np.abs(want)))
 
 
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["vmap", "pallas"])
+@pytest.mark.parametrize("pallas", [False, True], ids=["vmap", "pallas"])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_colocated_edges_match_oracle(case, use_pallas):
+def test_colocated_edges_match_oracle(case, pallas, pallas_route):
     """Edge latencies, latency and the score grid at 1e-5 relative to the
     float64 oracle, on the vmap route and the Pallas route (interpret)."""
     g, fleets, xs = _case(CASES[case])
     fam = pack_region_fleets(fleets)
-    ev = BatchedEvaluator(g, CostConfig(), use_pallas=use_pallas,
-                          interpret=True)
     xb = np.stack([xs[0]] * len(fleets))
-    el = np.asarray(ev.edge_latencies(xb, fam))
-    lat = np.asarray(ev.latency(xb, fam))
-    grid = np.asarray(ev.score_grid(pack_placements(xs), fam))
+    with pallas_route() if pallas else contextlib.nullcontext():
+        ev = BatchedEvaluator(g, CostConfig())
+        el = np.asarray(ev.edge_latencies(xb, fam))
+        lat = np.asarray(ev.latency(xb, fam))
+        grid = np.asarray(ev.score_grid(pack_placements(xs), fam))
     for s, fleet in enumerate(fleets):
         assert _rel(el[s], edge_latencies(g, fleet, xs[0])) <= REL
         assert _rel(lat[s], latency(g, fleet, xs[0])) <= REL
@@ -182,7 +184,7 @@ def test_colocated_weighted_movement_matches_oracle(case):
             assert _rel(got, want) <= REL
 
 
-def test_structured_span_and_scope(telemetry):
+def test_structured_span_and_scope(telemetry, pallas_route):
     """The ``score_grid`` span carries ``R`` on the structured path, and
     on the Pallas route ``kernel_rows``: the edge kernel reads x and w per
     operator, 2·n_ops V-sized rows a placement row.  The region terms'
@@ -194,13 +196,13 @@ def test_structured_span_and_scope(telemetry):
     g, fleets, xs = _case(1e4)
     fam = pack_region_fleets(fleets)
     P = pack_placements(xs[:1])
-    for use_pallas in (False, True):
-        ev = BatchedEvaluator(g, CostConfig(), use_pallas=use_pallas,
-                              interpret=True)
-        ev.score_grid(P, fam)
-        args = (P, *ev._family_args(fam), np.zeros((2, 1), np.float32),
-                np.float32(0.0))
-        hlo = jax.jit(ev._structured(fam).grid).lower(*args).compile()
+    for route in (contextlib.nullcontext, pallas_route):
+        with route():
+            ev = BatchedEvaluator(g, CostConfig())
+            ev.score_grid(P, fam)
+            args = (P, *ev._family_args(fam), np.zeros((2, 1), np.float32),
+                    np.float32(0.0))
+            hlo = jax.jit(ev._structured(fam).grid).lower(*args).compile()
         assert "region.terms" in hlo.as_text()
     spans = [e["args"] for e in obs.trace_events()
              if e["name"] == "score_grid"]

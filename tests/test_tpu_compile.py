@@ -138,8 +138,8 @@ def test_dense_score_grid_compiles(tpu_dispatch, smoke):
     of V = 4096 devices, with dq per cell and β per placement."""
     cs, graph = smoke
     full = cs.FULL
+    assert dispatch.route() == dispatch.Route(pallas=True, interpret=False)
     ev = BatchedEvaluator(graph)
-    assert (ev.use_pallas, ev.interpret) == (True, False)
     P, S = full.chunk_rows, full.scenarios
     args = _shapes(tpu_dispatch, (P, graph.n_ops, full.dense_devices),
                    (S, full.dense_devices, full.dense_devices), (S, P), (P,))
