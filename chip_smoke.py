@@ -20,6 +20,9 @@ and then serves the same queries again warm.  It checks that
     the float64 oracle in ``repro.core.costmodel`` within ``ORACLE_RTOL``;
   * the edge kernels ran as compiled Pallas: ``kernels.dispatch.plans``
     with ``interpret=False`` is positive, and no plan was interpreted;
+  * every structured dispatch, served or direct, took the region terms
+    from its rows' nonzeros: ``eval.region_terms`` counts ``sparse`` and
+    no ``dense``;
   * the structured edge kernel, given per-operator rows and the edge
     list, agrees with ``ref.edge_latency_structured_ref`` given rows
     gathered to edges, on one scenario's region terms at the deployment's
@@ -351,6 +354,16 @@ def dispatch_counts(reg) -> dict[str, float]:
     }
 
 
+def region_terms_routes(reg) -> dict[str, float]:
+    """Structured dispatches by the operand their region terms came from:
+    ``sparse`` (the rows' nonzeros) or ``dense``."""
+    rows = [r for r in reg.snapshot() if r["type"] == "counter"
+            and r["name"] == "eval.region_terms"]
+    return {route: sum(r["value"] for r in rows
+                       if r["labels"]["route"] == route)
+            for route in ("sparse", "dense")}
+
+
 def run_phase(svc, graph, dep: Deployment, seed: int, shape: Shape,
               log=print) -> dict:
     """Cold and warm serving of one deployment, then every check."""
@@ -415,6 +428,11 @@ def main(argv: list[str] | None = None) -> int:
             and counts["pallas_interpreted_plans"] == 0):
         raise AssertionError(f"edge kernels did not run as compiled "
                              f"Pallas: {counts}")
+    routes = region_terms_routes(obs.registry())
+    print("smoke region_terms " + json.dumps(routes))
+    if not (routes["sparse"] > 0 and routes["dense"] == 0):
+        raise AssertionError(f"structured dispatches did not all take the "
+                             f"sparse region terms: {routes}")
     mem = dev.memory_stats() or {}
     print(f"smoke peak_bytes_in_use={mem.get('peak_bytes_in_use')}")
     print(json.dumps({"ok": True, "device": {

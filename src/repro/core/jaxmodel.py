@@ -30,7 +30,8 @@ from repro.core.graph import OpGraph
 __all__ = ["SmoothConfig", "make_latency_fn", "make_objective_fn",
            "make_edge_latencies_com_fn", "make_latency_com_fn",
            "make_edge_latencies_region_fn", "make_latency_region_fn",
-           "region_a_off", "region_own", "region_terms", "region_times",
+           "region_a_off", "region_own", "region_terms",
+           "region_terms_sparse", "region_times",
            "critical_path_dp"]
 
 
@@ -278,6 +279,65 @@ def region_terms(x_j: jnp.ndarray, degrade: jnp.ndarray, own: jnp.ndarray,
     return mass, own * loo + self_cost * x_j
 
 
+#: the most slots :func:`region_terms_sparse` takes: each is selected into
+#: ``w`` elementwise, O(k) a device; wider rows take :func:`region_terms`
+SELECT_SLOTS = 16
+
+
+def _slot_sum(v: jnp.ndarray, reg: jnp.ndarray, n_regions: int):
+    """(..., k) slot entries summed by their region ``reg`` (..., k) into
+    (..., R), slot after slot: a row's sums are the same bits whatever
+    rows ride with it.  A slot of region ``n_regions`` adds nothing."""
+    hit = jnp.where(reg[..., None] == jnp.arange(n_regions), v[..., None],
+                    0.0)                                   # (..., k, R)
+    return jax.lax.fori_loop(
+        0, v.shape[-1], lambda s, acc: acc + hit[..., s, :],
+        jnp.zeros(v.shape[:-1] + (n_regions,), v.dtype), unroll=8)
+
+
+def region_terms_sparse(idx: jnp.ndarray, val: jnp.ndarray,
+                        degrade: jnp.ndarray, own: jnp.ndarray, region_ix,
+                        n_regions: int, self_cost: float, per_device=None):
+    """:func:`region_terms` of rows given as their nonzeros: ``idx``
+    (..., k) device indices, ``V`` in an unused slot, and ``val`` (..., k)
+    the entries.  The sums run over the k slots, so only ``w`` (..., V) is
+    dense: off the support ``loo_u`` is the whole region mass, so
+    ``w = own · per_device(mass)`` there, and the support's entries are
+    then set by the formula of :func:`region_terms`.  Bitwise the dense
+    terms where no two support devices of a row share a region (elsewhere
+    a region's sum adds its terms in another order).  ``per_device`` as
+    for :func:`region_terms`; at most ``SELECT_SLOTS`` slots."""
+    V, k = own.shape[-1], idx.shape[-1]
+    if k > SELECT_SLOTS:
+        raise ValueError(f"{k} slots: the sparse region terms take at most "
+                         f"{SELECT_SLOTS}")
+    if per_device is None:
+        def per_device(m):
+            return m[..., region_ix]
+    take = partial(jnp.take, mode="fill")
+    reg = take(jnp.asarray(region_ix), idx, fill_value=n_regions)
+    dj = take(degrade, idx, fill_value=0) * val
+    mass = _slot_sum(dj, reg, n_regions)
+
+    def at_slots(m):
+        return jnp.take_along_axis(m, reg, axis=-1, mode="fill",
+                                   fill_value=0)
+
+    mass_u = at_slots(mass)
+    dom = dj > DOMINANT_SHARE * mass_u
+    rest = _slot_sum(jnp.where(dom, 0.0, dj), reg, n_regions)
+    loo = jnp.where(dom, at_slots(rest), mass_u - dj)
+    support = take(own, idx, fill_value=0) * loo + self_cost * val
+    w = own * per_device(mass)
+    # each slot's entry selected in by its device index: elementwise, so
+    # XLA fuses it into the matmul's one write of w (a scatter relays w
+    # out to a linear layout and back on a TPU)
+    device = jnp.arange(V, dtype=idx.dtype)
+    for s in range(k):
+        w = jnp.where(device == idx[..., s, None], support[..., s, None], w)
+    return mass, w
+
+
 def region_times(mass: jnp.ndarray, w: jnp.ndarray, inter: jnp.ndarray,
                  degrade: jnp.ndarray, region_ix) -> jnp.ndarray:
     """``t = mass @ a_off + w`` (..., V) in XLA, each row on its own: the
@@ -294,11 +354,13 @@ def make_edge_latencies_region_fn(graph: OpGraph, region: np.ndarray,
                                   n_regions: int, self_cost: float = 0.0,
                                   cfg: SmoothConfig = SmoothConfig(),
                                   nz_eps: float = 0.0):
-    """Returns ``elat(x, inter, degrade) -> (E,)`` — the structured twin of
-    :func:`make_edge_latencies_com_fn`.
+    """Returns ``elat(x, inter, degrade, sparse=None) -> (E,)`` — the
+    structured twin of :func:`make_edge_latencies_com_fn`.
 
     ``region``/``n_regions``/``self_cost`` are static family structure;
-    ``inter`` (R, R) and ``degrade`` (V,) are traced per-scenario state.
+    ``inter`` (R, R) and ``degrade`` (V,) are traced per-scenario state;
+    ``sparse``, where given, is x's nonzeros ``(idx, val)`` (n_ops, k),
+    and the region terms come from them (:func:`region_terms_sparse`).
     Hard-max only; matches the numpy oracle on the equivalent RegionFleet.
     """
     src, dst, sel = _edge_arrays(graph)
@@ -308,15 +370,20 @@ def make_edge_latencies_region_fn(graph: OpGraph, region: np.ndarray,
     region_ix = jnp.asarray(np.asarray(region, dtype=np.int64))
     alpha = cfg.alpha
 
-    def elat(x: jnp.ndarray, inter: jnp.ndarray,
-             degrade: jnp.ndarray) -> jnp.ndarray:
+    def elat(x: jnp.ndarray, inter: jnp.ndarray, degrade: jnp.ndarray,
+             sparse=None) -> jnp.ndarray:
         degrade = degrade.astype(x.dtype)
         # every edge into operator j shares j's terms: price per operator
         # (n_ops, V), then gather per edge
         with jax.named_scope("region.terms"):
             own = region_own(inter.astype(x.dtype), degrade, region_ix)
-            mass, w = region_terms(x, degrade, own, region_ix, n_regions,
-                                   self_cost)
+            if sparse is None:
+                mass, w = region_terms(x, degrade, own, region_ix,
+                                       n_regions, self_cost)
+            else:
+                mass, w = region_terms_sparse(*sparse, degrade, own,
+                                              region_ix, n_regions,
+                                              self_cost)
             t = region_times(mass, w, inter.astype(x.dtype), degrade,
                              region_ix)[dst_j]           # (E, V)
         out = jnp.max(x[src_j] * sel_j[:, None] * t, axis=1)   # (E,)

@@ -55,7 +55,7 @@ from repro.serve.bucketing import (CoalesceKey, fleet_digest, next_pow2,
                                    pad_rows)
 from repro.serve.cache import ServeStats
 from repro.sim.batched import (BatchedEvaluator, SparsePlacements,
-                               sparse_placements)
+                               sparse_placements, takes_sparse_terms)
 
 __all__ = ["WhatIfQuery", "QueryTicket", "ResultChunk", "QueryResult",
            "WhatIfService"]
@@ -367,7 +367,7 @@ class WhatIfService:
     # -- the serving loop (coalesce → pad → dispatch → stream) ---------------
     def step(self) -> int:
         """Serve the oldest non-empty coalesce queue: merge its pending
-        queries into one super-batch, dispatch it in ≤max_chunk_rows
+        queries into a super-batch, dispatch it in ≤max_chunk_rows
         power-of-two chunks, stream each chunk's finished scores to tenant
         mailboxes, finalize completed queries.  Returns the number of
         queries completed (0 = nothing pending)."""
@@ -376,6 +376,17 @@ class WhatIfService:
             return 0
         queue = self._queues.pop(key)
         fleet = next(f for f in self._fleets.values() if f.key == key)
+        # a region fleet's rows that take the sparse region terms and rows
+        # that take the dense ones (takes_sparse_terms) are super-batches
+        # of their own, so a query's chunks take the route a direct
+        # score_grid of its rows takes
+        return sum(self._serve_batch(fleet, batch) for batch in (
+            [p for p in queue if takes_sparse_terms(p.sparse)],
+            [p for p in queue if not takes_sparse_terms(p.sparse)])
+            if batch)
+
+    def _serve_batch(self, fleet: _Fleet, queue: list[_Pending]) -> int:
+        """``step`` for one super-batch of one queue's queries."""
         # (query, rows) spans inside the super-batch, in queue order
         spans, off = [], 0
         for p in queue:
@@ -438,7 +449,10 @@ class WhatIfService:
                   live: list[tuple[_Pending, slice]]):
         """The chunk's rows padded to ``bucket`` (as SparsePlacements
         where every live query has them), each query's columns carrying
-        its own dq/β (joint and padding columns 0)."""
+        its own dq/β (joint and padding columns 0).  A region fleet's
+        dense rows go to the device here: score_grid then keeps the dense
+        region terms its queries were batched for, and does not scan a
+        chunk of them for nonzeros again."""
         with obs.span("serve.assemble", bucket=bucket):
             if all(p.sparse is not None for p, _ in live):
                 padded = SparsePlacements.concat(
@@ -450,6 +464,8 @@ class WhatIfService:
                     [p.placements[p.done_rows:
                                   p.done_rows + sl.stop - sl.start]
                      for p, sl in live]), bucket)
+                if isinstance(fleet.pack, RegionFleetFamily):
+                    padded = jnp.asarray(padded)
             dq = np.zeros((fleet.n_scenarios, bucket), np.float32)
             beta = np.zeros(bucket, np.float32)
             for p, sl in live:

@@ -54,7 +54,8 @@ from repro.core.devices import ExplicitFleet, RegionFleet, RegionFleetFamily
 from repro.core.graph import OpGraph
 from repro.core.jaxmodel import (SmoothConfig, _edge_arrays,
                                  critical_path_dp, region_a_off, region_own,
-                                 region_terms,
+                                 SELECT_SLOTS, region_terms,
+                                 region_terms_sparse,
                                  make_edge_latencies_com_fn,
                                  make_edge_latencies_region_fn)
 from repro.core.objectives import (ObjectiveGrids, ObjectiveSet,
@@ -65,7 +66,7 @@ from repro.kernels.region_sum import region_sum_pallas
 
 __all__ = ["BatchedEvaluator", "SparsePlacements", "pack_fleets",
            "pack_placements", "pack_region_fleets", "pack_speeds",
-           "sparse_placements"]
+           "sparse_placements", "takes_sparse_terms"]
 
 # instance memo behind BatchedEvaluator.shared(): one evaluator per
 # (graph content, cfg, dispatch route), so independent consumers (search
@@ -118,7 +119,8 @@ class SparsePlacements:
     (P, n_ops, k) float32 masses, unused slots at index ``n_devices``.  A
     row over a few devices of a 10⁵-device fleet is then ``8·k`` bytes an
     operator instead of ``4·V``; ``score_grid`` rebuilds the dense rows on
-    the device, bit for bit."""
+    the device, bit for bit, and on a region fleet prices the region terms
+    from the nonzeros themselves."""
 
     idx: np.ndarray
     val: np.ndarray
@@ -189,6 +191,16 @@ def sparse_placements(x) -> SparsePlacements | None:
     return SparsePlacements(idx.reshape(P, n, k), val.reshape(P, n, k), V)
 
 
+def takes_sparse_terms(placements) -> bool:
+    """Whether a region fleet's :meth:`BatchedEvaluator.score_grid` of
+    ``placements`` prices the region terms from the rows' nonzeros: rows
+    given as :class:`SparsePlacements` of at most ``SELECT_SLOTS`` (16)
+    slots.  Other rows take the dense terms.  The one rule of every caller,
+    the service's super-batches included."""
+    return (isinstance(placements, SparsePlacements)
+            and placements.idx.shape[2] <= SELECT_SLOTS)
+
+
 @functools.partial(jax.jit, static_argnames="n_devices")
 def _densify(idx: jnp.ndarray, val: jnp.ndarray,
              n_devices: int) -> jnp.ndarray:
@@ -211,6 +223,26 @@ def pack_speeds(fleets: list[Fleet], dtype=jnp.float32) -> jnp.ndarray:
     if len(shapes) != 1:
         raise ValueError(f"fleets disagree on device count: {sorted(shapes)}")
     return jnp.asarray(np.stack(sp), dtype=dtype)
+
+
+def _region_ops(region_ix, n_regions: int, interpret: bool):
+    """The Pallas route's ``segment_sum`` and ``per_device`` for
+    :func:`repro.core.jaxmodel.region_terms`: the region sums in the Pallas
+    region-sum kernel (a row's bits do not depend on its batch; a
+    scatter-add over V costs about as much whatever its rows), the
+    per-device gather as a matmul with the layout's one-hot, exact at
+    HIGHEST (one product per output, by a one) and faster than the gather
+    on a v5e."""
+    onehot = (jnp.arange(n_regions)[:, None]
+              == region_ix[None, :]).astype(jnp.float32)
+
+    def segment_sum(v):
+        return region_sum_pallas(v, onehot, interpret=interpret)
+
+    def per_device(m):
+        return jnp.matmul(m, onehot, precision=jax.lax.Precision.HIGHEST)
+
+    return segment_sum, per_device
 
 
 def _host_bytes(pairs) -> int:
@@ -379,65 +411,64 @@ class BatchedEvaluator:
             SmoothConfig(alpha=self.cfg.alpha), nz_eps=self.cfg.nz_eps)
         region_ix = jnp.asarray(np.asarray(region, dtype=np.int64))
 
-        def elat_b(x, inter, degrade):
-            """x (B, n, V); inter (Sb, R, R), degrade (Sb, V), Sb ∈ {1, B}."""
+        def elat_b(x, inter, degrade, sparse=None):
+            """x (B, n, V); inter (Sb, R, R), degrade (Sb, V), Sb ∈ {1, B};
+            ``sparse``, where given, x's nonzeros ``(idx, val)`` (B, n, k):
+            the region terms then come from them, on either route."""
             if not self._route.pallas:
                 if inter.shape[0] == 1 and x.shape[0] != 1:
-                    return jax.vmap(elat_single, in_axes=(0, None, None))(
-                        x, inter[0], degrade[0])           # (B, E)
-                return jax.vmap(elat_single)(x, inter, degrade)
+                    return jax.vmap(elat_single,
+                                    in_axes=(0, None, None, 0))(
+                        x, inter[0], degrade[0], sparse)   # (B, E)
+                return jax.vmap(elat_single)(x, inter, degrade, sparse)
             # Pallas route: the region terms per operator, by the same
-            # jaxmodel.region_terms as the vmap route, on the MXU: the
-            # region sums in the Pallas region-sum kernel (a row's bits
-            # do not depend on its batch; a scatter-add over V costs about
-            # as much whatever its rows), the per-device gathers as matmuls
-            # with the layout's one-hot, exact at HIGHEST (one product per
-            # output, by a one) and faster than the gather on a v5e; the
-            # edge kernel takes the per-operator rows with the edge list
-            # and fuses t = mass @ a_off + w, the gather to edges and the
-            # row max, so no (B, E, V) copy is written
+            # jaxmodel.region_terms(_sparse) as the vmap route, on the MXU
+            # (_region_ops); the edge kernel takes the per-operator rows
+            # with the edge list and fuses t = mass @ a_off + w, the gather
+            # to edges and the row max, so no (B, E, V) copy is written
             with jax.named_scope("region.terms"):
-                onehot = (jnp.arange(n_regions)[:, None]
-                          == region_ix[None, :]).astype(jnp.float32)
+                segment_sum, per_device = _region_ops(
+                    region_ix, n_regions, self._route.interpret)
 
-                def segment_sum(v):
-                    return region_sum_pallas(
-                        v, onehot, interpret=self._route.interpret)
-
-                def per_device(m):
-                    return jnp.matmul(m, onehot,
-                                      precision=jax.lax.Precision.HIGHEST)
-
-                def terms(x1, i, d):
-                    mass, w = region_terms(x1, d, region_own(i, d, region_ix),
-                                           region_ix, n_regions, self_cost,
-                                           segment_sum, per_device)
+                def terms(x1, sp1, i, d):
+                    own = region_own(i, d, region_ix)
+                    if sp1 is None:
+                        mass, w = region_terms(x1, d, own, region_ix,
+                                               n_regions, self_cost,
+                                               segment_sum, per_device)
+                    else:
+                        mass, w = region_terms_sparse(
+                            *sp1, d, own, region_ix, n_regions, self_cost,
+                            per_device)
                     return region_a_off(i, d, region_ix), mass, w
 
                 if inter.shape[0] == 1:
-                    a, mass, w = terms(x, inter[0], degrade[0])
+                    a, mass, w = terms(x, sparse, inter[0], degrade[0])
                     a = a[None]                         # (1, R, V)
                 else:
                     a, mass, w = jax.vmap(
-                        lambda x1, i, d: terms(x1[None], i, d))(
-                        x, inter, degrade)
+                        lambda x1, sp1, i, d: terms(
+                            x1[None], jax.tree.map(lambda v: v[None], sp1),
+                            i, d))(x, sparse, inter, degrade)
                     mass, w = mass[:, 0], w[:, 0]       # (B, n, ·)
             out = dispatch.edge_latency_structured(
                 x.astype(jnp.float32), mass.astype(jnp.float32),
                 a.astype(jnp.float32), w.astype(jnp.float32), self._edges)
             return out + self._links_term(x, out.dtype)
 
-        def lat_b(x, inter, degrade):
-            return critical_path_dp(self.graph, elat_b(x, inter, degrade))
+        def lat_b(x, inter, degrade, sparse=None):
+            return critical_path_dp(self.graph,
+                                    elat_b(x, inter, degrade, sparse))
 
         def obj_b(x, inter, degrade, dq, beta):
             return lat_b(x, inter, degrade) / (1.0 + beta * dq)
 
-        def grid(placements, inters, degrades, dq, beta):
+        def grid(placements, inters, degrades, dq, beta, sparse=None):
             # same no-replication cross product as the dense path: scenarios
             # stream through lax.map carrying only (R, R) + (V,) state each
             lat = jax.lax.map(
-                lambda sc: lat_b(placements, sc[0][None], sc[1][None]),
+                lambda sc: lat_b(placements, sc[0][None], sc[1][None],
+                                 sparse),
                 (inters, degrades))
             return self._finish_grid(lat, inters.shape[0], dq, beta)
 
@@ -512,13 +543,13 @@ class BatchedEvaluator:
             has_lat = "latency_f" in obj_set.names
 
             def grid(placements, inters, degrades, speeds, dq, beta,
-                     weights):
+                     weights, sparse=None):
                 def per_scenario(sc):
                     inter, degrade, speed = sc
                     outs = {}
                     if has_lat:
                         outs["latency_f"] = sf.lat_raw(
-                            placements, inter[None], degrade[None])
+                            placements, inter[None], degrade[None], sparse)
                     for name, f in builders.items():
                         outs[name] = jax.vmap(
                             lambda x: f(x, inter, degrade, speed))(placements)
@@ -617,30 +648,48 @@ class BatchedEvaluator:
         occupancy objectives on the dense path ((V,) or (S, V), see
         :func:`pack_speeds`; default unit speeds); structured families
         carry their own speeds, so ``speed`` must stay None there.
+
+        On the structured path the region terms come from each row's
+        nonzeros wherever the rows reach here as :class:`SparsePlacements`
+        of at most 16 slots (:func:`takes_sparse_terms`); host rows are
+        turned into them where :func:`sparse_placements` accepts them, so a
+        direct call and a served call of the same rows take the same route.
+        Device arrays and wider rows keep the dense terms.  The route is
+        the whole call's: one row that ``sparse_placements`` refuses sends
+        every row of the call to the dense terms, whose sums over two or
+        more support devices of one region may differ in the last bits.
         """
         structured = isinstance(coms, RegionFleetFamily)
+        if structured and not isinstance(placements,
+                                         (SparsePlacements, jax.Array)):
+            sparse = sparse_placements(placements)
+            placements = placements if sparse is None else sparse
         S = coms.n_scenarios if structured else int(np.shape(coms)[0])
         shape = (placements.shape if isinstance(placements, SparsePlacements)
                  else np.shape(placements))
         P, V = int(shape[0]), int(shape[-1])
         path = "structured" if structured else "dense"
         multi = objectives is not None
+        terms = "sparse" if takes_sparse_terms(placements) else "dense"
         reg = obs.registry()
         if reg.enabled:
             reg.counter("eval.score_grid.dispatches", path=path).add(1)
             reg.histogram("eval.score_grid.cells", lo=1.0).observe(S * P)
-        regions = {"R": coms.n_regions} if structured else {}
+            if structured:
+                reg.counter("eval.region_terms", route=terms).add(1)
+        regions = {"R": coms.n_regions, "terms": terms} if structured else {}
         with obs.span("score_grid", S=S, P=P, path=path, multi=multi,
                       **regions) as sp:
             if reg.enabled and self._route.pallas:
                 sp.set(kernel_rows=self._kernel_rows(structured, P, V))
-            placements, pack, dq_arr, beta = self._upload(
+            x, sparse, pack, dq_arr, beta = self._upload(
                 placements, coms, dq, beta, S, P)
             san = sanitize.state()
             if san.enabled and san.domain_check:
                 sanitize.check_dq(dq)  # host-side operand: no round-trip
-            out = self._dispatch_grid(placements, coms, pack, dq_arr, beta,
-                                      objectives, speed, structured)
+            out = self._dispatch_grid(
+                x, sparse if terms == "sparse" else None, coms, pack,
+                dq_arr, beta, objectives, speed, structured)
             sp.sync(out.scalarized if isinstance(out, ObjectiveGrids)
                     else out)
         if guard_output and san.enabled and san.nan_check:
@@ -670,8 +719,9 @@ class BatchedEvaluator:
 
     def _upload(self, placements, coms, dq, beta, S: int, P: int):
         """The grid's operands as device arrays: placements (rebuilt dense
-        on the device from :class:`SparsePlacements`), the pack (a dense
-        stack, or a family's ``(inter, degrade)``), dq and β.  In a
+        on the device from :class:`SparsePlacements`, whose ``(idx, val)``
+        come back too, else None), the pack (a dense stack, or a family's
+        ``(inter, degrade)``), dq and β.  In a
         ``grid.upload`` span that, with telemetry on, waits for the copies
         and counts ``h2d_bytes``: the device bytes of every operand that
         was not already a ``jax.Array``."""
@@ -682,9 +732,11 @@ class BatchedEvaluator:
                 val = jnp.asarray(placements.val)
                 x = _densify(idx, val, n_devices=placements.n_devices)
                 sent = [(placements.idx, idx), (placements.val, val)]
+                sparse = (idx, val)
             else:
                 x = jnp.asarray(placements)
                 sent = [(placements, x)]
+                sparse = None
             if structured:
                 pairs = list(zip((coms.inter, coms.degrade),
                                  self._family_args(coms)))
@@ -697,19 +749,20 @@ class BatchedEvaluator:
                 up.sync([x, *out])
                 up.set(h2d_bytes=_host_bytes(sent + pairs))
         pack = tuple(out[:2]) if structured else out[0]
-        return x, pack, out[-2], out[-1]
+        return x, sparse, pack, out[-2], out[-1]
 
-    def _dispatch_grid(self, placements, coms, pack, dq_arr, beta,
+    def _dispatch_grid(self, placements, sparse, coms, pack, dq_arr, beta,
                        objectives, speed, structured: bool):
         """``pack`` is ``coms`` on the device: the dense stack, or the
-        family's ``(inter, degrade)``."""
+        family's ``(inter, degrade)``; ``sparse`` the placements' ``(idx,
+        val)`` or None, read on the structured path alone."""
         if objectives is None:
             if speed is not None:
                 raise ValueError("speed only feeds the occupancy objectives "
                                  "— pass objectives= to use it")
             if structured:
                 return self._structured(coms).grid(placements, *pack,
-                                                   dq_arr, beta)
+                                                   dq_arr, beta, sparse)
             return self._jit_grid(placements, pack, dq_arr, beta)
         obj_set = as_objective_set(objectives)
         weights = jnp.asarray(obj_set.weights, jnp.float32)
@@ -721,7 +774,7 @@ class BatchedEvaluator:
             # traced degrade itself (effective = speed / degrade)
             speeds = jnp.asarray(coms.speed_or_ones(), jnp.float32)
             grids, scal = self._multi_structured(coms, obj_set)(
-                placements, *pack, speeds, dq_arr, beta, weights)
+                placements, *pack, speeds, dq_arr, beta, weights, sparse)
         else:
             grids, scal = self._multi_dense(obj_set)(
                 placements, pack, self._dense_speeds(pack, speed), dq_arr,

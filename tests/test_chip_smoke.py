@@ -1,6 +1,7 @@
 """chip_smoke.py's phases end to end on the CPU at a tiny size, and its
 refusal to report a result from anything but a TPU."""
 
+import dataclasses
 import importlib.util
 import sys
 from pathlib import Path
@@ -61,6 +62,23 @@ def test_phase_parity_and_oracle_on_cpu(deployment, metrics):
     counts = cs.dispatch_counts(metrics)
     assert counts == {"pallas_compiled_plans": 0,
                       "pallas_interpreted_plans": 0}
+
+
+@pytest.mark.parametrize("devices, route", [(96, "dense"), (256, "sparse")])
+def test_structured_phase_counts_its_region_terms_route(devices, route,
+                                                        metrics):
+    """The structured phase, served and direct, takes the sparse region
+    terms wherever ``sparse_placements`` accepts its rows (8 slots need
+    V ≥ 128) and the dense ones elsewhere; ``region_terms_routes`` counts
+    every dispatch under one route."""
+    shape = dataclasses.replace(TINY, struct_devices=devices)
+    graph, deps = cs.build_deployments(3, shape)
+    dep = next(d for d in deps if d.name == "structured")
+    svc = WhatIfService(graph, admission=cs.ADMISSION,
+                        max_chunk_rows=shape.chunk_rows)
+    cs.run_phase(svc, graph, dep, 3, shape, log=lambda line: None)
+    routes = cs.region_terms_routes(metrics)
+    assert routes[route] > 0 and sum(routes.values()) == routes[route]
 
 
 def test_structured_kernel_check_on_cpu():

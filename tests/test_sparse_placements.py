@@ -1,8 +1,13 @@
 """Region-fleet placements cross to the device as their nonzeros:
 ``sparse_placements`` and the device rebuild give back the dense rows bit
-for bit, ``score_grid`` scores them as it scores the dense rows, and the
-service uploads a region fleet's rows that way."""
+for bit, ``score_grid`` scores them (the region terms from the nonzeros)
+as it scores the dense rows (the dense terms), and the service uploads a
+region fleet's rows that way, on the route a direct call of its rows
+takes."""
 
+import contextlib
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -83,30 +88,68 @@ def _family(rng, V=1024, R=4, S=3):
     return pack_region_fleets(fleets)
 
 
-def test_score_grid_scores_sparse_rows_as_the_dense_rows():
+def _colocated_rows(rng, region, P, n_ops, per_op, distinct):
+    """(P, n_ops, V) rows whose operators all spread over the same
+    ``per_op`` devices of their row, so both ends of every edge sit on
+    the support and the support's own entries of ``w`` enter each score;
+    with ``distinct`` those devices lie in distinct regions (every region
+    sum has one term), else anywhere."""
+    n_regions = int(region.max()) + 1
+    x = np.zeros((P, n_ops, region.size), np.float32)
+    for p in range(P):
+        devs = ([rng.choice(np.flatnonzero(region == r)) for r in
+                 rng.choice(n_regions, per_op, replace=False)] if distinct
+                else rng.choice(region.size, per_op, replace=False))
+        for i in range(n_ops):
+            x[p, i, devs] = rng.dirichlet(np.ones(per_op))
+    return x
+
+
+def _within_4_ulp(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.all(np.abs(got - want)
+                  <= 4 * np.spacing(np.abs(want).astype(np.float32)))
+
+
+def _score_sparse_as_dense():
+    """score_grid of rows sent as their nonzeros (the sparse region terms)
+    against the same rows as a device array (the dense terms), single- and
+    multi-objective: bitwise where the support devices of each (row,
+    operator) lie in distinct regions, within 4 ulp where they share
+    one (a region's sum then adds its terms in another order)."""
     rng = np.random.default_rng(3)
     g = random_dag(5, 0.5, rng=rng)
     fam = _family(rng)
-    x = _rows(rng, 4, g.n_ops, 1024, 4)
     ev = BatchedEvaluator.shared(g)
-    sp = sparse_placements(x)
-    np.testing.assert_array_equal(
-        np.asarray(ev.score_grid(sp, fam, dq=0.3, beta=0.5)),
-        np.asarray(ev.score_grid(x, fam, dq=0.3, beta=0.5)))
-    got = ev.score_grid(sp, fam, objectives=OBJ2)
-    want = ev.score_grid(x, fam, objectives=OBJ2)
-    for name in OBJ2.names:
-        np.testing.assert_array_equal(np.asarray(got[name]),
-                                      np.asarray(want[name]))
+    shared = _colocated_rows(rng, fam.region, 4, g.n_ops, 4, False)
+    distinct = _colocated_rows(rng, fam.region, 4, g.n_ops, 4, True)
+    for x, same in ((distinct, np.testing.assert_array_equal),
+                    (shared, _within_4_ulp)):
+        sp = sparse_placements(x)
+        assert sp is not None
+        same(ev.score_grid(sp, fam, dq=0.3, beta=0.5),
+             ev.score_grid(jnp.asarray(x), fam, dq=0.3, beta=0.5))
+        got = ev.score_grid(sp, fam, objectives=OBJ2)
+        want = ev.score_grid(jnp.asarray(x), fam, objectives=OBJ2)
+        for name in OBJ2.names:
+            same(got[name], want[name])
 
 
-def test_region_fleet_service_uploads_the_nonzeros(telemetry):
-    """A served region-fleet chunk sends (idx, val) slots, not V floats a
-    row-operator, and its scores are bitwise a direct dense score_grid."""
-    rng = np.random.default_rng(4)
-    g = random_dag(5, 0.5, rng=rng)
-    fam = _family(rng)
-    S, V, n_ops = fam.n_scenarios, 1024, g.n_ops
+def test_score_grid_scores_sparse_rows_as_the_dense_rows():
+    _score_sparse_as_dense()
+
+
+def test_score_grid_scores_sparse_rows_as_the_dense_rows_on_pallas(
+        pallas_route):
+    """The same on the Pallas route (interpreted)."""
+    with pallas_route():
+        _score_sparse_as_dense()
+
+
+def _serve_two_queries(rng, g, fam):
+    """A 3-row and a 1-row query served in one 4-row chunk; returns the
+    (ticket, rows, dq, β) of each and the served results by query id."""
+    V, n_ops = fam.n_devices, g.n_ops
     svc = WhatIfService(g, admission=AdmissionConfig(p99_budget_s=1e6),
                         max_chunk_rows=4)
     fid = svc.register_fleet("a", fam)
@@ -115,6 +158,17 @@ def test_region_fleet_service_uploads_the_nonzeros(telemetry):
                                           dq=0.3, beta=0.5))
     tb = svc.submit("b", fid, WhatIfQuery(kind="score", placements=y))
     svc.drain()
+    done = {m.query_id: m for t in ("a", "b") for m in svc.poll(t)
+            if isinstance(m, QueryResult)}
+    return [(ta, x, 0.3, 0.5), (tb, y, 0.0, 0.0)], done
+
+
+def _uploads_the_nonzeros():
+    rng = np.random.default_rng(4)
+    g = random_dag(5, 0.5, rng=rng)
+    fam = _family(rng)
+    S, V, n_ops = fam.n_scenarios, 1024, g.n_ops
+    queries, done = _serve_two_queries(rng, g, fam)
     got = [e["args"]["h2d_bytes"] for e in obs.trace_events()
            if e["name"] == "grid.upload"]
     # one 4-row chunk: 8 int32 + 8 float32 slots a row-operator, the
@@ -122,10 +176,114 @@ def test_region_fleet_service_uploads_the_nonzeros(telemetry):
     family = 4 * (S * 4 * 4 + S * V)
     assert got == [4 * (2 * 8 * 4 * n_ops + S * 4 + 4) + family]
     ev = BatchedEvaluator.shared(g)
-    done = {m.query_id: m for t in ("a", "b") for m in svc.poll(t)
-            if isinstance(m, QueryResult)}
-    for tk, rows, dq, beta in ((ta, x, 0.3, 0.5), (tb, y, 0.0, 0.0)):
+    for tk, rows, dq, beta in queries:
         res = done[tk.query_id]
         np.testing.assert_array_equal(
             res.scores, np.asarray(ev.score_grid(rows, fam, dq=dq,
                                                  beta=beta), np.float32))
+
+
+def test_region_fleet_service_uploads_the_nonzeros(telemetry):
+    """A served region-fleet chunk sends (idx, val) slots, not V floats a
+    row-operator, and its scores are bitwise a direct score_grid of the
+    same host rows."""
+    _uploads_the_nonzeros()
+
+
+def test_region_fleet_service_uploads_the_nonzeros_on_pallas(telemetry,
+                                                             pallas_route):
+    """The same on the Pallas route (interpreted): served scores are
+    bitwise a direct score_grid of the same host rows."""
+    with pallas_route():
+        _uploads_the_nonzeros()
+
+
+def _terms_seen(reg) -> list:
+    """(``terms`` of each structured score_grid span, region-terms counter
+    values by route)."""
+    spans = [e["args"].get("terms") for e in obs.trace_events()
+             if e["name"] == "score_grid"]
+    counts = {r["labels"]["route"]: r["value"] for r in reg.snapshot()
+              if r["name"] == "eval.region_terms"}
+    return [spans, counts]
+
+
+@pytest.mark.parametrize("route", ["vmap", "pallas"])
+def test_served_region_fleet_chunk_takes_the_sparse_terms(route, telemetry,
+                                                          pallas_route):
+    """The served chunk, and the direct call of the same host rows, record
+    ``terms=sparse`` and count ``eval.region_terms{route=sparse}``; rows
+    given as a device array keep the dense terms and record ``dense``."""
+    rng = np.random.default_rng(6)
+    g = random_dag(5, 0.5, rng=rng)
+    fam = _family(rng)
+    with pallas_route() if route == "pallas" else contextlib.nullcontext():
+        queries, _ = _serve_two_queries(rng, g, fam)
+        assert _terms_seen(telemetry) == [["sparse"], {"sparse": 1}]
+        ev = BatchedEvaluator.shared(g)
+        ev.score_grid(queries[0][1], fam)
+        ev.score_grid(jnp.asarray(queries[0][1]), fam, objectives=OBJ2)
+    assert _terms_seen(telemetry) == [["sparse", "sparse", "dense"],
+                                      {"sparse": 2, "dense": 1}]
+
+
+def test_rows_sparse_placements_refuses_are_served_apart(telemetry,
+                                                         pallas_route):
+    """A query over 100 devices an operator (more than V/16:
+    ``sparse_placements`` refuses it) queued beside one over 4: each is
+    its own super-batch, so each takes the region terms a direct call of
+    its rows takes, and its scores are that call's bits."""
+    rng = np.random.default_rng(7)
+    g = random_dag(5, 0.5, rng=rng)
+    fam = _family(rng)
+    narrow, wide = _rows(rng, 3, g.n_ops, 1024, 4), \
+        _rows(rng, 2, g.n_ops, 1024, 100)
+    assert sparse_placements(wide) is None
+    with pallas_route():
+        svc = WhatIfService(g, admission=AdmissionConfig(p99_budget_s=1e6),
+                            max_chunk_rows=8)
+        fid = svc.register_fleet("a", fam)
+        tickets = [svc.submit("a", fid, WhatIfQuery(
+            kind="score", placements=rows, dq=0.3, beta=0.5))
+            for rows in (wide, narrow)]
+        svc.drain()
+        assert _terms_seen(telemetry) == [["sparse", "dense"],
+                                          {"sparse": 1, "dense": 1}]
+        done = {m.query_id: m for m in svc.poll("a")
+                if isinstance(m, QueryResult)}
+        ev = BatchedEvaluator.shared(g)
+        for tk, rows in zip(tickets, (wide, narrow)):
+            np.testing.assert_array_equal(
+                done[tk.query_id].scores,
+                np.asarray(ev.score_grid(rows, fam, dq=0.3, beta=0.5),
+                           np.float32))
+
+
+def test_a_refused_query_split_across_chunks_keeps_the_dense_terms(
+        telemetry, pallas_route):
+    """A query whose last row spreads over 100 devices an operator (so
+    ``sparse_placements`` refuses the whole query) and whose first three
+    rows over 4, served in 2-row chunks: the chunk of narrow rows alone
+    keeps the dense terms, as the direct call of the whole query does,
+    and the served scores are that call's bits."""
+    rng = np.random.default_rng(10)
+    g = random_dag(5, 0.5, rng=rng)
+    fam = _family(rng)
+    rows = np.concatenate([_rows(rng, 3, g.n_ops, 1024, 4),
+                           _rows(rng, 1, g.n_ops, 1024, 100)])
+    assert sparse_placements(rows) is None
+    assert sparse_placements(rows[:2]) is not None
+    with pallas_route():
+        svc = WhatIfService(g, admission=AdmissionConfig(p99_budget_s=1e6),
+                            max_chunk_rows=2)
+        fid = svc.register_fleet("a", fam)
+        tk = svc.submit("a", fid, WhatIfQuery(kind="score", placements=rows,
+                                              dq=0.3, beta=0.5))
+        svc.drain()
+        done = {m.query_id: m for m in svc.poll("a")
+                if isinstance(m, QueryResult)}
+        direct = BatchedEvaluator.shared(g).score_grid(rows, fam, dq=0.3,
+                                                      beta=0.5)
+    np.testing.assert_array_equal(done[tk.query_id].scores,
+                                  np.asarray(direct, np.float32))
+    assert _terms_seen(telemetry) == [["dense"] * 3, {"dense": 3}]

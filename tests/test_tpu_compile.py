@@ -162,3 +162,25 @@ def test_structured_score_grid_compiles(tpu_dispatch, smoke):
                    (full.scenarios, full.regions, full.regions),
                    (full.scenarios, full.struct_devices))
     _compiled_text(ev._structured(fam).grid, args + [scalar, scalar])
+
+
+def test_structured_sparse_score_grid_compiles(tpu_dispatch, smoke):
+    """The structured score_grid of a served chunk, the region terms from
+    each row's nonzeros: P = 64 placements of 16 operators as (idx, val)
+    slots (k = 8) beside their dense rows, S = 8 scenarios of V = 131072
+    devices in R = 32 regions."""
+    cs, graph = smoke
+    full = cs.FULL
+    fam = region_fleet_family(
+        np.random.default_rng(0), full.scenarios,
+        ScenarioConfig(n_regions=(full.regions, full.regions)),
+        n_devices=full.struct_devices)
+    ev = BatchedEvaluator(graph)
+    P, n = full.chunk_rows, graph.n_ops
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=tpu_dispatch)
+    idx = jax.ShapeDtypeStruct((P, n, 8), jnp.int32, sharding=tpu_dispatch)
+    args = _shapes(tpu_dispatch, (P, n, full.struct_devices),
+                   (full.scenarios, full.regions, full.regions),
+                   (full.scenarios, full.struct_devices))
+    args += [scalar, scalar, (idx, _shapes(tpu_dispatch, (P, n, 8))[0])]
+    _compiled_text(ev._structured(fam).grid, args)
